@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve.engine import ServeEngine
 from repro.serve.splitbrain_engine import SplitBrainEngine, traffic_model_for
@@ -152,6 +153,7 @@ def main(argv=None) -> int:
                     help="generated tokens per measurement")
     ap.add_argument("--out", default="BENCH_decode.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     max_new = args.tokens or (8 if args.quick else 32)
     batch = 2

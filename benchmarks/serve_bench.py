@@ -117,6 +117,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serve import pages
 from repro.serve import slots
@@ -733,7 +734,15 @@ def bench_tp(arch: str, max_new: int, max_slots: int,
     traffic on BOTH sides with equal totals (per-shard meter entries sum to
     the single-device analytical model), zero steady-state recompiles, the
     pool cut on KV heads (kv_shards == tp); the decode tokens/s speedup is
-    additionally gated on hosts with >= 2 cores."""
+    additionally gated on hosts with >= 2 cores.
+
+    The workers run on forced CPU devices, so a parent on any other backend
+    would report their CPU numbers beside its own: refused there."""
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"serve_bench: the tp discipline runs its workers on forced CPU "
+            f"devices and cannot measure the {jax.default_backend()!r} "
+            f"backend this process is on; run it with JAX_PLATFORMS=cpu")
     cfg = get_config(arch).reduced(**overrides)
     spec = {
         "arch": arch,
@@ -988,6 +997,7 @@ def main(argv=None) -> int:
                     help="mean Poisson inter-arrival gap (saturating default)")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # 64 full-run requests: sub-second walls make the discipline ratios
     # noisy on a shared machine; a longer trace stabilizes the gates

@@ -215,7 +215,8 @@ def kernel_bench() -> List[Row]:
         lambda: jax.block_until_ready(ref.w4a8_matmul(qx, xs, codes, ws)))
     us_pal, got = _timeit(
         lambda: jax.block_until_ready(w4a8_matmul(qx, xs, codes, ws,
-                                                  bm=128, bn=128, bk=128)))
+                                                  bm=128, bn=128, bk=128,
+                                                  interpret=True)))
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                 - want.astype(jnp.float32))))
     return [

@@ -89,7 +89,9 @@ class ModelConfig:
     rwkv_chunk: int = 0                # >0: chunked matmul-form WKV (Perf H1)
     ssm_scan: str = "sequential"       # "associative" = log-depth scan (Perf H5)
     dtype: str = "bfloat16"
-    use_pallas: bool = False
+    kernels: str = "jnp"               # kernels/ops.py KERNELS: "jnp" | "pallas" |
+                                       # "interpret"; serving engines set it from
+                                       # their mesh's platform
     ita: ITAConfig = field(default_factory=ITAConfig)
     parallel: ParallelConfig = field(default_factory=ParallelConfig)
     # notes for DESIGN/EXPERIMENTS (e.g. long_500k applicability)

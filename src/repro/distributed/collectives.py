@@ -23,7 +23,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -80,8 +79,8 @@ def dp_train_step_compressed(loss_fn, mesh: Mesh, axis_name: str = "data",
 
     in_specs = (P(), P(axis_name))
     out_specs = (P(), P())
-    return shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 # ------------------------------------------------- distributed decode attention
@@ -124,11 +123,42 @@ def distributed_decode_attention(mesh: Mesh, axis_name: str = "model",
         return out.reshape(B, Hq, 1, D).astype(q.dtype)
 
     b = tuple(a for a in batch_axes if a in mesh.axis_names) or None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b), P(b, None, axis_name, None),
                   P(b, None, axis_name, None), P(b, axis_name)),
-        out_specs=P(b), check_rep=False)
+        out_specs=P(b), check_vma=False)
+
+
+# ------------------------------------------------- TP flash attention
+def tp_flash_attention(mesh: Mesh, axis_name: str = "model", *,
+                       causal: bool = True, window: Optional[int] = None,
+                       softcap: Optional[float] = None,
+                       scale: Optional[float] = None,
+                       batch_axes: tuple = (), cut_heads: bool = True,
+                       interpret: bool = False):
+    """Per-shard Pallas flash attention on a TP mesh (prefill and chunked
+    prefill; DESIGN.md §11).  A Mosaic kernel cannot be partitioned by
+    XLA, so it runs inside ``shard_map``.
+
+    q: (B, Hq, Tq, D); k, v: (B, Hkv, Tk, D); kv_offset: (B,) int32.
+    ``cut_heads`` (Hq % tp == 0 and Hkv % tp == 0): each shard takes a
+    contiguous block of q heads and exactly the kv heads they read (GQA
+    alignment as in :func:`tp_paged_decode_attention`), with no collective;
+    otherwise every shard runs all heads on replicated operands.
+    """
+    from repro.kernels import flash_attention as _fa
+
+    def local(q, k, v, off):
+        return _fa.flash_attention(q, k, v, causal=causal, window=window,
+                                   softcap=softcap, scale=scale,
+                                   kv_offset=off, interpret=interpret)
+
+    b = tuple(a for a in batch_axes if a in mesh.axis_names) or None
+    heads = P(b, axis_name if cut_heads else None, None, None)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(heads, heads, heads, P(b)),
+                         out_specs=heads, check_vma=False)
 
 
 # --------------------------------------------- TP paged decode attention
@@ -137,13 +167,16 @@ def tp_paged_decode_attention(mesh: Mesh, axis_name: str = "model", *,
                               softcap: Optional[float] = None,
                               scale: Optional[float] = None,
                               batch_axes: tuple = (),
-                              interpret: bool = True):
+                              interpret: bool = False,
+                              quant: bool = False):
     """Per-shard Pallas paged flash-decode over the HEAD-CUT pool
     (DESIGN.md §11).
 
     q: (B, Hq, 1, D) cut on heads over ``axis_name``;
     pools: (num_pages, ps, Hkv, D) cut on KV heads;
-    page_table (B, P) / cache_len (B,): host-owned, replicated.
+    page_table (B, P) / cache_len (B,): host-owned, replicated;
+    ``quant=True``: two more operands, the (num_pages, Hkv) per-page
+    dequant scales of a quantized pool, cut on the same KV heads.
 
     Requires Hq % tp == 0 and Hkv % tp == 0.  Contiguous head blocks keep
     GQA alignment in-shard — q heads [i*Hq/tp, ...) attend exactly the kv
@@ -154,26 +187,28 @@ def tp_paged_decode_attention(mesh: Mesh, axis_name: str = "model", *,
     """
     from repro.kernels import paged_attention as _pa
 
-    def local(q, k_pool, v_pool, table, length):
+    def local(q, k_pool, v_pool, table, length, k_scale=None, v_scale=None):
         return _pa.paged_decode_attention(
             q, k_pool, v_pool, table, length, window=window,
-            softcap=softcap, scale=scale, interpret=interpret)
+            softcap=softcap, scale=scale, k_scale=k_scale, v_scale=v_scale,
+            interpret=interpret)
 
     b = tuple(a for a in batch_axes if a in mesh.axis_names) or None
-    return shard_map(
+    pool = P(None, None, axis_name, None)
+    scales = (P(None, axis_name),) * 2 if quant else ()
+    return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(b, axis_name, None, None),
-                  P(None, None, axis_name, None),
-                  P(None, None, axis_name, None),
-                  P(b, None), P(b)),
-        out_specs=P(b, axis_name, None, None), check_rep=False)
+        in_specs=(P(b, axis_name, None, None), pool, pool,
+                  P(b, None), P(b)) + scales,
+        out_specs=P(b, axis_name, None, None), check_vma=False)
 
 
 def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
                                     softcap: Optional[float] = None,
                                     scale: Optional[float] = None,
                                     batch_axes: tuple = (),
-                                    interpret: bool = True):
+                                    interpret: bool = False,
+                                    quant: bool = False):
     """The Hkv < tp fallback: heads replicate, the PAGE axis splits.
 
     When the TP degree does not divide the KV head count the pool stays
@@ -184,11 +219,12 @@ def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
     pairs combine exactly in log-sum-exp space with two tiny psums
     (O(B*Hq*D) wire), the paged twin of ``distributed_decode_attention``.
     Sliding-window leaves never page (serve/pages.py), so the merge only
-    covers the window-free case.
+    covers the window-free case.  ``quant=True`` takes the quantized
+    pool's (num_pages, Hkv) scales as two more (replicated) operands.
     """
     from repro.kernels import paged_attention as _pa
 
-    def local(q, k_pool, v_pool, table, length):
+    def local(q, k_pool, v_pool, table, length, k_scale=None, v_scale=None):
         B, Hq, _, D = q.shape
         ps = k_pool.shape[1]
         span = table.shape[1] * ps          # positions this shard covers
@@ -198,7 +234,8 @@ def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
         len_loc = jnp.clip(length - off, 0, span)
         out, lse = _pa.paged_decode_attention(
             q, k_pool, v_pool, table, len_loc, softcap=softcap,
-            scale=scale, interpret=interpret, return_lse=True)
+            scale=scale, k_scale=k_scale, v_scale=v_scale,
+            interpret=interpret, return_lse=True)
         lse = lse.reshape(B, Hq, 1)          # (B, Hkv, group) -> head order
         m = jax.lax.pmax(lse, axis_name)
         w = jnp.exp(lse - m)                 # empty shards drop out (w ~ 0)
@@ -207,7 +244,8 @@ def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
         return (num / jnp.maximum(den[..., None], 1e-30)).astype(q.dtype)
 
     b = tuple(a for a in batch_axes if a in mesh.axis_names) or None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(b), P(), P(), P(b, axis_name), P(b)),
-        out_specs=P(b), check_rep=False)
+        in_specs=(P(b), P(), P(), P(b, axis_name), P(b))
+        + ((P(), P()) if quant else ()),
+        out_specs=P(b), check_vma=False)

@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -62,10 +61,10 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, num_microbatches: int,
             tick, (state, outputs), jnp.arange(M + S - 1))
         return outputs[None]  # (1, M, mb...) -> stacked over stages
 
-    stacked = shard_map(
+    stacked = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis_name), P()),
-        out_specs=P(axis_name), check_rep=False)
+        out_specs=P(axis_name), check_vma=False)
 
     def apply(stage_params, x):
         out = stacked(stage_params, x)      # (S, M, mb...)

@@ -23,15 +23,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 DEFAULT_BQ, DEFAULT_BK = 512, 512
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, causal: bool, window: Optional[int],
-            softcap: Optional[float], kv_offset: int, n_kv: int,
+            softcap: Optional[float], n_kv: int,
             bq: int, bk: int, tk_valid: int):
+    b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -41,7 +37,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qpos0 = qi * bq + kv_offset
+    qpos0 = qi * bq + off_ref[b]
     block_needed = True
     if causal:
         block_needed = ki * bk <= qpos0 + bq - 1
@@ -85,16 +81,23 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "softcap", "scale", "kv_offset",
+    static_argnames=("causal", "window", "softcap", "scale",
                      "bq", "bk", "interpret"),
 )
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: Optional[int] = None,
                     softcap: Optional[float] = None,
-                    scale: Optional[float] = None, kv_offset: int = 0,
+                    scale: Optional[float] = None, kv_offset=0,
                     bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True) -> jnp.ndarray:
-    """q (B,Hq,Tq,D); k,v (B,Hkv,Tk,D); Hq % Hkv == 0."""
+                    interpret: bool = False) -> jnp.ndarray:
+    """q (B,Hq,Tq,D); k,v (B,Hkv,Tk,D); Hq % Hkv == 0.
+
+    Query row ``i`` of batch row ``b`` sits at key position
+    ``i + kv_offset[b]``.  ``kv_offset`` (scalar or (B,), may be traced)
+    rides in as a scalar-prefetch operand, so one compiled program serves
+    every offset — the chunked-prefill case, where a chunk at a traced
+    start attends causally over the whole linear cache.  ``interpret=True``
+    runs the Pallas interpreter (CPU validation only)."""
     B, Hq, Tq, D = q.shape
     _, Hkv, Tk, _ = k.shape
     group = Hq // Hkv
@@ -108,25 +111,34 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     nq, nk = q.shape[2] // bq_, k.shape[2] // bk_
 
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel, scale=s, causal=causal, window=window, softcap=softcap,
-            kv_offset=kv_offset, n_kv=nk, bq=bq_, bk=bk_, tk_valid=Tk),
+    offsets = jnp.broadcast_to(jnp.asarray(kv_offset, jnp.int32), (B,))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                    # per-row kv_offset
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq_, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk_, D), lambda b, h, i, j: (b, h // group, j, 0)),
-            pl.BlockSpec((1, 1, bk_, D), lambda b, h, i, j: (b, h // group, j, 0)),
+            pl.BlockSpec((1, 1, bq_, D), lambda b, h, i, j, _: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk_, D),
+                         lambda b, h, i, j, _: (b, h // group, j, 0)),
+            pl.BlockSpec((1, 1, bk_, D),
+                         lambda b, h, i, j, _: (b, h // group, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq_, D), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq_, D),
+                               lambda b, h, i, j, _: (b, h, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq_, 1), jnp.float32),
             pltpu.VMEM((bq_, 1), jnp.float32),
             pltpu.VMEM((bq_, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel, scale=s, causal=causal, window=window, softcap=softcap,
+            n_kv=nk, bq=bq_, bk=bk_, tk_valid=Tk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(offsets, q, k, v)
     return out[:, :, :Tq]

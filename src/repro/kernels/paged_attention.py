@@ -6,17 +6,21 @@ directly THROUGH the page table, so the ``(B, max_len, ...)`` dense-view
 transient of the reference paged decode step never exists and steady-state
 HBM reads drop from O(max_len) to O(live tokens) per slot.
 
-Grid: ``(B, Hkv, P)`` — slots × kv-heads × page-blocks, with the page axis
-innermost ("arbitrary" semantics) as the split-K axis of a flash-decode
-online softmax: running max / denominator / accumulator live in VMEM
-scratch and are revisited across page steps.  The page table and the
-per-slot lengths ride in as **scalar-prefetch** operands
-(``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index maps resolve
-``table[b, p]`` BEFORE the kernel body runs and the DMA engine fetches
-exactly one physical page per grid step — the paged analogue of
-``flash_attention.py``'s GQA-via-index-map trick (q is laid out
-``(B, Hkv, group, D)`` so every KV page is read once per kv head, never
-per q head).
+Grid: ``(B, P)`` — slots × page-blocks, with the page axis innermost
+("arbitrary" semantics) as the split-K axis of a flash-decode online
+softmax: running max / denominator / accumulator live in VMEM scratch and
+are revisited across page steps.  The page table and the per-slot lengths
+ride in as **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``),
+so the K/V BlockSpec index maps resolve ``table[b, p]`` BEFORE the kernel
+body runs and the DMA engine fetches exactly one physical page per grid
+step.  The block is the WHOLE page, every kv head at once:
+``(1, ps, Hkv, D)`` over the ``(N, ps, Hkv, D)`` pool, whose last two
+dimensions equal the array's — the only page block of this layout the TPU
+compiler accepts (a per-head ``(1, ps, 1, D)`` block is refused).  Scores
+are per-head lane reductions over ``D`` (decode is bandwidth-bound: one
+query row per kv head leaves the MXU nothing to fill), and GQA groups loop
+statically inside the body, so every page is read once per slot, never per
+q head.
 
 Pages past a slot's ``cache_len`` (and pages wholly below its sliding
 window) skip their compute with ``pl.when``.  For the dead TAIL the
@@ -40,29 +44,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(*refs, scale: float, window: Optional[int],
             softcap: Optional[float], ps: int, n_pages: int, group: int,
             quant: bool = False, with_lse: bool = False):
+    table_ref, len_ref, q_ref, k_ref, v_ref, *rest = refs
     if quant:
-        # per-page, per-kv-head dequant scales ride as two extra
-        # scalar-prefetch operands (DESIGN.md §13) — grid unchanged
-        table_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, \
-            *rest = refs
-    else:
-        table_ref, len_ref, q_ref, k_ref, v_ref, o_ref, *rest = refs
-        ks_ref = vs_ref = None
+        # per-page, per-kv-head dequant scales ride as (1, Hkv, 1) blocks
+        # fetched through the same table index as the page (DESIGN.md §13)
+        ks_ref, vs_ref, *rest = rest
+    o_ref, *rest = rest
     if with_lse:
         lse_ref, m_ref, l_ref, acc_ref = rest
     else:
         (m_ref, l_ref, acc_ref), lse_ref = rest, None
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -81,45 +78,43 @@ def _kernel(*refs, scale: float, window: Optional[int],
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # (group, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)     # (ps, D)
-        if ks_ref is not None:
-            # fused dequant: the fetched page block is int8/fp8 codes;
-            # multiply by this page×kv-head's scale before the softmax
-            pid = table_ref[b, p]
-            k = k * ks_ref[pid, h]
-            v = v * vs_ref[pid, h]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            logits = softcap * jnp.tanh(logits / softcap)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (group, ps), 1)
+        k = k_ref[0].astype(jnp.float32)              # (ps, Hkv, D)
+        v = v_ref[0].astype(jnp.float32)              # (ps, Hkv, D)
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1, 1), 0)
         mask = pos < length
         if window is not None:
             mask &= pos > length - 1 - window
-        logits = jnp.where(mask, logits, NEG_INF)
+        for g in range(group):
+            q = q_ref[0, g].astype(jnp.float32)       # (Hkv, D)
+            logits = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            if quant:
+                # fused dequant: scores of int8/fp8 codes times this
+                # page's per-kv-head scale == scores of dequantized keys
+                logits = logits * ks_ref[0][None]
+            if softcap is not None:
+                logits = softcap * jnp.tanh(logits / softcap)
+            logits = jnp.where(mask, logits, NEG_INF)  # (ps, Hkv, 1)
 
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
-        pexp = jnp.exp(logits - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * corr + pexp.sum(-1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            m_prev, l_prev = m_ref[g], l_ref[g]        # (Hkv, 1)
+            m_new = jnp.maximum(m_prev, logits.max(axis=0))
+            pexp = jnp.exp(logits - m_new[None])
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[g] = l_prev * corr + pexp.sum(axis=0)
+            m_ref[g] = m_new
+            pv = jnp.sum(pexp * v, axis=0)             # (Hkv, D)
+            if quant:
+                pv = pv * vs_ref[0]
+            acc_ref[g] = acc_ref[g] * corr + pv
 
     @pl.when(p == n_pages - 1)
     def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)             # (group, Hkv, 1)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         if lse_ref is not None:
             # log-sum-exp of this invocation's logits, for cross-shard
             # combination (a slot with no local pages reports ~ -inf and
             # drops out of the merge)
-            lse_ref[0, 0] = m_ref[..., 0] + jnp.log(l[..., 0])
+            lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(
@@ -134,7 +129,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            scale: Optional[float] = None,
                            k_scale: Optional[jnp.ndarray] = None,
                            v_scale: Optional[jnp.ndarray] = None,
-                           interpret: bool = True,
+                           interpret: bool = False,
                            return_lse: bool = False):
     """q (B, Hq, 1, D); pools (num_pages, page_size, Hkv, D);
     page_table (B, P) int32 physical page ids; cache_len (B,) valid lengths.
@@ -142,16 +137,17 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     ``(page_table[b, t // page_size], t % page_size)``.
 
     ``k_scale``/``v_scale`` (num_pages, Hkv) f32 dequantize QUANTIZED pools
-    (int8/fp8 codes) at page-fetch time: they ride in as two more
-    scalar-prefetch operands and the kernel multiplies each fetched page
-    block by ``scale[table[b, p], h]`` before the online softmax — the
-    split-K grid structure is unchanged and the pages stream at 1 byte per
-    element (DESIGN.md §13).
+    (int8/fp8 codes) at page-fetch time: each page's scales are fetched
+    through the same table index as the page itself and multiply its
+    scores and value sum inside the online softmax — the split-K grid is
+    unchanged and the pages stream at 1 byte per element (DESIGN.md §13).
 
     ``return_lse=True`` additionally returns the per-head log-sum-exp
     (B, Hkv, group) f32 of the computed logits, so partial results over a
     SPLIT page axis can be exactly combined across TP shards
     (``distributed.collectives.tp_paged_decode_attention_merge``).
+
+    ``interpret=True`` runs the Pallas interpreter (CPU validation only).
     """
     B, Hq, _, D = q.shape
     ps, Hkv = k_pool.shape[1], k_pool.shape[2]
@@ -159,56 +155,55 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     group = Hq // Hkv
     s = scale if scale is not None else D ** -0.5
     quant = k_scale is not None
-    # GQA layout: the group dim rides inside the q/out block, so each KV
-    # page is fetched once per KV head (not once per q head)
-    qg = q[:, :, 0, :].reshape(B, Hkv, group, D)
+    # GQA layout (B, group, Hkv, D): the group dim rides inside the q/out
+    # block, so each KV page is fetched once per slot (not once per q head)
+    qg = q[:, :, 0, :].reshape(B, Hkv, group, D).transpose(0, 2, 1, 3)
 
-    # index maps take the scalar-prefetch refs as trailing args — varargs
-    # keeps one set of maps valid for both the 2- and 4-operand layouts
-    out_specs = pl.BlockSpec((1, 1, group, D),
-                             lambda b, h, p, *_: (b, h, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype)
+    # index maps take the two scalar-prefetch refs as trailing args
+    def per_slot(*block):
+        return pl.BlockSpec(block, lambda b, p, *_: (b,) + (0,) * (len(block) - 1))
+
+    def per_page(*block):
+        return pl.BlockSpec(
+            block, lambda b, p, tbl, _: (tbl[b, p],) + (0,) * (len(block) - 1))
+
+    in_specs = [per_slot(1, group, Hkv, D),
+                per_page(1, ps, Hkv, D), per_page(1, ps, Hkv, D)]
+    operands = [qg, k_pool, v_pool]
+    if quant:
+        in_specs += [per_page(1, Hkv, 1), per_page(1, Hkv, 1)]
+        operands += [k_scale.astype(jnp.float32)[:, :, None],
+                     v_scale.astype(jnp.float32)[:, :, None]]
+    out_specs = per_slot(1, group, Hkv, D)
+    out_shape = jax.ShapeDtypeStruct((B, group, Hkv, D), q.dtype)
     if return_lse:
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, 1, group),
-                                  lambda b, h, p, *_: (b, h, 0))]
+        out_specs = [out_specs, per_slot(1, group, Hkv, 1)]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((B, Hkv, group), jnp.float32)]
+                     jax.ShapeDtypeStruct((B, group, Hkv, 1), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # page_table, cache_len (+ k/v page scales when quantized)
-        num_scalar_prefetch=4 if quant else 2,
-        grid=(B, Hkv, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, D),
-                         lambda b, h, p, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, p, tbl, *_: (tbl[b, p], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda b, h, p, tbl, *_: (tbl[b, p], 0, h, 0)),
-        ],
+        num_scalar_prefetch=2,                    # page_table, cache_len
+        grid=(B, P),
+        in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((group, Hkv, 1), jnp.float32),
+            pltpu.VMEM((group, Hkv, 1), jnp.float32),
+            pltpu.VMEM((group, Hkv, D), jnp.float32),
         ],
     )
-    prefetch = (page_table.astype(jnp.int32),
-                jnp.asarray(cache_len, jnp.int32))
-    if quant:
-        prefetch += (k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32))
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=s, window=window, softcap=softcap, ps=ps,
             n_pages=P, group=group, quant=quant, with_lse=return_lse),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*prefetch, qg, k_pool, v_pool)
+    )(page_table.astype(jnp.int32), jnp.asarray(cache_len, jnp.int32),
+      *operands)
+    to_heads = lambda a: a.transpose(0, 2, 1, 3).reshape(B, Hq, 1, -1)
     if return_lse:
         out, lse = out
-        return out.reshape(B, Hq, 1, D), lse
-    return out.reshape(B, Hq, 1, D)
+        return to_heads(out), to_heads(lse).reshape(B, Hkv, group)
+    return to_heads(out)

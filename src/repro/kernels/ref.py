@@ -1,7 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth the kernels are tested against, and they double
-as the CPU/dry-run execution path of the framework (``use_pallas=False``).
+as the CPU/dry-run execution path of the framework (``kernels="jnp"``).
 The chunked attention reference is written with ``lax.scan`` so that lowering
 never materializes a (T x T) score matrix — required for the 32k-prefill
 dry-run cells.

@@ -17,11 +17,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BT = 256
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sfinal_ref, s_ref, *,
             bt: int, n_t: int):
     ti = pl.program_id(2)
@@ -30,7 +25,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sfinal_ref, s_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    u = u_ref[0].astype(jnp.float32)  # (D,)
+    u = u_ref[0, 0].astype(jnp.float32)  # (D,)
 
     def step(t, _):
         rt = r_ref[0, 0, t].astype(jnp.float32)   # (D,)
@@ -53,7 +48,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sfinal_ref, s_ref, *,
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def rwkv6_scan(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                w: jnp.ndarray, u: jnp.ndarray, *, bt: int = DEFAULT_BT,
-               interpret: bool = True):
+               interpret: bool = False):
     """r,k,v,w: (B,H,T,D); u: (H,D). Returns (out (B,H,T,D), state (B,H,D,D))."""
     B, H, T, D = r.shape
     bt_ = min(bt, T)
@@ -68,7 +63,10 @@ def rwkv6_scan(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, 1, bt_, D), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, bt_, D), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, bt_, D), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, D), lambda b, h, t: (h, 0)),
+            # u as (H, 1, D): a (1, 1, D) block's last two dims equal the
+            # array's, which the TPU lowering requires ((1, D) over (H, D)
+            # is refused)
+            pl.BlockSpec((1, 1, D), lambda b, h, t: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bt_, D), lambda b, h, t: (b, h, t, 0)),
@@ -79,8 +77,8 @@ def rwkv6_scan(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             jax.ShapeDtypeStruct((B, H, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(H, 1, D))
     return out, sfinal

@@ -22,11 +22,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 256, 256, 512
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
 def _kernel(x_ref, xs_ref, w_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
     """One (bm, bn) output tile; accumulate over the K grid dimension."""
     k_idx = pl.program_id(2)
@@ -37,10 +32,10 @@ def _kernel(x_ref, xs_ref, w_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
 
     x = x_ref[...]          # (bm, bk) int8
     w = w_ref[...]          # (bk, bn) int8 (int4 codes)
-    # int8 x int4 -> int32 exact on the MXU
+    # int8 x int4-codes-in-int8 -> int32, exact on the MXU (Mosaic takes
+    # integer dots only as int8 operands with an int32 result)
     acc_ref[...] += jax.lax.dot_general(
-        x.astype(jnp.int32), w.astype(jnp.int32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32,
+        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32,
     ).astype(jnp.float32)
 
     @pl.when(k_idx == n_k - 1)
@@ -57,7 +52,7 @@ def _kernel(x_ref, xs_ref, w_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
 def w4a8_matmul(qx: jnp.ndarray, x_scale: jnp.ndarray, codes: jnp.ndarray,
                 w_scale: jnp.ndarray, *, bm: int = DEFAULT_BM,
                 bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-                out_dtype=jnp.bfloat16, interpret: bool = True) -> jnp.ndarray:
+                out_dtype=jnp.bfloat16, interpret: bool = False) -> jnp.ndarray:
     """qx (M,K) int8, x_scale (M,1) f32, codes (K,N) int8, w_scale (N,) f32."""
     M, K = qx.shape
     _, N = codes.shape
@@ -78,7 +73,7 @@ def w4a8_matmul(qx: jnp.ndarray, x_scale: jnp.ndarray, codes: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qx, x_scale, codes, ws2d)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs import SHAPES, get_config, registry
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.distributed import runtime
 from repro.launch import hlo_analysis as hlo
 from repro.launch.mesh import make_production_mesh
 from repro.models import api
@@ -168,7 +169,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     specs = api.input_specs(cfg, shape)
 
     t0 = time.time()
-    with mesh:
+    with runtime.use_mesh(mesh):
         if shape.kind == "train":
             optcfg = opt_mod.AdamWConfig(
                 quantize_moments=cfg.param_count() > 5e10)

@@ -11,6 +11,13 @@ import math
 import jax
 
 
+def _auto(n: int):
+    """Auto axis types: GSPMD propagates shardings from the jit boundaries
+    and ``with_sharding_constraint`` pins (``jax.make_mesh`` defaults to
+    Explicit axes, which every program here would then have to annotate)."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def _validate_shape(shape, devices, *, what):
     """Raise a readable error before jax.make_mesh fails opaquely."""
     n = len(devices)
@@ -42,7 +49,7 @@ def make_production_mesh(shape=(16, 16), *, multi_pod: bool = False):
             f"make_production_mesh: shape {shape} must have {len(axes)} axes {axes}"
         )
     _validate_shape(shape, jax.devices(), what="make_production_mesh")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_test_mesh(devices=None, shape=None):
@@ -60,4 +67,5 @@ def make_test_mesh(devices=None, shape=None):
     if len(shape) != 2:
         raise ValueError(f"make_test_mesh: shape {shape} must be (dp, tp)")
     _validate_shape(shape, devices, what="make_test_mesh")
-    return jax.make_mesh(shape, ("data", "model"), devices=devices)
+    return jax.make_mesh(shape, ("data", "model"), axis_types=_auto(2),
+                         devices=devices)

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import api
 from repro.serve import pages
@@ -204,6 +205,7 @@ def main(argv=None):
         if not priorities:
             ap.error("--priority must name at least one SLA class")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
@@ -212,13 +214,12 @@ def main(argv=None):
     params = api.init_params(cfg, jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
 
-    mesh = None
-    if args.tp > 1:
-        if jax.device_count() < args.tp:
-            ap.error(f"--tp {args.tp} needs >= {args.tp} devices, have "
-                     f"{jax.device_count()} (see module docstring for "
-                     f"forcing host devices)")
-        mesh = make_test_mesh(shape=(1, args.tp))
+    if args.tp < 1 or jax.device_count() < args.tp:
+        ap.error(f"--tp {args.tp} needs >= {args.tp} devices, have "
+                 f"{jax.device_count()} (see module docstring for "
+                 f"forcing host devices)")
+    # exactly tp devices: --tp 1 is one device however many the host has
+    mesh = make_test_mesh(jax.devices()[:args.tp], shape=(1, args.tp))
 
     if args.continuous:
         # pages AND prefill chunks must both tile the cache

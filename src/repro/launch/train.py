@@ -23,6 +23,7 @@ import numpy as np
 from repro.ckpt.manager import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, DataLoader
+from repro.distributed import runtime
 from repro.launch.mesh import make_test_mesh
 from repro.models import api
 from repro.train import optimizer as opt_mod
@@ -30,7 +31,7 @@ from repro.train import step as step_mod
 
 
 def build(cfg, optcfg, mesh, key):
-    with mesh:
+    with runtime.use_mesh(mesh):
         params = api.init_params(cfg, key)
         opt_state = opt_mod.init_state(params, optcfg)
     step = step_mod.make_train_step(cfg, optcfg, mesh, params, opt_state)
@@ -85,7 +86,7 @@ def main(argv=None) -> Dict[str, Any]:
 
     losses = []
     step_times = []
-    with mesh:
+    with runtime.use_mesh(mesh):
         for i in range(start_step, args.steps):
             batch_np = next(loader)
             batch = {k: jnp.asarray(v) for k, v in batch_np.items()}

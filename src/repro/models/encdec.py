@@ -79,10 +79,13 @@ def encode(params, frontend: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
                          num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                          head_dim=cfg.resolved_head_dim, positions=positions,
                          rope_theta=cfg.rope_theta, causal=False,
-                         use_pallas=cfg.use_pallas)
+                         kernels=cfg.kernels,
+                         model_axis=cfg.parallel.model_axis,
+                         batch_axes=cfg.parallel.batch_axes)
         x = x + h
         y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
-        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"])
+        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"],
+                         cfg.kernels)
         return x, None
 
     if cfg.parallel.remat != "none":
@@ -109,26 +112,31 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
         h = L.attn_apply(p["self"], L.rmsnorm(x, p["ln_self"], cfg.norm_eps),
                          num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                          head_dim=hd, positions=positions,
-                         rope_theta=cfg.rope_theta, use_pallas=cfg.use_pallas)
+                         rope_theta=cfg.rope_theta, kernels=cfg.kernels,
+                         model_axis=cfg.parallel.model_axis,
+                         batch_axes=cfg.parallel.batch_axes)
         x = x + h
         xn = L.rmsnorm(x, p["ln_cross"], cfg.norm_eps)
         Bx, Tx, _ = enc.shape
-        ck = L.linear(enc, p["cross"]["wk"]).reshape(Bx, Tx, cfg.num_kv_heads, hd).transpose(0, 2, 1, 3)
-        cv = L.linear(enc, p["cross"]["wv"]).reshape(Bx, Tx, cfg.num_kv_heads, hd).transpose(0, 2, 1, 3)
+        ck = L.linear(enc, p["cross"]["wk"], cfg.kernels).reshape(Bx, Tx, cfg.num_kv_heads, hd).transpose(0, 2, 1, 3)
+        cv = L.linear(enc, p["cross"]["wv"], cfg.kernels).reshape(Bx, Tx, cfg.num_kv_heads, hd).transpose(0, 2, 1, 3)
         h = L.attn_apply(p["cross"], xn, num_heads=cfg.num_heads,
                          num_kv_heads=cfg.num_kv_heads, head_dim=hd,
                          positions=positions, rope_theta=cfg.rope_theta,
-                         kv=(ck, cv), use_pallas=cfg.use_pallas)
+                         kv=(ck, cv), kernels=cfg.kernels,
+                         model_axis=cfg.parallel.model_axis,
+                         batch_axes=cfg.parallel.batch_axes)
         x = x + h
         y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
-        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"])
+        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"],
+                         cfg.kernels)
         return x, None
 
     if cfg.parallel.remat != "none":
         layer = jax.checkpoint(layer)
     x, _ = jax.lax.scan(layer, x, params["dec_blocks"])
     x = L.rmsnorm(x, params["ln_final"], cfg.norm_eps)
-    logits = L.linear(x, params["lm_head"]).astype(jnp.float32)
+    logits = L.linear(x, params["lm_head"], cfg.kernels).astype(jnp.float32)
     return logits, 0.0
 
 
@@ -147,8 +155,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         Bx, Tx, _ = enc.shape
 
         def proj(p):
-            ck = L.linear(enc, p["cross"]["wk"]).reshape(Bx, Tx, cfg.num_kv_heads, hd)
-            cv = L.linear(enc, p["cross"]["wv"]).reshape(Bx, Tx, cfg.num_kv_heads, hd)
+            ck = L.linear(enc, p["cross"]["wk"], cfg.kernels).reshape(Bx, Tx, cfg.num_kv_heads, hd)
+            cv = L.linear(enc, p["cross"]["wv"], cfg.kernels).reshape(Bx, Tx, cfg.num_kv_heads, hd)
             return ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3)
 
         ck, cv = jax.vmap(proj)(params["dec_blocks"])
@@ -167,7 +175,8 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig):
     def layer(x, inputs):
         p, kc, vc, ck, cv = inputs
         xn = L.rmsnorm(x, p["ln_self"], cfg.norm_eps)
-        q, k, v = L.qkv_project(p["self"], xn, cfg.num_heads, cfg.num_kv_heads, hd)
+        q, k, v = L.qkv_project(p["self"], xn, cfg.num_heads, cfg.num_kv_heads, hd,
+                                cfg.kernels)
         q = L.rope(q, positions, cfg.rope_theta)
         k = L.rope(k, positions, cfg.rope_theta)
         kc = L.cache_write(kc, k[:, :, 0:1], pos,
@@ -179,24 +188,25 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig):
         o = ops.decode_attention(q, kc, vc, pos + 1, dist_axis=dist_axis,
                                  batch_axes=cfg.parallel.batch_axes)
         x = x + L.linear(o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.num_heads * hd),
-                         p["self"]["wo"])
+                         p["self"]["wo"], cfg.kernels)
         xn = L.rmsnorm(x, p["ln_cross"], cfg.norm_eps)
-        qx = L.linear(xn, p["cross"]["wq"]).reshape(B, 1, cfg.num_heads, hd).transpose(0, 2, 1, 3)
+        qx = L.linear(xn, p["cross"]["wq"], cfg.kernels).reshape(B, 1, cfg.num_heads, hd).transpose(0, 2, 1, 3)
         Tx = ck.shape[2]
         o = ops.decode_attention(qx, ck, cv, jnp.full((B,), Tx, jnp.int32),
                                  dist_axis=dist_axis,
                                  batch_axes=cfg.parallel.batch_axes)
         x = x + L.linear(o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.num_heads * hd),
-                         p["cross"]["wo"])
+                         p["cross"]["wo"], cfg.kernels)
         y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
-        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"])
+        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"],
+                         cfg.kernels)
         return x, (kc, vc)
 
     x, (k, v) = jax.lax.scan(
         layer, x, (params["dec_blocks"], cache["k"], cache["v"],
                    cache["cross_k"], cache["cross_v"]))
     x = L.rmsnorm(x, params["ln_final"], cfg.norm_eps)
-    logits = L.linear(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    logits = L.linear(x[:, 0], params["lm_head"], cfg.kernels).astype(jnp.float32)
     new_cache = dict(cache)
     new_cache.update({"k": k, "v": v, "len": cache["len"] + 1})
     return logits, new_cache

@@ -78,18 +78,18 @@ def _ssm_branch(p, x, cfg: ModelConfig, state=None):
     """x: (B, T, d) -> (out, new_state (B, d, N))."""
     ssm_p = p["ssm"]
     pin = _pin(cfg) or (lambda a: a)
-    h = jax.nn.silu(L.linear(x, ssm_p["w_in"]))
+    h = jax.nn.silu(L.linear(x, ssm_p["w_in"], cfg.kernels))
     delta = jax.nn.softplus(
-        L.linear(pin(L.linear(x, ssm_p["w_delta"])), ssm_p["w_delta_up"])
+        L.linear(pin(L.linear(x, ssm_p["w_delta"], cfg.kernels)),
+                 ssm_p["w_delta_up"], cfg.kernels)
     ).astype(jnp.float32)
     A = -jnp.exp(ssm_p["A_log"].astype(jnp.float32))
-    Bm = L.linear(x, ssm_p["w_B"]).astype(jnp.float32)
-    Cm = L.linear(x, ssm_p["w_C"]).astype(jnp.float32)
+    Bm = L.linear(x, ssm_p["w_B"], cfg.kernels).astype(jnp.float32)
+    Cm = L.linear(x, ssm_p["w_C"], cfg.kernels).astype(jnp.float32)
     y, new_state = ops.selective_scan(h, delta, A, Bm, Cm, state,
-                                      use_pallas=cfg.use_pallas,
                                       algorithm=cfg.ssm_scan)
     y = y + h * ssm_p["D"].astype(h.dtype)
-    return L.linear(pin(y), ssm_p["w_out"]), new_state
+    return L.linear(pin(y), ssm_p["w_out"], cfg.kernels), new_state
 
 
 def _embed_decode(params, tokens: jnp.ndarray, cfg: ModelConfig):
@@ -108,21 +108,21 @@ def _fuse_tail(p, x, xn, o, sstate, cfg: ModelConfig):
     pin = _pin(cfg) or (lambda a: a)
     attn_out = L.linear(
         pin(o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.num_heads * hd)),
-        p["attn"]["wo"])
+        p["attn"]["wo"], cfg.kernels)
     ssm_out, new_state = _ssm_branch(p, xn, cfg, state=sstate)
     fused = 0.5 * (L.rmsnorm(attn_out, p["ln_attn_out"], cfg.norm_eps)
                    + L.rmsnorm(ssm_out, p["ln_ssm_out"], cfg.norm_eps))
     x = x + fused
     y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
     x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"],
-                     pin_fn=_pin(cfg))
+                     cfg.kernels, pin_fn=_pin(cfg))
     return x, new_state
 
 
 def _logits_head(params, x: jnp.ndarray, cfg: ModelConfig):
     """Shared logits tail: final norm + LM head at the single position."""
     x = L.rmsnorm(x, params["ln_final"], cfg.norm_eps)
-    return L.linear(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    return L.linear(x[:, 0], params["lm_head"], cfg.kernels).astype(jnp.float32)
 
 
 def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, **_):
@@ -141,20 +141,23 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, **_):
         attn_out = L.attn_apply(
             p["attn"], xn, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, positions=positions,
-            rope_theta=cfg.rope_theta, window=window, use_pallas=cfg.use_pallas)
+            rope_theta=cfg.rope_theta, window=window, kernels=cfg.kernels,
+            model_axis=cfg.parallel.model_axis,
+            batch_axes=cfg.parallel.batch_axes)
         ssm_out, _ = _ssm_branch(p, xn, cfg)
         fused = 0.5 * (L.rmsnorm(attn_out, p["ln_attn_out"], cfg.norm_eps)
                        + L.rmsnorm(ssm_out, p["ln_ssm_out"], cfg.norm_eps))
         x = x + fused
         y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
-        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"])
+        x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"],
+                         cfg.kernels)
         return x, jnp.zeros((), jnp.float32)
 
     if cfg.parallel.remat != "none":
         layer = jax.checkpoint(layer)
     x, _ = jax.lax.scan(layer, x, params["blocks"])
     x = L.rmsnorm(x, params["ln_final"], cfg.norm_eps)
-    logits = L.linear(x, params["lm_head"]).astype(jnp.float32)
+    logits = L.linear(x, params["lm_head"], cfg.kernels).astype(jnp.float32)
     return logits, 0.0
 
 
@@ -198,14 +201,14 @@ def paged_decode_step(params, cache, table, tokens: jnp.ndarray,
         p, kc, vc, sstate = inputs
         xn = L.rmsnorm(x, p["ln_in"], cfg.norm_eps)
         q, k, v = L.qkv_project(p["attn"], xn, cfg.num_heads,
-                                cfg.num_kv_heads, hd)
+                                cfg.num_kv_heads, hd, cfg.kernels)
         q = L.rope(q, positions, cfg.rope_theta)
         k = L.rope(k, positions, cfg.rope_theta)
         kc = L.paged_cache_write(kc, k, table, pos, write)
         vc = L.paged_cache_write(vc, v, table, pos, write)
         o = ops.paged_decode_attention(q, kc, vc, table, pos + 1,
                                        window=window,
-                                       use_pallas=cfg.use_pallas,
+                                       kernels=cfg.kernels,
                                        model_axis=cfg.parallel.model_axis,
                                        batch_axes=cfg.parallel.batch_axes)
         x, new_state = _fuse_tail(p, x, xn, o, sstate, cfg)
@@ -228,7 +231,8 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig):
     def layer(x, inputs):
         p, kc, vc, sstate = inputs
         xn = L.rmsnorm(x, p["ln_in"], cfg.norm_eps)
-        q, k, v = L.qkv_project(p["attn"], xn, cfg.num_heads, cfg.num_kv_heads, hd)
+        q, k, v = L.qkv_project(p["attn"], xn, cfg.num_heads, cfg.num_kv_heads, hd,
+                                cfg.kernels)
         q = L.rope(q, positions, cfg.rope_theta)
         k = L.rope(k, positions, cfg.rope_theta)
         S = kc.shape[2]

@@ -20,20 +20,20 @@ def dense_init(key, in_dim: int, out_dim: int, dtype=jnp.float32) -> jnp.ndarray
     return jax.random.uniform(key, (in_dim, out_dim), dtype, -scale, scale)
 
 
-def linear(x: jnp.ndarray, w, use_pallas: Optional[bool] = None) -> jnp.ndarray:
+def linear(x: jnp.ndarray, w, kernels: str) -> jnp.ndarray:
     """Apply a linear map; ``w`` is a raw (in,out) array or a QuantizedLinear.
 
     The quantized branch is the ITA device datapath: INT8 activations times
     hardwired INT4 codes (see core/quant.py, kernels/w4a8_matmul.py).
-    ``use_pallas`` selects the Pallas W4A8 kernel for the quantized branch
-    (None defers to the ``kernels.ops`` module default).
+    ``kernels`` (kernels/ops.py) selects the W4A8 kernel or its oracle for
+    the quantized branch.
     """
     if isinstance(w, quant.QuantizedLinear):
         shape = x.shape[:-1]
         x2 = x.reshape(-1, x.shape[-1])
         qx, xs = quant.quantize_activations_int8(x2)
         y = ops.w4a8_matmul(qx, xs, w.codes, w.scales, out_dtype=x.dtype,
-                            use_pallas=use_pallas)
+                            kernels=kernels)
         return y.reshape(*shape, w.codes.shape[-1])
     return x @ w.astype(x.dtype)
 
@@ -60,16 +60,16 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0) -> jnp.
 
 
 def swiglu(x: jnp.ndarray, w1, w3, w2,
-           use_pallas: Optional[bool] = None, pin_fn=None) -> jnp.ndarray:
+           kernels: str, pin_fn=None) -> jnp.ndarray:
     """FFN(x) = W2 . (silu(W1 x) * (W3 x)) — eq. (4)/(5) of the paper.
 
     ``pin_fn`` (serve TP exactness, DESIGN.md §11) is applied to the hidden
     activation before the W2 contraction — sharding.pin_tp_exact gathers a
     d_ff-sharded hidden so the down-projection is never split."""
-    h = jax.nn.silu(linear(x, w1, use_pallas)) * linear(x, w3, use_pallas)
+    h = jax.nn.silu(linear(x, w1, kernels)) * linear(x, w3, kernels)
     if pin_fn is not None:
         h = pin_fn(h)
-    return linear(h, w2, use_pallas)
+    return linear(h, w2, kernels)
 
 
 # ----------------------------------------------------------------------------
@@ -87,26 +87,29 @@ def attn_init(key, d_model: int, num_heads: int, num_kv_heads: int,
 
 
 def qkv_project(p: dict, x: jnp.ndarray, num_heads: int, num_kv_heads: int,
-                head_dim: int, use_pallas: Optional[bool] = None):
+                head_dim: int, kernels: str):
     """The ITA device phase of attention: static linear maps only."""
     B, T, _ = x.shape
-    q = linear(x, p["wq"], use_pallas).reshape(B, T, num_heads, head_dim).transpose(0, 2, 1, 3)
-    k = linear(x, p["wk"], use_pallas).reshape(B, T, num_kv_heads, head_dim).transpose(0, 2, 1, 3)
-    v = linear(x, p["wv"], use_pallas).reshape(B, T, num_kv_heads, head_dim).transpose(0, 2, 1, 3)
+    q = linear(x, p["wq"], kernels).reshape(B, T, num_heads, head_dim).transpose(0, 2, 1, 3)
+    k = linear(x, p["wk"], kernels).reshape(B, T, num_kv_heads, head_dim).transpose(0, 2, 1, 3)
+    v = linear(x, p["wv"], kernels).reshape(B, T, num_kv_heads, head_dim).transpose(0, 2, 1, 3)
     return q, k, v
 
 
 def attn_apply(p: dict, x: jnp.ndarray, *, num_heads: int, num_kv_heads: int,
                head_dim: int, positions: jnp.ndarray, rope_theta: float,
                window: Optional[int] = None, softcap: Optional[float] = None,
-               causal: bool = True, use_pallas: bool = False,
-               kv: Optional[tuple] = None, pin_fn=None) -> jnp.ndarray:
+               causal: bool = True, kernels: str,
+               kv: Optional[tuple] = None, pin_fn=None,
+               model_axis: Optional[str] = None,
+               batch_axes: tuple = ()) -> jnp.ndarray:
     """Full attention block (prefill/training path). ``kv`` overrides K/V
     (cross-attention: keys/values from another sequence, no rope).
     ``pin_fn`` gathers the head-sharded attention output before the wo
-    contraction (serve TP exactness, DESIGN.md §11)."""
+    contraction (serve TP exactness, DESIGN.md §11); ``model_axis`` and
+    ``batch_axes`` route a Pallas kernel per shard on a TP mesh."""
     B, T, _ = x.shape
-    q, k, v = qkv_project(p, x, num_heads, num_kv_heads, head_dim)
+    q, k, v = qkv_project(p, x, num_heads, num_kv_heads, head_dim, kernels)
     if kv is None:
         q = rope(q, positions, rope_theta)
         k = rope(k, positions, rope_theta)
@@ -114,11 +117,12 @@ def attn_apply(p: dict, x: jnp.ndarray, *, num_heads: int, num_kv_heads: int,
         k, v = kv
         causal, window = False, None
     o = ops.attention(q, k, v, causal=causal, window=window, softcap=softcap,
-                      use_pallas=use_pallas)
+                      kernels=kernels, model_axis=model_axis,
+                      batch_axes=batch_axes)
     o = o.transpose(0, 2, 1, 3).reshape(B, T, num_heads * head_dim)
     if pin_fn is not None:
         o = pin_fn(o)
-    return linear(o, p["wo"])
+    return linear(o, p["wo"], kernels)
 
 
 def cache_write(cache: jnp.ndarray, new: jnp.ndarray, pos: jnp.ndarray,

@@ -84,12 +84,13 @@ def _time_mix(p, x, cfg, state=None, x_prev=None):
     xs = _token_shift(x, x_prev)
     mix = p["mix"].astype(x.dtype)
     xr, xk, xv, xg, xw = (x + mix[i] * (xs - x) for i in range(5))
-    r = linear(xr, p["wr"]).reshape(B, T, H, HEAD_DIM).transpose(0, 2, 1, 3)
-    k = linear(xk, p["wk"]).reshape(B, T, H, HEAD_DIM).transpose(0, 2, 1, 3)
-    v = linear(xv, p["wv"]).reshape(B, T, H, HEAD_DIM).transpose(0, 2, 1, 3)
+    r = linear(xr, p["wr"], cfg.kernels).reshape(B, T, H, HEAD_DIM).transpose(0, 2, 1, 3)
+    k = linear(xk, p["wk"], cfg.kernels).reshape(B, T, H, HEAD_DIM).transpose(0, 2, 1, 3)
+    v = linear(xv, p["wv"], cfg.kernels).reshape(B, T, H, HEAD_DIM).transpose(0, 2, 1, 3)
     pin = _pin(cfg)
-    g = jax.nn.silu(linear(xg, p["wg"]))
-    dw = linear(pin(jnp.tanh(linear(xw, p["w_lora_a"]))), p["w_lora_b"])
+    g = jax.nn.silu(linear(xg, p["wg"], cfg.kernels))
+    dw = linear(pin(jnp.tanh(linear(xw, p["w_lora_a"], cfg.kernels))),
+                p["w_lora_b"], cfg.kernels)
     w = jnp.exp(-jnp.exp((p["w0"].astype(jnp.float32) + dw.astype(jnp.float32))))
     w = w.reshape(B, T, H, HEAD_DIM).transpose(0, 2, 1, 3)
     if cfg.rwkv_chunk and T > 1:
@@ -99,20 +100,20 @@ def _time_mix(p, x, cfg, state=None, x_prev=None):
     else:
         out, new_state = ops.rwkv6(r, k, v, w.astype(r.dtype),
                                    p["u"].astype(jnp.float32), state,
-                                   use_pallas=cfg.use_pallas)
+                                   kernels=cfg.kernels)
     out = pin(out.transpose(0, 2, 1, 3).reshape(B, T, d))
     out = rmsnorm(out, p["ln_x"], cfg.norm_eps) * g
-    return linear(pin(out), p["wo"]), new_state, x[:, -1]
+    return linear(pin(out), p["wo"], cfg.kernels), new_state, x[:, -1]
 
 
-def _channel_mix(p, x, x_prev=None, pin_fn=None):
+def _channel_mix(p, x, cfg, x_prev=None, pin_fn=None):
     xs = _token_shift(x, x_prev)
     mix = p["mix"].astype(x.dtype)
     xk = x + mix[1] * (xs - x)
-    h = jnp.square(jax.nn.relu(linear(xk, p["cm_k"])))
+    h = jnp.square(jax.nn.relu(linear(xk, p["cm_k"], cfg.kernels)))
     if pin_fn is not None:
         h = pin_fn(h)
-    return linear(h, p["cm_v"]), x[:, -1]
+    return linear(h, p["cm_v"], cfg.kernels), x[:, -1]
 
 
 def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, **_):
@@ -126,7 +127,7 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, **_):
             x = _shd.pin_batch(x, cfg)
         h, _, _ = _time_mix(p, rmsnorm(x, p["ln_tm"], cfg.norm_eps), cfg)
         x = x + h
-        h, _ = _channel_mix(p, rmsnorm(x, p["ln_cm"], cfg.norm_eps),
+        h, _ = _channel_mix(p, rmsnorm(x, p["ln_cm"], cfg.norm_eps), cfg,
                             pin_fn=_pin(cfg) if cfg.parallel.exact_tp else None)
         return x + h, jnp.zeros((), jnp.float32)
 
@@ -134,7 +135,7 @@ def forward(params, tokens: jnp.ndarray, cfg: ModelConfig, **_):
         layer = jax.checkpoint(layer)
     x, _ = jax.lax.scan(layer, x, params["blocks"])
     x = rmsnorm(x, params["ln_final"], cfg.norm_eps)
-    logits = linear(x, params["lm_head"]).astype(jnp.float32)
+    logits = linear(x, params["lm_head"], cfg.kernels).astype(jnp.float32)
     return logits, 0.0
 
 
@@ -160,7 +161,7 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig):
         h, new_wkv, last_tm = _time_mix(
             p, rmsnorm(x, p["ln_tm"], cfg.norm_eps), cfg, state=wkv, x_prev=x_tm)
         x = x + h
-        h, last_cm = _channel_mix(p, rmsnorm(x, p["ln_cm"], cfg.norm_eps),
+        h, last_cm = _channel_mix(p, rmsnorm(x, p["ln_cm"], cfg.norm_eps), cfg,
                                   x_prev=x_cm,
                                   pin_fn=_pin(cfg) if cfg.parallel.exact_tp
                                   else None)
@@ -169,6 +170,6 @@ def decode_step(params, cache, tokens: jnp.ndarray, cfg: ModelConfig):
     x, (wkv, x_tm, x_cm) = jax.lax.scan(
         layer, x, (params["blocks"], cache["wkv"], cache["x_tm"], cache["x_cm"]))
     x = rmsnorm(x, params["ln_final"], cfg.norm_eps)
-    logits = linear(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    logits = linear(x[:, 0], params["lm_head"], cfg.kernels).astype(jnp.float32)
     return logits, {"wkv": wkv, "x_tm": x_tm, "x_cm": x_cm,
                     "len": cache["len"] + 1}

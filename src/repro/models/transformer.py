@@ -107,14 +107,16 @@ def _block_apply(p, x, spec, cfg: ModelConfig, positions):
         num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
         head_dim=cfg.resolved_head_dim, positions=positions,
         rope_theta=cfg.rope_theta, window=spec.window, softcap=cfg.softcap,
-        use_pallas=cfg.use_pallas, pin_fn=_pin(cfg))
+        kernels=cfg.kernels,
+        model_axis=cfg.parallel.model_axis,
+        batch_axes=cfg.parallel.batch_axes, pin_fn=_pin(cfg))
     x = x + h
     y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
     if cfg.moe:
         out, aux = moe_mod.moe_apply(p["moe"], y, cfg.moe)
     else:
         out, aux = L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"], p["mlp"]["w2"],
-                            pin_fn=_pin(cfg)), 0.0
+                            cfg.kernels, pin_fn=_pin(cfg)), 0.0
     return x + out, aux
 
 
@@ -124,7 +126,9 @@ def _cross_apply(p, x, cross_kv, cfg: ModelConfig):
         p["attn"], L.rmsnorm(x, p["ln"], cfg.norm_eps),
         num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads, head_dim=hd,
         positions=jnp.zeros((1,), jnp.int32), rope_theta=cfg.rope_theta,
-        kv=cross_kv, use_pallas=cfg.use_pallas, pin_fn=_pin(cfg))
+        kv=cross_kv, kernels=cfg.kernels,
+        model_axis=cfg.parallel.model_axis,
+        batch_axes=cfg.parallel.batch_axes, pin_fn=_pin(cfg))
     return x + jnp.tanh(p["gate"]).astype(x.dtype) * h
 
 
@@ -132,8 +136,8 @@ def _cross_kv(p, frontend: jnp.ndarray, cfg: ModelConfig):
     """Project stub modality embeddings to cross K/V (device-phase op)."""
     Bx, Tx, _ = frontend.shape
     hd = cfg.resolved_head_dim
-    k = L.linear(frontend, p["attn"]["wk"]).reshape(Bx, Tx, cfg.num_kv_heads, hd)
-    v = L.linear(frontend, p["attn"]["wv"]).reshape(Bx, Tx, cfg.num_kv_heads, hd)
+    k = L.linear(frontend, p["attn"]["wk"], cfg.kernels).reshape(Bx, Tx, cfg.num_kv_heads, hd)
+    v = L.linear(frontend, p["attn"]["wv"], cfg.kernels).reshape(Bx, Tx, cfg.num_kv_heads, hd)
     return k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
 
@@ -200,7 +204,7 @@ def _block_qkv(pj, x, positions, cfg: ModelConfig):
     """Shared block head for prefill/decode: pre-norm, QKV projection, rope."""
     xn = L.rmsnorm(x, pj["ln_attn"], cfg.norm_eps)
     q, k, v = L.qkv_project(pj["attn"], xn, cfg.num_heads, cfg.num_kv_heads,
-                            cfg.resolved_head_dim)
+                            cfg.resolved_head_dim, cfg.kernels)
     q = L.rope(q, positions, cfg.rope_theta)
     k = L.rope(k, positions, cfg.rope_theta)
     return q, k, v
@@ -214,13 +218,13 @@ def _block_tail(pj, x, o, cfg: ModelConfig):
     pin = _pin(cfg)
     if pin is not None:
         o = pin(o)
-    x = x + L.linear(o, pj["attn"]["wo"])
+    x = x + L.linear(o, pj["attn"]["wo"], cfg.kernels)
     y = L.rmsnorm(x, pj["ln_mlp"], cfg.norm_eps)
     if cfg.moe:
         out, _ = moe_mod.moe_apply(pj["moe"], y, cfg.moe)
     else:
         out = L.swiglu(y, pj["mlp"]["w1"], pj["mlp"]["w3"], pj["mlp"]["w2"],
-                       pin_fn=pin)
+                       cfg.kernels, pin_fn=pin)
     return x + out
 
 
@@ -238,7 +242,7 @@ def _logits_head(params, x: jnp.ndarray, cfg: ModelConfig):
     the head math their token-identity contract depends on."""
     x = L.rmsnorm(x, params["ln_final"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = L.linear(x, head).astype(jnp.float32)
+    logits = L.linear(x, head, cfg.kernels).astype(jnp.float32)
     if cfg.final_softcap:
         logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
     return logits
@@ -308,7 +312,9 @@ def prefill(params, cache, tokens: jnp.ndarray, cfg: ModelConfig,
             kc = jax.lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
             vc = jax.lax.dynamic_update_slice(vc, v, (0, 0, 0, 0))
             o = ops.attention(q, k, v, causal=True, window=spec.window,
-                              softcap=cfg.softcap, use_pallas=cfg.use_pallas)
+                              softcap=cfg.softcap, kernels=cfg.kernels,
+                              model_axis=cfg.parallel.model_axis,
+                              batch_axes=cfg.parallel.batch_axes)
             x = _block_tail(pj, x, o, cfg)
             new_k.append(kc)
             new_v.append(vc)
@@ -338,7 +344,7 @@ def prefill(params, cache, tokens: jnp.ndarray, cfg: ModelConfig,
         x_last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
         advance = jnp.asarray(true_len, jnp.int32)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = L.linear(x_last, head).astype(jnp.float32)
+    logits = L.linear(x_last, head, cfg.kernels).astype(jnp.float32)
     if cfg.final_softcap:
         logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
     new_cache = dict(cache)
@@ -400,7 +406,9 @@ def prefill_chunk(params, cache, tokens: jnp.ndarray, true_len, cfg: ModelConfig
                 c, vv, (0, i, 0)))(vc, v, start)
             o = ops.chunk_attention(q, kc, vc, positions, window=spec.window,
                                     softcap=cfg.softcap,
-                                    use_pallas=cfg.use_pallas)
+                                    kernels=cfg.kernels,
+                                    model_axis=cfg.parallel.model_axis,
+                                    batch_axes=cfg.parallel.batch_axes)
             x = _block_tail(pj, x, o, cfg)
             new_k.append(kc)
             new_v.append(vc)
@@ -469,7 +477,7 @@ def paged_decode_step(params, cache, table, tokens: jnp.ndarray,
                 vc = L.paged_cache_write(vc, v, table, pos, write)
                 o = ops.paged_decode_attention(
                     q, kc, vc, table, pos + 1, window=spec.window,
-                    softcap=cfg.softcap, use_pallas=cfg.use_pallas,
+                    softcap=cfg.softcap, kernels=cfg.kernels,
                     model_axis=cfg.parallel.model_axis,
                     batch_axes=cfg.parallel.batch_axes)
             else:

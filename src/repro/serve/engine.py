@@ -53,7 +53,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.splitbrain import TrafficMeter, TrafficModel
+from repro.distributed import runtime
 from repro.distributed import sharding as shd
+from repro.kernels import ops
 from repro.launch.mesh import make_test_mesh
 from repro.models import api
 from repro.serve import pages as pages_mod
@@ -68,14 +70,20 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                  num_pages: Optional[int] = None,
                  paged_attn: str = "inplace", prefix_cache: str = "off",
                  kv_dtype: str = "bf16"):
+        # the default mesh is ONE device: tensor parallelism is asked for
+        # with an explicit (1, tp) mesh, never inherited from the host
+        self.mesh = mesh if mesh is not None else make_test_mesh(
+            jax.devices()[:1])
         # Serve programs trace with exact_tp: every down-projection input is
         # gathered before its contraction (shd.pin_tp_exact), so the sharded
         # step is BITWISE identical to single-device greedy — the serve
         # token-identity contract (DESIGN.md §11).  No-op on a 1-device mesh.
+        # The kernel set (kernels/ops.py) is chosen here, once, from the
+        # mesh's platform: compiled Pallas on TPU, jnp oracles elsewhere.
         cfg = dataclasses.replace(
-            cfg, parallel=dataclasses.replace(cfg.parallel, exact_tp=True))
+            cfg, parallel=dataclasses.replace(cfg.parallel, exact_tp=True),
+            kernels=ops.for_platform(self.mesh.devices.flat[0].platform))
         self.cfg = cfg
-        self.mesh = mesh if mesh is not None else make_test_mesh()
         # tensor-parallel degree of the serving mesh (DESIGN.md §11): the
         # stacked params shard COLUMN-only (serve_param_pspecs — row cuts
         # would split contraction sums and break bf16 token identity) and
@@ -85,7 +93,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                     if cfg.parallel.model_axis in self.mesh.axis_names else 1)
         self._param_sh = shd.with_sharding(
             self.mesh, shd.serve_param_pspecs(params, cfg, self.mesh))
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             self.params = jax.device_put(params, self._param_sh)
         self.max_len = max_len
         self.fused = fused
@@ -254,7 +262,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                 f"request does not fit the cache: prompt_len={T0} + "
                 f"max_new={max_new} needs {T0 - 1 + max_new} positions but "
                 f"max_len={self.max_len}")
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             if not fused:
                 cache = api.init_cache(cfg, B, self.max_len, frontend=frontend,
                                        params=self.params)
@@ -396,7 +404,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             # max_len: the page table is a no-op and the dense layout IS
             # the occupancy-proportional one — skip pool bookkeeping.
             self._paging_active = False
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 cache = api.init_cache(self.cfg, n_slots, self.max_len)
                 return jax.device_put(cache, self._cache_shardings(shape))
         if (self._paged_attn == "inplace"
@@ -430,7 +438,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                                            self._kv_dtype)
             if self._kv_dtype != "bf16" else None)
         self._pager.prefix_on = self.prefix_sharing_active()
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return pages_mod.make_pool(shape, ba, sa, pool.num_pages,
                                        self.page_size,
                                        shardings=self._pool_sh,
@@ -458,7 +466,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             raise InvalidRequestError(
                 "prefill_slot needs a non-empty prompt (the last token "
                 "seeds decoding)")
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             cache = api.init_cache(self.cfg, 1, self.max_len)
             if T0 > 1:
                 width = slots_mod.bucket(T0 - 1)
@@ -492,7 +500,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
 
     def new_request_cache(self):
         """Fresh B=1 cache for chunked prefill (slot-shaped, empty)."""
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             cache = api.init_cache(self.cfg, 1, self.max_len)
             return jax.device_put(cache, self._b1_shardings())
 
@@ -503,7 +511,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
         if self._b1_shape is None:
             self._b1_shape = jax.eval_shape(
                 lambda: api.init_cache(self.cfg, 1, self.max_len))
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return self.paged_seed(cache, slot, cached_len,
                                    self._slot_axes(), self._slot_seq_axes(),
                                    self._b1_shape)
@@ -540,7 +548,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                 chunk_fn, donate_argnums=(1,),
                 in_shardings=(self._param_sh, b1_sh, None, None),
                 out_shardings=b1_sh)
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return self._chunk_jit[W](self.params, cache, chunk[None, :],
                                       jnp.int32(true_w))
 
@@ -552,7 +560,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
         land on the scratch page — fixed write count, no recompiles)."""
         if self._paging_active:
             n_tok = int(np.asarray(slot_cache["len"])[0])
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 return self.paged_insert(batched_cache, slot_cache, slot,
                                          self._slot_axes(),
                                          self._slot_seq_axes(), n_tok)
@@ -563,7 +571,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                     lambda: api.init_cache(self.cfg, self._slot_count,
                                            self.max_len))),
                 single_sh=self._b1_shardings())
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return self._slot_insert(batched_cache, slot_cache,
                                      jnp.int32(slot))
 
@@ -593,7 +601,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             corrupt = np.zeros((n,), bool)
         if self._paging_active:
             act = np.asarray(active, bool)
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 cache = self.paged_pre_step(cache, act, self._slot_axes(),
                                             self._slot_seq_axes())
             if self._paged_step is None:
@@ -634,7 +642,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                     in_shardings=(self._param_sh, self._pool_sh, repl,
                                   vec, vec, vec),
                     out_shardings=(vec, vec, self._pool_sh))
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 out = self._paged_step(self.params, cache,
                                        self._pager.table(),
                                        jnp.asarray(tokens, jnp.int32),
@@ -648,7 +656,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                 self._ragged_cfg, self.mesh, self.params, cache,
                 self._slot_axes(), cache_spec_fn=shd.serve_cache_pspecs,
                 param_spec_fn=shd.serve_param_pspecs)
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return self._slot_step_jit[n](
                 self.params, cache, jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(active, bool), jnp.asarray(corrupt, bool))
@@ -667,7 +675,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
         requests republish as they re-prefill, so sharing re-forms among
         the survivors.
         """
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             self.params = jax.device_put(self.params, self._param_sh)
         return self.init_slot_cache(n_slots)
 

@@ -41,6 +41,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.splitbrain import TrafficMeter, TrafficModel
+from repro.distributed import runtime
 from repro.distributed import sharding as shd
 from repro.kernels import ops
 from repro.launch.mesh import make_test_mesh
@@ -64,7 +65,8 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
 
     def __init__(self, cfg: ModelConfig, params, max_len: int = 256,
                  quantize: bool = True, jit: bool = True,
-                 use_pallas: bool = False, page_size: Optional[int] = None,
+                 kernels: Optional[str] = None,
+                 page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  paged_attn: str = "inplace", prefix_cache: str = "off",
                  kv_dtype: str = "bf16", mesh=None):
@@ -75,7 +77,8 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             raise ValueError(
                 "split-brain reference engine covers dense FFNs")
         self.cfg = cfg
-        self.mesh = mesh if mesh is not None else make_test_mesh()
+        self.mesh = mesh if mesh is not None else make_test_mesh(
+            jax.devices()[:1])
         # tensor-parallel degree of the serving mesh (DESIGN.md §11); tp == 1
         # (the 1-device test mesh) reproduces the single-device layout.
         self._tp = (int(self.mesh.shape[cfg.parallel.model_axis])
@@ -87,7 +90,9 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         self.host_params = params  # norms/embedding stay host-side floats
         self.max_len = max_len
         self.jit = jit
-        self.use_pallas = use_pallas
+        # kernel set (kernels/ops.py): chosen once, from the mesh's platform
+        self.kernels = kernels or ops.for_platform(
+            self.mesh.devices.flat[0].platform)
         # -- static hoisting: everything derivable from cfg/params is computed
         #    once here, not per decode_token call.
         self._hd = cfg.resolved_head_dim
@@ -119,7 +124,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         spec_fn = shd.param_pspecs if quantize else shd.serve_param_pspecs
         self._param_sh = shd.with_sharding(
             self.mesh, spec_fn(self._weights, cfg, self.mesh))
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             self._weights = jax.device_put(self._weights, self._param_sh)
         self._cache_sh: Dict[int, Any] = {}      # keyed by batch size
         # Pre-computed per-token boundary-crossing byte counts (shapes are
@@ -152,7 +157,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         self.meter.h2d("x_qkv_in", x.shape)
         q, k, v = L.qkv_project(layer_p["attn"], x, cfg.num_heads,
                                 cfg.num_kv_heads, self._hd,
-                                use_pallas=self.use_pallas)
+                                kernels=self.kernels)
         # K, V stream back to the host KV cache (eq. 7); Q accompanies them
         # in the same DMA (the paper counts K/V only — Q stays on-device in
         # the ASIC pipeline; we ship it because our "device" is a function).
@@ -161,16 +166,16 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
 
     def _device_attn_out(self, layer_p, attn):
         self.meter.h2d("attn_in", attn.shape)   # eq. 8
-        return L.linear(attn, layer_p["attn"]["wo"], self.use_pallas)
+        return L.linear(attn, layer_p["attn"]["wo"], self.kernels)
 
     def _device_ffn(self, layer_p, y):
         out = L.swiglu(y, layer_p["mlp"]["w1"], layer_p["mlp"]["w3"],
-                       layer_p["mlp"]["w2"], use_pallas=self.use_pallas)
+                       layer_p["mlp"]["w2"], kernels=self.kernels)
         return out
 
     def _device_logits(self, x):
         head = self._weights["head"]
-        logits = L.linear(x, head, self.use_pallas)
+        logits = L.linear(x, head, self.kernels)
         self.meter.d2h("logits", logits.shape)   # eq. 9
         return logits
 
@@ -225,7 +230,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         cfg = self.cfg
         B = token.shape[0]
         hd = self._hd
-        pl = self.use_pallas
+        kn = self.kernels
         # HOST: embedding lookup (vocabulary table, random access)
         x = weights["embed"][token][:, None, :].astype(self._dtype)
         positions = pos[:, None]
@@ -236,24 +241,24 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             xn = L.rmsnorm(x, p["ln_attn"], cfg.norm_eps)
             # DEVICE: QKV projection
             q, k, v = L.qkv_project(p["attn"], xn, cfg.num_heads,
-                                    cfg.num_kv_heads, hd, use_pallas=pl)
+                                    cfg.num_kv_heads, hd, kernels=kn)
             q = L.rope(q, positions, cfg.rope_theta)
             k = L.rope(k, positions, cfg.rope_theta)
             # HOST: KV-cache append + attention (discipline-specific)
             attn, kc, vc = kv_attend(kc, vc, q, k, v)
             attn = attn.transpose(0, 2, 1, 3).reshape(B, 1, cfg.num_heads * hd)
             # DEVICE: output projection;  HOST: residual add
-            x = x + L.linear(attn, p["attn"]["wo"], pl)
+            x = x + L.linear(attn, p["attn"]["wo"], kn)
             # HOST norm -> DEVICE FFN -> HOST residual
             y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
             x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"],
-                             p["mlp"]["w2"], use_pallas=pl)
+                             p["mlp"]["w2"], kernels=kn)
             return x, (kc, vc)
 
         x, (new_k, new_v) = jax.lax.scan(
             layer_fn, x, (weights["layers"], k_cache, v_cache))
         x = L.rmsnorm(x, weights["ln_final"], cfg.norm_eps)
-        logits = L.linear(x, weights["head"], pl)[:, 0]
+        logits = L.linear(x, weights["head"], kn)[:, 0]
         # HOST: sampling
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, logits, new_k, new_v
@@ -298,7 +303,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             vc = L.paged_cache_write(vc, v, table, pos, write)
             attn = ops.paged_decode_attention(
                 q, kc, vc, table, pos + 1, softcap=self.cfg.softcap,
-                use_pallas=self.use_pallas,
+                kernels=self.kernels,
                 model_axis=self.cfg.parallel.model_axis,
                 batch_axes=self.cfg.parallel.batch_axes)
             return attn, kc, vc
@@ -371,7 +376,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         if not self.jit:
             return self.decode_token_eager(cache, token)
         self._meter_token(token.shape[0])
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             next_tok, logits, k, v, length = self._decode_jit(
                 self._weights, cache["k"], cache["v"], cache["len"], token)
         return next_tok, logits, {"k": k, "v": v, "len": length}
@@ -453,7 +458,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             prompts = jnp.pad(prompts, ((0, 0), (0, Pb - T0)))
         cache = self.init_cache(B)
         t0 = time.perf_counter()
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             toks, k, v, length, n = self._generate_jit[key](
                 self._weights, cache["k"], cache["v"], cache["len"], prompts,
                 jnp.int32(T0), jnp.int32(T0 - 1 + max_new))
@@ -551,7 +556,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         materialises on a multi-device mesh)."""
         like = self._cache_like(batch)
         sh = self._cache_shardings(batch)
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return jax.tree.map(
                 lambda a, s: jnp.zeros(a.shape, a.dtype, device=s), like, sh)
 
@@ -587,7 +592,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             pages_mod.kv_token_bytes_quant(shape, ba, sa, self.page_size,
                                            self._kv_dtype)
             if self._kv_dtype != "bf16" else None)
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return pages_mod.make_pool(shape, ba, sa, pool.num_pages,
                                        self.page_size,
                                        shardings=self._pool_sh,
@@ -606,7 +611,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         host artifacts (a device failure invalidates buffers, never code),
         so the rebuilt pool re-enters the SAME compiled step and recovery
         costs zero recompiles."""
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             self._weights = jax.device_put(self._weights, self._param_sh)
         return self.init_slot_cache(n_slots)
 
@@ -620,7 +625,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         ``cached_len`` — the tail chunk stream continues from there."""
         if self._b1_shape is None:
             self._b1_shape = self._cache_like(1)
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return self.paged_seed(cache, slot, cached_len, self._SLOT_AXES,
                                    self._SEQ_AXES, self._b1_shape)
 
@@ -638,7 +643,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         pages_mod.check_chunk_width(W, self.max_len)
         if W not in self._prefill_jit:
             self._prefill_jit[W] = self._prefill_fn(W)
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             k, v, ln = self._prefill_jit[W](
                 self._weights, cache["k"], cache["v"], cache["len"],
                 jnp.asarray(chunk[None, :]), jnp.int32(true_w))
@@ -692,7 +697,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
                 self._prefill_jit[width] = self._prefill_fn(width)
             body = np.zeros((1, width), np.int32)
             body[0, :T0 - 1] = prompt[:-1]
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 k, v, ln = self._prefill_jit[width](
                     self._weights, cache["k"], cache["v"], cache["len"],
                     jnp.asarray(body), jnp.int32(T0 - 1))
@@ -706,7 +711,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         B=1 K/V is scattered block-wise onto them."""
         if self._paging_active:
             n_tok = int(np.asarray(slot_cache["len"])[0])
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 return self.paged_insert(batched_cache, slot_cache, slot,
                                          self._SLOT_AXES, self._SEQ_AXES,
                                          n_tok)
@@ -715,7 +720,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
                 self._SLOT_AXES,
                 batched_sh=self._cache_shardings(self._slot_count),
                 single_sh=self._cache_shardings(1))
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             return self._slot_insert(batched_cache, slot_cache,
                                      jnp.int32(slot))
 
@@ -739,7 +744,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             corrupt = np.zeros((n,), bool)
         if self._paging_active:
             act = np.asarray(active, bool)
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 cache = self.paged_pre_step(cache, act, self._SLOT_AXES,
                                             self._SEQ_AXES)
             if self._paged_step is None:
@@ -780,7 +785,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
                     in_shardings=(self._param_sh, self._pool_sh, repl,
                                   vec, vec, vec),
                     out_shardings=(vec, vec, self._pool_sh))
-            with self.mesh:
+            with runtime.use_mesh(self.mesh):
                 nxt, ok, pc = self._paged_step(
                     self._weights, cache, self._pager.table(),
                     jnp.asarray(tokens, jnp.int32),
@@ -807,7 +812,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
                 in_shardings=(self._param_sh, sh["k"], sh["v"], sh["len"],
                               vec, vec, vec),
                 out_shardings=(vec, vec, sh["k"], sh["v"], sh["len"]))
-        with self.mesh:
+        with runtime.use_mesh(self.mesh):
             nxt, ok, k, v, ln = self._slot_step(
                 self._weights, cache["k"], cache["v"], cache["len"],
                 jnp.asarray(tokens, jnp.int32), jnp.asarray(active, bool),

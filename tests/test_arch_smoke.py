@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ASSIGNED, get_config
+from repro.distributed import runtime
 from repro.launch.mesh import make_test_mesh
 from repro.models import api
 from repro.train import optimizer as opt_mod
@@ -55,7 +56,7 @@ def test_smoke_train_step(arch):
         cfg, parallel=dataclasses.replace(cfg.parallel, remat="none"))
     mesh = make_test_mesh()
     optcfg = opt_mod.AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=10)
-    with mesh:
+    with runtime.use_mesh(mesh):
         params = api.init_params(cfg, jax.random.PRNGKey(0))
         opt_state = opt_mod.init_state(params, optcfg)
         step = step_mod.make_train_step(cfg, optcfg, mesh, params, opt_state,

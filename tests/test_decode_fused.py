@@ -59,12 +59,13 @@ def test_fused_generate_matches_stepwise(arch):
 
 
 def test_pallas_device_ops_match_reference():
-    """use_pallas=True routes the quantized device projections through the
-    w4a8 Pallas kernel (interpret mode on CPU) — integer path bit-exact."""
+    """kernels="interpret" routes the quantized device projections through
+    the w4a8 Pallas kernel (interpret mode on CPU) — integer path
+    bit-exact."""
     cfg, params = _lm("llama2-7b")
     eng_r = SplitBrainEngine(cfg, params, max_len=16, quantize=True)
     eng_p = SplitBrainEngine(cfg, params, max_len=16, quantize=True,
-                             use_pallas=True)
+                             kernels="interpret")
     tok = jnp.asarray([3, 5], jnp.int32)
     tr, lr, _ = eng_r.decode_token(eng_r.init_cache(2), tok)
     tp, lp, _ = eng_p.decode_token(eng_p.init_cache(2), tok)

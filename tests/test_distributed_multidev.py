@@ -15,7 +15,12 @@ from conftest import run_multidev
 _SCRIPT = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.distributed import runtime
+    from repro.launch.mesh import make_test_mesh
+
+    def auto_mesh(shape, names):
+        return jax.make_mesh(shape, names,
+                             axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
     # ---------------- 1. train step partitions on a 2x4 mesh ----------------
     import dataclasses
@@ -23,12 +28,12 @@ _SCRIPT = """
     from repro.models import api
     from repro.train import optimizer as opt_mod, step as step_mod
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh(shape=(2, 4))
     cfg = get_config("granite-8b").reduced()
     cfg = dataclasses.replace(cfg, parallel=dataclasses.replace(
         cfg.parallel, remat="none", batch_axes=("data",)))
     optcfg = opt_mod.AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=5)
-    with mesh:
+    with runtime.use_mesh(mesh):
         params = api.init_params(cfg, jax.random.PRNGKey(0))
         opt_state = opt_mod.init_state(params, optcfg)
         step = step_mod.make_train_step(cfg, optcfg, mesh, params, opt_state,
@@ -46,14 +51,14 @@ _SCRIPT = """
 
     # ---------------- 2. compressed psum vs exact ----------------------------
     from repro.distributed.collectives import compressed_psum_mean
-    gmesh = jax.make_mesh((8,), ("data",))
+    gmesh = auto_mesh((8,), ("data",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 64)).astype(np.float32))
 
     def red(x):
         return compressed_psum_mean({"g": x}, "data")["g"]
 
-    got = shard_map(red, mesh=gmesh, in_specs=P("data"), out_specs=P("data"),
-                    check_rep=False)(x)
+    got = jax.shard_map(red, mesh=gmesh, in_specs=P("data"),
+                        out_specs=P("data"), check_vma=False)(x)
     want = jnp.mean(x, axis=0)
     err = float(jnp.max(jnp.abs(got[0] - want)))
     scale_bound = float(jnp.max(jnp.abs(x)) / 127.0) * 2
@@ -63,7 +68,7 @@ _SCRIPT = """
     # ---------------- 3. distributed decode attention ------------------------
     from repro.distributed.collectives import distributed_decode_attention
     from repro.kernels import ref
-    dmesh = jax.make_mesh((8,), ("model",))
+    dmesh = auto_mesh((8,), ("model",))
     rng = np.random.default_rng(1)
     B, Hq, Hkv, S, D = 2, 4, 2, 64, 16
     q = jnp.asarray(rng.normal(size=(B, Hq, 1, D)).astype(np.float32))
@@ -72,7 +77,7 @@ _SCRIPT = """
     lens = jnp.asarray([40, 64], jnp.int32)
     valid = jnp.arange(S)[None, :] < lens[:, None]
     fn = distributed_decode_attention(dmesh, "model")
-    with dmesh:
+    with runtime.use_mesh(dmesh):
         got = fn(q, k, v, valid)
     want = ref.decode_attention(q, k, v, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
@@ -80,14 +85,14 @@ _SCRIPT = """
 
     # ---------------- 4. pipeline parallel vs sequential ---------------------
     from repro.distributed.pipeline import pipeline_apply
-    pmesh = jax.make_mesh((8,), ("pipe",))
+    pmesh = auto_mesh((8,), ("pipe",))
     Sstages, M, mb, dim = 8, 16, 4, 32
     Ws = jnp.asarray(rng.normal(size=(Sstages, dim, dim)).astype(np.float32) * 0.3)
     xs = jnp.asarray(rng.normal(size=(M, mb, dim)).astype(np.float32))
 
     piped = pipeline_apply(pmesh, lambda p, x: jnp.tanh(x @ p),
                            num_microbatches=M, axis_name="pipe")
-    with pmesh:
+    with runtime.use_mesh(pmesh):
         got = piped(Ws, xs)
     want = xs
     for s in range(Sstages):

@@ -1,5 +1,6 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracle,
 swept over shapes and dtypes."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -25,7 +26,7 @@ def test_w4a8_matches_oracle(M, K, N, bm, bn, bk, out_dtype):
     codes = jnp.asarray(rng.integers(-7, 8, (K, N)).astype(np.int8))
     ws = jnp.asarray(rng.uniform(0.01, 0.1, (N,)).astype(np.float32))
     got = w4a8_matmul(qx, xs, codes, ws, bm=bm, bn=bn, bk=bk,
-                      out_dtype=out_dtype)
+                      out_dtype=out_dtype, interpret=True)
     want = ref.w4a8_matmul(qx, xs, codes, ws, out_dtype)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -40,7 +41,7 @@ def test_w4a8_integer_path_bit_exact():
     ones_m = jnp.ones((128, 1), jnp.float32)
     ones_n = jnp.ones((128,), jnp.float32)
     got = w4a8_matmul(qx, ones_m, codes, ones_n, bm=64, bn=64, bk=64,
-                      out_dtype=jnp.float32)
+                      out_dtype=jnp.float32, interpret=True)
     want = np.asarray(qx, np.int64) @ np.asarray(codes, np.int64)
     np.testing.assert_array_equal(np.asarray(got).astype(np.int64), want)
 
@@ -66,10 +67,30 @@ def test_flash_attention_matches_naive(case, dtype):
     v = jnp.asarray(rng.normal(size=(B, Hkv, Tk, D)), dtype)
     kvo = Tk - Tq if c.get("causal") else 0
     want = ref.mha(q, k, v, kv_offset=kvo, **c)
-    got = flash_attention(q, k, v, kv_offset=kvo, bq=32, bk=32, **c)
+    got = flash_attention(q, k, v, kv_offset=kvo, bq=32, bk=32,
+                          interpret=True, **c)
     atol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("start", [0, 32, 96])
+@pytest.mark.parametrize("Hq,Hkv,window", [(4, 2, None), (4, 4, 48)])
+def test_chunk_attention_kernel_matches_ref(start, Hq, Hkv, window):
+    """Chunked prefill through the flash kernel: a 32-row chunk at a TRACED
+    start attends causally over a 128-slot linear cache (garbage past the
+    chunk must stay masked) — one compiled program for every start."""
+    from repro.kernels import ops
+    rng = np.random.default_rng(start + Hkv)
+    B, W, S, D = 2, 32, 128, 16
+    q = jnp.asarray(rng.normal(size=(B, Hq, W, D)), jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(B, Hkv, S, D)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(B, Hkv, S, D)), jnp.float32)
+    q_pos = start + jnp.arange(W)[None, :].repeat(B, 0)
+    want = ref.chunk_attention(q, kc, vc, q_pos, window=window)
+    got = jax.jit(lambda *a: ops.chunk_attention(
+        *a, window=window, kernels="interpret"))(q, kc, vc, q_pos)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
 @pytest.mark.parametrize("case", ATTN_CASES)
@@ -115,7 +136,7 @@ def test_rwkv_kernel_matches_ref(B, H, T, D, bt):
     w = jnp.asarray(rng.uniform(0.8, 0.999, (B, H, T, D)).astype(np.float32))
     u = jnp.asarray(rng.normal(size=(H, D)).astype(np.float32))
     want_o, want_s = ref.rwkv6_scan(r, k, v, w, u)
-    got_o, got_s = rwkv6_scan(r, k, v, w, u, bt=bt)
+    got_o, got_s = rwkv6_scan(r, k, v, w, u, bt=bt, interpret=True)
     np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), atol=1e-4)
     np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s), atol=1e-4)
 

@@ -93,12 +93,17 @@ _SCRIPT = """
                                                mesh=mesh),
                  kv_shards=TP)
 
-    # ---- TP paged-attention kernel dispatch (interpret-mode Pallas) --------
-    from repro.kernels import ops
+    # ---- TP paged-attention kernels (interpret-mode Pallas) ----------------
+    # The per-shard collectives are driven with the concrete mesh: the
+    # interpreter's host callbacks do not partition under the abstract mesh
+    # a traced serve step sees (compiled kernels do: the routing through ops
+    # is compiled for a described four-chip mesh in test_tpu_compile.py and
+    # run by the chip smoke test's --tp path).
+    from repro.distributed import collectives
     from repro.kernels import paged_attention as _pa
     mesh = make_test_mesh(shape=(1, TP))
 
-    def kernel_case(Hq, Hkv, name):
+    def kernel_case(Hq, Hkv, name, quant=False):
         B, D, ps, N, Pg = 3, 16, 8, 12, 4
         q = jnp.asarray(rng.standard_normal((B, Hq, 1, D)), jnp.float32)
         kp = jnp.asarray(rng.standard_normal((N, ps, Hkv, D)), jnp.float32)
@@ -106,16 +111,45 @@ _SCRIPT = """
         table = jnp.asarray(
             rng.permutation(N)[: B * Pg].reshape(B, Pg), jnp.int32)
         lens = jnp.asarray([1, 9, 30], jnp.int32)
-        want = _pa.paged_decode_attention(q, kp, vp, table, lens, softcap=2.0)
-        with mesh:
-            got = ops.paged_decode_attention(q, kp, vp, table, lens,
-                                             softcap=2.0, use_pallas=True,
-                                             model_axis="model")
+        scales = dict()
+        if quant:   # int8 codes, per-page per-kv-head scales
+            kp, vp = (jnp.asarray(rng.integers(-127, 128, (N, ps, Hkv, D)),
+                                  jnp.int8) for _ in range(2))
+            scales = dict((n, jnp.asarray(rng.uniform(0.01, 0.1, (N, Hkv)),
+                                          jnp.float32))
+                          for n in ("k_scale", "v_scale"))
+        want = _pa.paged_decode_attention(q, kp, vp, table, lens, softcap=2.0,
+                                          interpret=True, **scales)
+        build = (collectives.tp_paged_decode_attention if Hkv % TP == 0
+                 else collectives.tp_paged_decode_attention_merge)
+        fn = build(mesh, "model", softcap=2.0, interpret=True, quant=quant)
+        got = fn(q, kp, vp, table, lens,
+                 *(scales[n] for n in ("k_scale", "v_scale") if quant))
         err = float(jnp.max(jnp.abs(want - got)))
         assert err < 1e-5, (name, err)
 
     kernel_case(4, TP, "head_cut")   # Hkv % tp == 0: per-shard grid
     kernel_case(4, 1, "merge")       # Hkv < tp: page split + LSE merge
+    kernel_case(4, TP, "head_cut_int8", quant=True)  # scales cut on heads
+    kernel_case(4, 1, "merge_int8", quant=True)
+
+    from repro.kernels import flash_attention as _fa
+
+    def flash_case(Hq, Hkv, name):   # chunked prefill at a nonzero start
+        B, W, S, D = 1, 16, 64, 16
+        q = jnp.asarray(rng.standard_normal((B, Hq, W, D)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
+        off = jnp.asarray([32], jnp.int32)
+        want = _fa.flash_attention(q, k, v, kv_offset=off, interpret=True)
+        fn = collectives.tp_flash_attention(
+            mesh, "model", interpret=True,
+            cut_heads=Hq % TP == 0 and Hkv % TP == 0)
+        err = float(jnp.max(jnp.abs(want - fn(q, k, v, off))))
+        assert err < 1e-5, (name, err)
+
+    flash_case(4, TP, "flash_head_cut")
+    flash_case(4, 1, "flash_replicated")
     print("KERNEL_TP_OK")
     print("MESH_SERVE_OK")
 """

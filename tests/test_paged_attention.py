@@ -139,16 +139,16 @@ def test_quant_ops_dispatch_unpacks_quantized_leaf(kv_dtype):
     kq, vq = _quantize(kp, kv_dtype), _quantize(vp, kv_dtype)
     want = ref.paged_decode_attention(q, kq.codes, vq.codes, table, lens,
                                       k_scale=kq.scales, v_scale=vq.scales)
-    got = ops.paged_decode_attention(q, kq, vq, table, lens,
-                                     use_pallas=False)
+    got = ops.paged_decode_attention(q, kq, vq, table, lens, kernels="jnp")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("case", CASES)
 def test_quant_pallas_kernel_matches_oracle(case):
-    """The fused-dequant Pallas kernel (scales as scalar-prefetch operands
-    3/4, per-page multiply at fetch) vs the scaled oracle — page sizes
+    """The fused-dequant Pallas kernel (per-page scale blocks fetched
+    through the page table, multiplied into scores and value sums) vs the
+    scaled oracle — page sizes
     {1, odd, 8}, GQA/MQA, window, softcap, all on int8 pools."""
     from repro.kernels.paged_attention import paged_decode_attention
     B, Hq, Hkv, D, ps, P, window, softcap = case

@@ -114,11 +114,18 @@ def test_generate_eos_per_request(arch, fused):
 
 def test_generate_batch_bucketing_pads_and_slices():
     """Odd batch sizes are padded to the next power of two internally and
-    sliced back: outputs identical to the unpadded reference."""
+    sliced back: outputs identical to the unpadded references — the
+    stepwise loop and each row served alone (batch 1 needs no padding).
+    The prompts hold no near-tie: the stepwise loop prefills token by
+    token, and its rounding flips one-bf16-ULP argmax ties of this
+    random-weight model (``default_rng(4)`` has one, in row 1)."""
     cfg, eng = _make_engine(max_len=16)
-    rng = np.random.default_rng(4)
+    rng = np.random.default_rng(0)
     prompts = rng.integers(1, cfg.vocab_size, (3, 4)).astype(np.int32)
     out = eng.generate(prompts, max_new=4)
     ref = eng.generate(prompts, max_new=4, fused=False)
+    rows = np.concatenate([eng.generate(prompts[i:i + 1], max_new=4)["tokens"]
+                           for i in range(3)])
     assert out["tokens"].shape == (3, 4)
     np.testing.assert_array_equal(out["tokens"], ref["tokens"])
+    np.testing.assert_array_equal(out["tokens"], rows)

@@ -25,7 +25,8 @@ FAMILIES = ["llama2-7b", "gemma2-27b", "hymba-1.5b", "rwkv6-7b"]
 
 
 def tp_mesh(tp: int) -> AbstractMesh:
-    return AbstractMesh((("data", 1), ("model", tp)))
+    return AbstractMesh((1, tp), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 @pytest.fixture(scope="module", params=FAMILIES)
