@@ -1,0 +1,53 @@
+"""What the program records about its own iterations: the ``sched.step``
+spans of ``repro.serve.telemetry``, one per scheduler iteration, on the
+host clock the client's window is taken on.  A program without that
+recorder gives nothing, and the metrics that read it are left out."""
+from __future__ import annotations
+
+from typing import List, Optional, Tuple
+
+STEP = "sched.step"
+# the prefill-side work an iteration can carry beside its decode step
+PREFILL_SIDE = ("seeds", "chunks", "inserts", "cow_copies")
+
+
+def _telemetry():
+    try:
+        from repro.serve import telemetry
+    except ImportError:
+        return None
+    return telemetry
+
+
+def decode_iterations(run) -> Optional[Tuple[list, list]]:
+    """(the window's iterations that dispatched a decode step, every record
+    the recorder holds), or None without a recorder or such iterations."""
+    tel = _telemetry()
+    if tel is None:
+        return None
+    recs = tel.recorder().spans()
+    steps = [s for s in recs if s.name == STEP and s.attrs
+             and s.attrs.get("decode") and run.win0 <= s.t0 < run.win1]
+    return (steps, recs) if steps else None
+
+
+def host_self_ms(run) -> Optional[float]:
+    """Mean over the window's decode iterations of the ``sched.step``
+    duration less its blocking ``*.wait`` read-backs, in ms."""
+    got = decode_iterations(run)
+    if got is None:
+        return None
+    steps, recs = got
+    own: List[float] = _telemetry().self_times(steps, recs)
+    return 1e3 * sum(own) / len(own)
+
+
+def mixed_share_pct(run) -> Optional[float]:
+    """Share of the window's decode iterations that also dispatched a
+    seed, a prefill chunk, an insert or a CoW page copy, in percent."""
+    got = decode_iterations(run)
+    if got is None:
+        return None
+    steps = got[0]
+    mixed = sum(1 for s in steps if any(s.attrs.get(k) for k in PREFILL_SIDE))
+    return 100.0 * mixed / len(steps)

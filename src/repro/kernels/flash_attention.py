@@ -140,5 +140,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_prefill",
     )(offsets, q, k, v)
     return out[:, :, :Tq]

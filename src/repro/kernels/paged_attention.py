@@ -200,6 +200,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_flash_decode",
     )(page_table.astype(jnp.int32), jnp.asarray(cache_len, jnp.int32),
       *operands)
     to_heads = lambda a: a.transpose(0, 2, 1, 3).reshape(B, Hq, 1, -1)

@@ -61,6 +61,7 @@ from repro.models import api
 from repro.serve import pages as pages_mod
 from repro.serve.errors import InvalidRequestError
 from repro.serve import slots as slots_mod
+from repro.serve import telemetry
 from repro.train import step as step_mod
 
 
@@ -211,6 +212,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             return tp
         return 1
 
+    @telemetry.traced("engine.meter")
     def meter_tokens(self, n: int) -> None:
         """Replay ``n`` active tokens' boundary crossings on the meter.
 
@@ -282,7 +284,6 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             cache = api.init_cache(cfg, Bb, self.max_len, frontend=frontend,
                                    params=self.params)
             tok = prompts_j[:, -1]
-            tp0 = time.perf_counter()
             if T0 > 1:
                 # one bucketed api.prefill_bucketed pass fills the cache with
                 # the whole prompt (no T0 Python-loop decode steps)
@@ -293,7 +294,6 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                 prefill = self._get_prefill(cache, width)
                 _, cache = prefill(self.params, cache, body,
                                    np.int32(T0 - 1))
-            prefill_s = time.perf_counter() - tp0
             # bucketed step count: run the bucket, slice to max_new (greedy
             # decode is prefix-stable, so the extra steps change nothing)
             steps = slots_mod.bucket(max_new)
@@ -308,8 +308,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
         return {"tokens": toks,
                 "gen_len": gen_len,
                 "tokens_per_s": int(gen_len.sum()) / dt,
-                "decode_s": dt,
-                "prefill_s": prefill_s}
+                "decode_s": dt}
 
     def _generate_stepwise(self, cache, prompts: np.ndarray, max_new: int,
                            eos_id: Optional[int] = None):
@@ -322,11 +321,9 @@ class ServeEngine(pages_mod.PagedEngineMixin):
         step = self._get_serve_step(cache)
         B = prompts.shape[0]
         tok = jnp.asarray(prompts[:, 0], jnp.int32)
-        tp0 = time.perf_counter()
         for t in range(1, prompts.shape[1]):
             _, _, cache = step(self.params, cache, tok)
             tok = jnp.asarray(prompts[:, t], jnp.int32)
-        prefill_s = time.perf_counter() - tp0
         out = []
         alive = np.ones((B,), bool)
         gen_len = np.zeros((B,), np.int32)
@@ -350,8 +347,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
         return {"tokens": tokens,
                 "gen_len": gen_len,
                 "tokens_per_s": int(gen_len.sum()) / dt,
-                "decode_s": dt,
-                "prefill_s": prefill_s}
+                "decode_s": dt}
 
     # ---------------------------------------------------------- slot protocol
     # Consumed by serve/scheduler.py: a fixed (max_slots, ...) cache pytree
@@ -504,6 +500,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             cache = api.init_cache(self.cfg, 1, self.max_len)
             return jax.device_put(cache, self._b1_shardings())
 
+    @telemetry.traced("engine.seed_request_cache")
     def seed_request_cache(self, cache, slot: int, cached_len: int):
         """Prefix-aware prefill entry: B=1 request cache seeded with the
         slot's matched prefix pages gathered from the pool, ``len`` set to
@@ -516,6 +513,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                                    self._slot_axes(), self._slot_seq_axes(),
                                    self._b1_shape)
 
+    @telemetry.traced("engine.prefill_chunk_slot")
     def prefill_chunk_slot(self, cache, chunk: np.ndarray, true_w: int):
         """Advance a B=1 request cache by one right-padded prompt chunk.
 
@@ -552,6 +550,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             return self._chunk_jit[W](self.params, cache, chunk[None, :],
                                       jnp.int32(true_w))
 
+    @telemetry.traced("engine.insert_slot")
     def insert_slot(self, batched_cache, slot_cache, slot: int):
         """Write a prefilled request into slot ``slot`` (donated, traced
         index: ONE compiled program covers every slot).  On the paged
@@ -559,7 +558,10 @@ class ServeEngine(pages_mod.PagedEngineMixin):
         dense cache is scattered block-wise onto them (excess logical pages
         land on the scratch page — fixed write count, no recompiles)."""
         if self._paging_active:
-            n_tok = int(np.asarray(slot_cache["len"])[0])
+            # the host knows this length already; the read-back blocks
+            # until the request's last prefill program has run
+            with telemetry.span("engine.insert.wait"):
+                n_tok = int(np.asarray(slot_cache["len"])[0])
             with runtime.use_mesh(self.mesh):
                 return self.paged_insert(batched_cache, slot_cache, slot,
                                          self._slot_axes(),
@@ -575,6 +577,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             return self._slot_insert(batched_cache, slot_cache,
                                      jnp.int32(slot))
 
+    @telemetry.traced("engine.decode_slots")
     def decode_slots(self, cache, tokens, active, corrupt=None):
         """One masked batched decode step: every slot computes, only active
         slots advance (inactive cache leaves frozen).  Fixed shapes — the
@@ -642,9 +645,10 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                     in_shardings=(self._param_sh, self._pool_sh, repl,
                                   vec, vec, vec),
                     out_shardings=(vec, vec, self._pool_sh))
-            with runtime.use_mesh(self.mesh):
-                out = self._paged_step(self.params, cache,
-                                       self._pager.table(),
+            table = self._pager.table()
+            with (telemetry.span("engine.decode.dispatch"),
+                  runtime.use_mesh(self.mesh)):
+                out = self._paged_step(self.params, cache, table,
                                        jnp.asarray(tokens, jnp.int32),
                                        jnp.asarray(active, bool),
                                        jnp.asarray(corrupt, bool))

@@ -86,6 +86,7 @@ from repro.models.layers import (KV_DTYPES, QuantizedLeaf, SCRATCH_PAGE,
                                  fake_quant_pages, kv_pow2_scale,
                                  kv_quantize, page_offsets,
                                  quant_page_append)
+from repro.serve import telemetry
 from repro.serve.errors import PageLifecycleError, ReservationError
 
 __all__ = [
@@ -481,6 +482,7 @@ class HostPager:
         return self.pool.try_reserve(slot,
                                      self._tokens_for(prompt_len, max_new))
 
+    @telemetry.traced("engine.pager.admit")
     def admit(self, slot: int, prompt: np.ndarray, max_new: int,
               chunk: Optional[int] = None) -> Optional[int]:
         """Admission with radix prefix matching.
@@ -555,6 +557,7 @@ class HostPager:
         self._ensure(slot, n_tokens)
         self.host_len[slot] = n_tokens
 
+    @telemetry.traced("engine.pager.publish")
     def publish(self, slot: int, prompt: np.ndarray) -> int:
         """Publish the slot's completed full prefill pages (positions below
         its prefilled body) into the prefix index.  No-op when prefix
@@ -591,7 +594,8 @@ class HostPager:
         """Device copy of the page table, re-uploaded only when a table
         entry actually changed (steady-state decode reuses it)."""
         if self._table_dev is None:
-            self._table_dev = jnp.asarray(self.pool.table)
+            with telemetry.span("engine.pager.table_upload"):
+                self._table_dev = jnp.asarray(self.pool.table)
         return self._table_dev
 
     def row(self, slot: int) -> jnp.ndarray:
@@ -787,6 +791,7 @@ class PagedEngineMixin:
             return self._kv_bytes(pages_touched * ps)
         return self._dense_view_read_bytes()
 
+    @telemetry.traced("engine.meter")
     def _meter_kv_read(self, active: np.ndarray) -> None:
         n = self.kv_read_bytes_step(active)
         if n:
@@ -936,6 +941,7 @@ class PagedEngineMixin:
                 kw = dict(in_shardings=(self._pool_sh, None, None),
                           out_shardings=self._pool_sh)
             self._cow_jit = jax.jit(copy, donate_argnums=(0,), **kw)
+        telemetry.count("cow_copies", len(copies))
         page_bytes = self._kv_bytes(self._pager.page_size)
         for src, dst in copies:
             cache = self._cow_jit(cache, jnp.int32(src), jnp.int32(dst))
@@ -947,8 +953,9 @@ class PagedEngineMixin:
         every active slot's append position, apply any required page
         copies, and meter the step's KV reads.  Returns the (possibly
         copied-into) cache."""
-        copies = self._pager.pre_decode(active)
-        cache = self.apply_cow_copies(cache, copies, ba, sa)
+        with telemetry.span("engine.pager.pre_decode"):
+            copies = self._pager.pre_decode(active)
+            cache = self.apply_cow_copies(cache, copies, ba, sa)
         self._meter_kv_read(active)
         return cache
 

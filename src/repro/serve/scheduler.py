@@ -81,10 +81,24 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.serve import telemetry
 from repro.serve.errors import DeviceError, SchedulerError
 
 __all__ = ["Request", "RequestResult", "RejectedRequest", "RequestState",
            "ContinuousBatchingScheduler"]
+
+# the keys of ``counters()``
+COUNTERS = ("iterations", "steps", "decoded_tokens", "prefill_tokens",
+            "cached_prompt_tokens", "preemptions", "quarantines", "failed",
+            "recoveries", "last_recovery_s")
+
+# The counts each iteration's ``sched.step`` span carries (telemetry.count
+# rolls them up from the layers below): slots in the decode batch, requests
+# admitted, prefix seeds, prefill dispatches (a chunk, or a whole prompt body
+# when chunking is off), slot inserts, CoW page copies, blocking
+# device-to-host reads, and whether a decode step was dispatched.
+STEP_COUNTS = dict(n_active=0, admitted=0, seeds=0, chunks=0, inserts=0,
+                   cow_copies=0, syncs=0, decode=0)
 
 
 class RequestState(str, enum.Enum):
@@ -276,6 +290,24 @@ class ContinuousBatchingScheduler:
         ``arrival_s`` and ``deadline_s``."""
         self._ensure_began()
         return self._now()
+
+    def counters(self) -> Dict[str, Any]:
+        """The loop's cumulative counters since ``begin`` (zeros before):
+        iterations, decode steps, tokens decoded, prefilled and served from
+        the prefix cache, preemptions, quarantines, FAILED requests,
+        recoveries and the last recovery's seconds."""
+        if not self._began:
+            return dict.fromkeys(COUNTERS, 0)
+        return {"iterations": self._iterations,
+                "steps": self._decode_steps,
+                "decoded_tokens": self._decoded_tokens,
+                "prefill_tokens": self._prefill_tokens,
+                "cached_prompt_tokens": self._cached_tokens,
+                "preemptions": self._preempt_count,
+                "quarantines": self._quarantines,
+                "failed": self._failed_count,
+                "recoveries": self._recoveries,
+                "last_recovery_s": self._last_recovery_s}
 
     def has_work(self) -> bool:
         """Anything queued, prefilling or decoding."""
@@ -546,17 +578,19 @@ class ContinuousBatchingScheduler:
                 and self.faults.admission_fault(rec.req.uid)):
             return None, True
         prompt, max_new = self._effective(rec)
-        if hasattr(eng, "admit_slot"):
-            return eng.admit_slot(slot, prompt, max_new,
-                                  self.prefill_chunk), False
-        if hasattr(eng, "reserve_slot"):
-            ok = eng.reserve_slot(slot, len(prompt), max_new)
-            return (0 if ok else None), False
-        return 0, False
+        with telemetry.span("sched.try_admit", uid=rec.req.uid):
+            if hasattr(eng, "admit_slot"):
+                return eng.admit_slot(slot, prompt, max_new,
+                                      self.prefill_chunk), False
+            if hasattr(eng, "reserve_slot"):
+                ok = eng.reserve_slot(slot, len(prompt), max_new)
+                return (0 if ok else None), False
+            return 0, False
 
     def _in_flight(self) -> bool:
         return bool(self._states) or bool(self._prefilling)
 
+    @telemetry.traced("sched.admit")
     def _admit(self, realtime: bool) -> None:
         eng = self.engine
         chunk = self.prefill_chunk
@@ -597,7 +631,9 @@ class ContinuousBatchingScheduler:
                 continue
             self._pending.remove(rec)
             self._free.remove(slot)
-            self._start(rec, slot, cached)
+            with telemetry.span("sched.start", uid=rec.req.uid):
+                telemetry.count("admitted")
+                self._start(rec, slot, cached)
 
     def _activate(self, slot: int, rec: _ReqRecord, tok: int,
                   tenure_s: float) -> None:
@@ -624,12 +660,14 @@ class ContinuousBatchingScheduler:
                 # prefix hit: seed a B=1 request cache with the matched
                 # pages gathered from the pool; only the unmatched tail is
                 # prefilled (chunk stream continuing at position ``cached``)
+                telemetry.count("seeds")
                 seeded = eng.seed_request_cache(self.cache, slot, cached)
                 if cached < body:
                     self._prefilling.append(_PrefillJob(
                         slot, rec, prompt, seeded, cached, now, cached))
                     return
                 # whole-body hit: nothing to prefill, go straight to decode
+                telemetry.count("inserts")
                 self.cache = eng.insert_slot(self.cache, seeded, slot)
                 eng.publish_prefix(slot, prompt)
                 self._activate(slot, rec, int(prompt[-1]), now)
@@ -638,7 +676,9 @@ class ContinuousBatchingScheduler:
                 self._prefilling.append(_PrefillJob(
                     slot, rec, prompt, eng.new_request_cache(), 0, now))
                 return
+            telemetry.count("chunks")
             slot_cache, tok = eng.prefill_slot(prompt)
+            telemetry.count("inserts")
             self.cache = eng.insert_slot(self.cache, slot_cache, slot)
             if hasattr(eng, "publish_prefix"):
                 eng.publish_prefix(slot, prompt)
@@ -667,79 +707,91 @@ class ContinuousBatchingScheduler:
         if self.faults is not None and self.faults.prefill_stalled(uid):
             return
         body = len(job.prompt) - 1
-        try:
-            if self.faults is not None:
-                self.faults.prefill_fault(uid)
-            w = min(chunk, body - job.consumed)
-            buf = np.zeros((chunk,), np.int32)
-            buf[:w] = job.prompt[job.consumed:job.consumed + w]
-            job.cache = eng.prefill_chunk_slot(job.cache, buf, w)
-            job.consumed += w
-            if job.consumed == body:
-                self._prefilling.popleft()
-                self.cache = eng.insert_slot(self.cache, job.cache, job.slot)
-                if hasattr(eng, "publish_prefix"):
-                    eng.publish_prefix(job.slot, job.prompt)
-                self._prefill_tokens += body - job.cached
-                self._unmetered += body - job.cached
-                self._activate(job.slot, job.rec, int(job.prompt[-1]),
-                               job.tenure_s)
-        except SchedulerError as e:
-            self._abort_job(job, RequestState.REJECTED,
-                            reason=f"prefill failed: {e}")
-        except Exception:
-            self._abort_job(job, RequestState.REJECTED,
-                            reason="prefill failed: unrecoverable")
-            raise
+        with telemetry.span("sched.prefill_tick", uid=uid):
+            try:
+                if self.faults is not None:
+                    self.faults.prefill_fault(uid)
+                w = min(chunk, body - job.consumed)
+                buf = np.zeros((chunk,), np.int32)
+                buf[:w] = job.prompt[job.consumed:job.consumed + w]
+                telemetry.count("chunks")
+                job.cache = eng.prefill_chunk_slot(job.cache, buf, w)
+                job.consumed += w
+                if job.consumed == body:
+                    self._prefilling.popleft()
+                    telemetry.count("inserts")
+                    self.cache = eng.insert_slot(self.cache, job.cache,
+                                                 job.slot)
+                    if hasattr(eng, "publish_prefix"):
+                        eng.publish_prefix(job.slot, job.prompt)
+                    self._prefill_tokens += body - job.cached
+                    self._unmetered += body - job.cached
+                    self._activate(job.slot, job.rec, int(job.prompt[-1]),
+                                   job.tenure_s)
+            except SchedulerError as e:
+                self._abort_job(job, RequestState.REJECTED,
+                                reason=f"prefill failed: {e}")
+            except Exception:
+                self._abort_job(job, RequestState.REJECTED,
+                                reason="prefill failed: unrecoverable")
+                raise
 
     def _decode_tick(self) -> None:
         if not self._active.any():
             return
-        eng = self.engine
-        n_active = int(self._active.sum())
-        corrupt = None
-        if self.faults is not None:
-            self.faults.step_stall()
-            self.faults.step_fault()       # may raise StepError/DeviceLost
-            bad = self.faults.corrupt_uids(self.decoding_uids())
-            if bad:
-                corrupt = np.zeros_like(self._active)
-                for slot, st in self._states.items():
-                    if st.rec.req.uid in bad:
-                        corrupt[slot] = True
-        nxt, ok, self.cache = eng.decode_slots(self.cache, self._tokens,
-                                               self._active, corrupt)
-        self._decode_steps += 1
-        self._decoded_tokens += n_active
-        self._unmetered += n_active
-        nxt = np.asarray(nxt)
-        okh = np.asarray(ok)
-        t_step = self._now()
-        for slot in np.flatnonzero(self._active):
-            st = self._states[slot]
-            rec = st.rec
-            if not okh[slot]:
-                # the sentinel flagged non-finite logits: the token is
-                # garbage — quarantine the slot instead of appending it
-                self._quarantine_slot(slot)
-                continue
-            tok = int(nxt[slot])
-            if rec.first_token_s is None:
-                rec.first_token_s = t_step
-            rec.tokens.append(tok)
-            if rec.req.stream is not None:
-                try:
-                    rec.req.stream(tok)
-                except Exception:
-                    # a throwing consumer is a gone consumer: cancel its
-                    # request next iteration, keep every other stream alive
-                    self._cancels.add(rec.req.uid)
-            done = (len(rec.tokens) >= rec.req.max_new
-                    or (self.eos_id is not None and tok == self.eos_id))
-            if done:
-                self._finish_slot(slot, RequestState.DONE)
-            else:
-                self._tokens[slot] = tok
+        with telemetry.span("sched.decode_tick"):
+            eng = self.engine
+            n_active = int(self._active.sum())
+            corrupt = None
+            if self.faults is not None:
+                self.faults.step_stall()
+                self.faults.step_fault()       # may raise StepError/DeviceLost
+                bad = self.faults.corrupt_uids(self.decoding_uids())
+                if bad:
+                    corrupt = np.zeros_like(self._active)
+                    for slot, st in self._states.items():
+                        if st.rec.req.uid in bad:
+                            corrupt[slot] = True
+            nxt, ok, self.cache = eng.decode_slots(self.cache, self._tokens,
+                                                   self._active, corrupt)
+            self._decode_steps += 1
+            self._decoded_tokens += n_active
+            self._unmetered += n_active
+            telemetry.count("decode")
+            telemetry.count("n_active", n_active)
+            with telemetry.span("sched.decode.wait"):
+                nxt = np.asarray(nxt)
+                okh = np.asarray(ok)
+            t_step = self._now()
+            with telemetry.span("sched.decode.emit"):
+                for slot in np.flatnonzero(self._active):
+                    st = self._states[slot]
+                    rec = st.rec
+                    if not okh[slot]:
+                        # the sentinel flagged non-finite logits: the
+                        # token is garbage — quarantine the slot instead of
+                        # appending it
+                        self._quarantine_slot(slot)
+                        continue
+                    tok = int(nxt[slot])
+                    if rec.first_token_s is None:
+                        rec.first_token_s = t_step
+                    rec.tokens.append(tok)
+                    if rec.req.stream is not None:
+                        try:
+                            rec.req.stream(tok)
+                        except Exception:
+                            # a throwing consumer is a gone consumer:
+                            # cancel its request next iteration, keep every
+                            # other stream alive
+                            self._cancels.add(rec.req.uid)
+                    done = (len(rec.tokens) >= rec.req.max_new
+                            or (self.eos_id is not None
+                                and tok == self.eos_id))
+                    if done:
+                        self._finish_slot(slot, RequestState.DONE)
+                    else:
+                        self._tokens[slot] = tok
 
     def _quarantine_slot(self, slot: int) -> None:
         """Quarantine a slot whose logits went non-finite: the device-side
@@ -828,19 +880,22 @@ class ContinuousBatchingScheduler:
         this iteration (they also stay queued for ``poll()``)."""
         self._ensure_began()
         n0 = len(self._results)
-        if self.faults is not None:
-            self.faults.on_step(self)
-        self._apply_cancellations()
-        self._expire_deadlines()
-        self._admit(realtime)
-        self._prefill_tick()
-        try:
-            self._decode_tick()
-        except DeviceError as e:
-            # a typed device failure is survivable by construction: every
-            # byte of dynamic state has a host copy — rebuild and resume
-            self.recover(reason=f"{type(e).__name__}: {e}")
-        self._iterations += 1
+        with telemetry.span("sched.step") as attrs:
+            attrs.update(iteration=self._iterations, **STEP_COUNTS)
+            if self.faults is not None:
+                self.faults.on_step(self)
+            self._apply_cancellations()
+            self._expire_deadlines()
+            self._admit(realtime)
+            self._prefill_tick()
+            try:
+                self._decode_tick()
+            except DeviceError as e:
+                # a typed device failure is survivable by construction:
+                # every byte of dynamic state has a host copy — rebuild and
+                # resume
+                self.recover(reason=f"{type(e).__name__}: {e}")
+            self._iterations += 1
         return self._results[n0:]
 
     def _flush_meter(self) -> None:
@@ -890,16 +945,7 @@ class ContinuousBatchingScheduler:
         return {
             "results": results,
             "rejected": self._rejected,
-            "steps": self._decode_steps,
-            "iterations": self._iterations,
-            "decoded_tokens": self._decoded_tokens,
-            "prefill_tokens": self._prefill_tokens,
-            "cached_prompt_tokens": self._cached_tokens,
-            "preemptions": self._preempt_count,
-            "quarantines": self._quarantines,
-            "failed": self._failed_count,
-            "recoveries": self._recoveries,
-            "last_recovery_s": self._last_recovery_s,
+            **self.counters(),
             "by_state": by_state,
             "wall_s": wall_s,
             "busy_s": busy_s,

@@ -345,18 +345,11 @@ class OnlineServer:
 
     # ------------------------------------------------------------- inspection
     def stats(self) -> Dict[str, Any]:
-        """Point-in-time loop counters (reads scheduler attributes the loop
-        thread also touches — informational, not transactional)."""
-        s = self.scheduler
+        """Point-in-time loop counters (the scheduler's ``counters()``,
+        read while the loop thread runs — informational, not
+        transactional)."""
         return {
-            "iterations": getattr(s, "_iterations", 0),
-            "decoded_tokens": getattr(s, "_decoded_tokens", 0),
-            "prefill_tokens": getattr(s, "_prefill_tokens", 0),
-            "preemptions": getattr(s, "_preempt_count", 0),
-            "quarantines": getattr(s, "_quarantines", 0),
-            "failed": getattr(s, "_failed_count", 0),
-            "recoveries": getattr(s, "_recoveries", 0),
-            "last_recovery_s": getattr(s, "_last_recovery_s", 0.0),
+            **self.scheduler.counters(),
             "watchdog_trips": self._watchdog_trips,
             "outstanding": len(self._handles),
         }

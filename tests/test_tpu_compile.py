@@ -9,6 +9,7 @@ overruns — at no chip time.  The topology is described inside a fixture so
 that importing this module never loads the TPU runtime.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,13 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _named_kernel(text, name):
+    """The kernel's custom call carries the ``name`` its ``pallas_call``
+    gives, while its target stays ``tpu_custom_call``."""
+    return re.search(rf"%{name}(\.\d+)? = .*"
+                     r'custom_call_target="tpu_custom_call"', text)
+
+
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8", "float8_e4m3fn"])
 @pytest.mark.parametrize("page_size", [16, 32])
 def test_paged_decode_compiles(one_chip, kv_dtype, page_size):
@@ -82,7 +90,7 @@ def test_paged_decode_compiles(one_chip, kv_dtype, page_size):
         def fn(q, k, v, table, lens, ks, vs):
             return paged_attention.paged_decode_attention(
                 q, k, v, table, lens, k_scale=ks, v_scale=vs)
-    assert "tpu_custom_call" in _compiled_text(fn, *args)
+    assert _named_kernel(_compiled_text(fn, *args), "paged_flash_decode")
 
 
 def test_paged_decode_lse_compiles(one_chip):
@@ -111,7 +119,7 @@ def test_flash_attention_compiles(one_chip, tq, tk):
         return flash_attention.flash_attention(q, k, v, causal=True,
                                                kv_offset=off)
     text = _compiled_text(fn, q, kv, kv, S((1,), jnp.int32))
-    assert "tpu_custom_call" in text
+    assert _named_kernel(text, "flash_prefill")
 
 
 def test_w4a8_matmul_compiles(one_chip):
