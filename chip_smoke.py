@@ -242,12 +242,13 @@ def one_chip(cfg, seed: int, log: CompileLog) -> None:
     B = snap["table"].shape[0]
     q = jnp.asarray(rng.standard_normal(
         (B, cfg.num_heads, 1, cfg.resolved_head_dim)), jnp.float32)
-    args = (q, jnp.asarray(snap["k"]), jnp.asarray(snap["v"]),
+    # the snapshot is layer 0 of the stacked pool: a one-layer stack
+    args = (q, jnp.asarray(snap["k"])[None], jnp.asarray(snap["v"])[None],
             jnp.asarray(snap["table"]), jnp.asarray(snap["len"]))
     got = paged_attention.paged_decode_attention(
-        *args, interpret=eng.cfg.kernels != "pallas")
+        *args, layer=0, interpret=eng.cfg.kernels != "pallas")
     with jax.default_matmul_precision("highest"):
-        want = ref.paged_decode_attention(*args)
+        want = ref.paged_decode_attention(*args, layer=0)
     diff = float(jnp.max(jnp.abs(got - want)))
     emit("kernel_vs_oracle", layer=0, slot_lens=snap["len"].tolist(),
          pool_dtype=str(snap["k"].dtype), max_abs_diff=diff, atol=KERNEL_ATOL,

@@ -173,33 +173,37 @@ def tp_paged_decode_attention(mesh: Mesh, axis_name: str = "model", *,
     (DESIGN.md §11).
 
     q: (B, Hq, 1, D) cut on heads over ``axis_name``;
-    pools: (num_pages, ps, Hkv, D) cut on KV heads;
-    page_table (B, P) / cache_len (B,): host-owned, replicated;
-    ``quant=True``: two more operands, the (num_pages, Hkv) per-page
+    pools: the whole stacked ``(L, num_pages, ps, Hkv/f, f·D)`` pool in
+    its stored, possibly lane-folded layout, cut on the head-row axis;
+    page_table (B, P) / cache_len (B,): host-owned, replicated; layer: the
+    int32 layer index the pool is read at, replicated;
+    ``quant=True``: two more operands, the (L, num_pages, Hkv) per-page
     dequant scales of a quantized pool, cut on the same KV heads.
 
-    Requires Hq % tp == 0 and Hkv % tp == 0.  Contiguous head blocks keep
-    GQA alignment in-shard — q heads [i*Hq/tp, ...) attend exactly the kv
-    heads [i*Hkv/tp, ...) their column-sharded wk/wv produced — so each
-    shard runs the UNCHANGED flash-decode grid on its (N, ps, Hkv/tp, D)
-    slice and NO collective is needed at all: the output comes back cut on
-    heads, ready for the row-sharded wo.
+    Requires Hq % tp == 0 and (Hkv/f) % tp == 0.  Contiguous head blocks
+    keep GQA alignment in-shard — q heads [i*Hq/tp, ...) attend exactly the
+    kv heads [i*Hkv/tp, ...) their column-sharded wk/wv produced, which are
+    head rows [i*Hkv/(f*tp), ...) of the folded pool — so each shard runs
+    the UNCHANGED flash-decode grid on its slice and NO collective is
+    needed at all: the output comes back cut on heads, ready for the
+    row-sharded wo.
     """
     from repro.kernels import paged_attention as _pa
 
-    def local(q, k_pool, v_pool, table, length, k_scale=None, v_scale=None):
+    def local(q, k_pool, v_pool, table, length, layer, k_scale=None,
+              v_scale=None):
         return _pa.paged_decode_attention(
             q, k_pool, v_pool, table, length, window=window,
             softcap=softcap, scale=scale, k_scale=k_scale, v_scale=v_scale,
-            interpret=interpret)
+            layer=layer, interpret=interpret)
 
     b = tuple(a for a in batch_axes if a in mesh.axis_names) or None
-    pool = P(None, None, axis_name, None)
-    scales = (P(None, axis_name),) * 2 if quant else ()
+    pool = P(None, None, None, axis_name, None)
+    scales = (P(None, None, axis_name),) * 2 if quant else ()
     return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b, axis_name, None, None), pool, pool,
-                  P(b, None), P(b)) + scales,
+                  P(b, None), P(b), P()) + scales,
         out_specs=P(b, axis_name, None, None), check_vma=False)
 
 
@@ -211,7 +215,7 @@ def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
                                     quant: bool = False):
     """The Hkv < tp fallback: heads replicate, the PAGE axis splits.
 
-    When the TP degree does not divide the KV head count the pool stays
+    When the TP degree does not divide the pool's head rows the pool stays
     replicated (sharding rules auto-drop the axis), so the head-cut path
     has nothing to cut.  Instead each shard walks a 1/tp slice of every
     slot's page-table columns — its local flash-decode sees lengths
@@ -219,14 +223,17 @@ def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
     pairs combine exactly in log-sum-exp space with two tiny psums
     (O(B*Hq*D) wire), the paged twin of ``distributed_decode_attention``.
     Sliding-window leaves never page (serve/pages.py), so the merge only
-    covers the window-free case.  ``quant=True`` takes the quantized
-    pool's (num_pages, Hkv) scales as two more (replicated) operands.
+    covers the window-free case.  The pool and its layer index arrive as
+    in :func:`tp_paged_decode_attention`; ``quant=True`` takes the
+    quantized pool's (L, num_pages, Hkv) scales as two more (replicated)
+    operands.
     """
     from repro.kernels import paged_attention as _pa
 
-    def local(q, k_pool, v_pool, table, length, k_scale=None, v_scale=None):
+    def local(q, k_pool, v_pool, table, length, layer, k_scale=None,
+              v_scale=None):
         B, Hq, _, D = q.shape
-        ps = k_pool.shape[1]
+        ps = k_pool.shape[-3]
         span = table.shape[1] * ps          # positions this shard covers
         off = jax.lax.axis_index(axis_name) * span
         # rebase: local position p corresponds to global off + p, so the
@@ -234,7 +241,7 @@ def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
         len_loc = jnp.clip(length - off, 0, span)
         out, lse = _pa.paged_decode_attention(
             q, k_pool, v_pool, table, len_loc, softcap=softcap,
-            scale=scale, k_scale=k_scale, v_scale=v_scale,
+            scale=scale, k_scale=k_scale, v_scale=v_scale, layer=layer,
             interpret=interpret, return_lse=True)
         lse = lse.reshape(B, Hq, 1)          # (B, Hkv, group) -> head order
         m = jax.lax.pmax(lse, axis_name)
@@ -246,6 +253,6 @@ def tp_paged_decode_attention_merge(mesh: Mesh, axis_name: str = "model", *,
     b = tuple(a for a in batch_axes if a in mesh.axis_names) or None
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(b), P(), P(), P(b, axis_name), P(b))
+        in_specs=(P(b), P(), P(), P(b, axis_name), P(b), P())
         + ((P(), P()) if quant else ()),
         out_specs=P(b), check_vma=False)

@@ -68,9 +68,11 @@ _SERVE_CACHE_RULES: Sequence[Tuple[str, Tuple[Optional[str], ...]]] = (
     (r".*len$", ("batch",)),
 )
 
-# Page-pool leaf rules: trailing (num_pages, page_size, Hkv, hd) — the pool
-# is cut on KV heads so each shard owns a (N, ps, Hkv/tp, hd) slice and the
-# paged flash-decode grid is unchanged per shard.  Page ids stay global
+# Page-pool leaf rules: trailing (num_pages, page_size, Hkv/f, f*hd) — the
+# stored, possibly lane-folded layout (layers.kv_fold) — the pool is cut on
+# its head rows so each shard owns a (N, ps, Hkv/(f*tp), f*hd) slice, i.e.
+# Hkv/tp contiguous kv heads, and the paged flash-decode grid is unchanged
+# per shard.  Page ids stay global
 # (tables replicated), so HostPager/CoW/prefix logic needs no distribution
 # awareness.
 _POOL_CACHE_RULES: Sequence[Tuple[str, Tuple[Optional[str], ...]]] = (
@@ -234,14 +236,16 @@ def pool_pspecs(pcache, cfg: ModelConfig, mesh: Mesh, sa):
 
     ``sa`` is the per-leaf sequence-axis tree (``serve.pages.seq_axes``):
     leaves with ``s_ax >= 0`` are in pool layout (trailing
-    ``(num_pages, page_size, Hkv, hd)``) and cut on KV heads; the rest keep
+    ``(num_pages, page_size, Hkv/f, f·hd)``, ``layers.kv_fold``) and cut on
+    their head rows; the rest keep
     their dense slot layout and take the serve rules.  Shape-checked like
     every rule here — an Hkv that ``tp`` does not divide replicates.
 
     Quantized pools (``QuantizedLeaf`` leaves, DESIGN.md §13) get a
     QuantizedLeaf of specs back: codes shard like the dense pool leaf and
     the per-page scales — trailing ``(num_pages, Hkv)`` — put the model
-    axis on their own Hkv dim so they follow the KV-head cut.
+    axis on their own Hkv dim so they follow the KV-head cut (and
+    replicate where the codes do).
 
     ``sa`` is mapped FIRST so QuantizedLeaf subtrees arrive whole at the
     leaf fn instead of being flattened into codes/scales.
@@ -256,10 +260,14 @@ def pool_pspecs(pcache, cfg: ModelConfig, mesh: Mesh, sa):
         if isinstance(leaf, QuantizedLeaf):
             if matched is None:
                 return QuantizedLeaf(P(), P(), leaf.kv_dtype, leaf.out_dtype)
+            codes = _fit(matched, leaf.codes.shape, ax)
+            # scales follow the codes' head cut: a folded pool whose head
+            # rows do not divide replicates, and its scales with it
+            cut = any(a is not None for a in tuple(codes))
             return QuantizedLeaf(
-                _fit(matched, leaf.codes.shape, ax),
-                _fit((None, "model"), leaf.scales.shape, ax),
-                leaf.kv_dtype, leaf.out_dtype)
+                codes,
+                _fit((None, "model"), leaf.scales.shape, ax) if cut
+                else P(), leaf.kv_dtype, leaf.out_dtype)
         if matched is None or not hasattr(leaf, "shape"):
             return P()
         return _fit(matched, leaf.shape, ax)

@@ -119,12 +119,18 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, cache_len, *,
                            scale: Optional[float] = None,
                            kernels: str,
                            model_axis: Optional[str] = None,
-                           batch_axes: tuple = ()):
+                           batch_axes: tuple = (),
+                           layer):
     """Gather-free decode attention THROUGH the page table: no dense-view
     transient (serve/pages.py::gather_view) is ever materialized.  The
     Pallas kernel walks ``pool[table]`` page-block-wise (flash-decode over
     the split-K page axis, DESIGN.md §6); the reference is a ``lax.scan``
     over pages with the same online-softmax accumulation.
+
+    Pools arrive whole and stacked, ``(L, N, ps, Hkv/f, f·D)`` in their
+    stored, possibly lane-folded layout (``layers.kv_fold``), and are read
+    at layer ``layer`` (a traced scalar) in place — no layer slice of the
+    pool is ever taken.
 
     On a TP serving mesh (``model_axis`` names a >1-sized axis of the
     ambient mesh) the Pallas branch dispatches per shard (DESIGN.md §11):
@@ -145,32 +151,33 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, cache_len, *,
         return ref.paged_decode_attention(q, k_pool, v_pool, page_table,
                                           cache_len, window=window,
                                           softcap=softcap, scale=scale,
-                                          k_scale=k_scale, v_scale=v_scale)
+                                          k_scale=k_scale, v_scale=v_scale,
+                                          layer=layer)
     interpret = kernels == "interpret"
     mesh, tp = _tp_mesh(model_axis)
     if tp > 1:
         from repro.distributed import collectives
-        Hq, Hkv = q.shape[1], k_pool.shape[2]
+        # the pool is cut on its stored head rows (Hkv/f of them)
+        Hq, rows = q.shape[1], k_pool.shape[-2]
+        args = (q, k_pool, v_pool, page_table, cache_len,
+                jnp.asarray(layer, jnp.int32)) + (
+            () if k_scale is None else (k_scale, v_scale))
         if Hq % tp == 0:
-            if Hkv % tp == 0:
-                fn = collectives.tp_paged_decode_attention(
+            if rows % tp == 0:
+                return collectives.tp_paged_decode_attention(
                     mesh, model_axis, window=window, softcap=softcap,
                     scale=scale, batch_axes=batch_axes, interpret=interpret,
-                    quant=k_scale is not None)
-                return fn(q, k_pool, v_pool, page_table, cache_len,
-                          *(() if k_scale is None else (k_scale, v_scale)))
+                    quant=k_scale is not None)(*args)
             if window is None and page_table.shape[1] % tp == 0:
-                fn = collectives.tp_paged_decode_attention_merge(
+                return collectives.tp_paged_decode_attention_merge(
                     mesh, model_axis, softcap=softcap, scale=scale,
                     batch_axes=batch_axes, interpret=interpret,
-                    quant=k_scale is not None)
-                return fn(q, k_pool, v_pool, page_table, cache_len,
-                          *(() if k_scale is None else (k_scale, v_scale)))
+                    quant=k_scale is not None)(*args)
     return _pa.paged_decode_attention(q, k_pool, v_pool, page_table,
                                       cache_len, window=window,
                                       softcap=softcap, scale=scale,
                                       k_scale=k_scale, v_scale=v_scale,
-                                      interpret=interpret)
+                                      layer=layer, interpret=interpret)
 
 
 def chunk_attention(q, k_cache, v_cache, q_pos, *,

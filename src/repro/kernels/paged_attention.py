@@ -13,14 +13,27 @@ are revisited across page steps.  The page table and the per-slot lengths
 ride in as **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``),
 so the K/V BlockSpec index maps resolve ``table[b, p]`` BEFORE the kernel
 body runs and the DMA engine fetches exactly one physical page per grid
-step.  The block is the WHOLE page, every kv head at once:
-``(1, ps, Hkv, D)`` over the ``(N, ps, Hkv, D)`` pool, whose last two
-dimensions equal the array's — the only page block of this layout the TPU
-compiler accepts (a per-head ``(1, ps, 1, D)`` block is refused).  Scores
-are per-head lane reductions over ``D`` (decode is bandwidth-bound: one
-query row per kv head leaves the MXU nothing to fill), and GQA groups loop
-statically inside the body, so every page is read once per slot, never per
-q head.
+step.  The kernel reads the WHOLE stacked pool ``(L, N, ps, Hkv/f, f·D)``
+as it is stored — the decode step never slices a layer out of it, which on
+the TPU is a copy of that layer's pool (DESIGN.md §6): the pool's leading
+axes merge into one run of ``L·N`` pages (a bitcast of the row-major pool)
+and the layer becomes an offset of the page ids, ``layer·N + table[b, p]``.
+The block is the whole page, every kv head at once:
+``(1, ps, Hkv/f, f·D)``, whose last two dimensions equal the array's — the
+only page block of this layout the TPU compiler accepts (a per-head block
+is refused).
+
+The stored layout is LANE-DENSE (``layers.kv_fold``): when ``D < 128``
+divides 128 and the kv heads divide into groups of ``f = 128 // D``, ``f``
+adjacent heads share one 128-lane row, head ``r·f + i`` in lanes
+``[i·D, (i+1)·D)`` of row ``r`` (``f = 1`` is the plain ``(Hkv, D)``
+layout).  Per-head quantities (scores, running max and denominator) are
+kept lane-expanded — each head's value repeated over its ``D`` lanes — so
+the probability-times-value product stays elementwise on full vregs; the
+per-head score reduction is a masked lane sum per lane group.  Scores are
+lane reductions over ``D`` (decode is bandwidth-bound: one query row per
+kv head leaves the MXU nothing to fill), and GQA groups loop statically
+inside the body, so every page is read once per slot, never per q head.
 
 Pages past a slot's ``cache_len`` (and pages wholly below its sliding
 window) skip their compute with ``pl.when``.  For the dead TAIL the
@@ -45,12 +58,44 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _lane_groups(fold: int, head_dim: int):
+    """(1, 1, f·D) int32: the head slot ``i`` of each lane of a folded row."""
+    return jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, fold * head_dim), 2) // head_dim
+
+
+def _head_sum(x, fold: int, lanes):
+    """Per-head sums over the ``D`` lanes of each head in ``x (…, R, f·D)``.
+    Unfolded (``f = 1``): ``(…, R, 1)``.  Folded: lane-expanded
+    ``(…, R, f·D)``, each head's sum repeated over its own lanes."""
+    if fold == 1:
+        return jnp.sum(x, axis=-1, keepdims=True)
+    out = jnp.zeros_like(x)
+    for i in range(fold):
+        sel = lanes == i
+        out = jnp.where(sel, jnp.sum(jnp.where(sel, x, 0.0), axis=-1,
+                                     keepdims=True), out)
+    return out
+
+
+def _expand_heads(s, fold: int, lanes):
+    """Per-kv-head values ``(R, f)`` -> the layout of ``_head_sum``'s output:
+    ``(R, 1)`` unfolded, ``(R, f·D)`` lane-expanded when folded."""
+    if fold == 1:
+        return s
+    out = jnp.zeros(s.shape[:-1] + (lanes.shape[-1],), s.dtype)
+    for i in range(fold):
+        out = jnp.where(lanes[0] == i, s[:, i:i + 1], out)
+    return out
+
+
 def _kernel(*refs, scale: float, window: Optional[int],
             softcap: Optional[float], ps: int, n_pages: int, group: int,
-            quant: bool = False, with_lse: bool = False):
-    table_ref, len_ref, q_ref, k_ref, v_ref, *rest = refs
+            fold: int, head_dim: int, quant: bool = False,
+            with_lse: bool = False):
+    _, len_ref, q_ref, k_ref, v_ref, *rest = refs
     if quant:
-        # per-page, per-kv-head dequant scales ride as (1, Hkv, 1) blocks
+        # per-page, per-kv-head dequant scales ride as (1, Hkv/f, f) blocks
         # fetched through the same table index as the page (DESIGN.md §13)
         ks_ref, vs_ref, *rest = rest
     o_ref, *rest = rest
@@ -78,37 +123,41 @@ def _kernel(*refs, scale: float, window: Optional[int],
 
     @pl.when(needed)
     def _compute():
-        k = k_ref[0].astype(jnp.float32)              # (ps, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)              # (ps, Hkv, D)
+        lanes = _lane_groups(fold, head_dim) if fold > 1 else None
+        k = k_ref[0].astype(jnp.float32)              # (ps, R, f·D)
+        v = v_ref[0].astype(jnp.float32)              # (ps, R, f·D)
         pos = start + jax.lax.broadcasted_iota(jnp.int32, (ps, 1, 1), 0)
         mask = pos < length
         if window is not None:
             mask &= pos > length - 1 - window
+        if quant:
+            ks = _expand_heads(ks_ref[0], fold, lanes)   # (R, W)
+            vs = _expand_heads(vs_ref[0], fold, lanes)
         for g in range(group):
-            q = q_ref[0, g].astype(jnp.float32)       # (Hkv, D)
-            logits = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            q = q_ref[0, g].astype(jnp.float32)       # (R, f·D)
+            logits = _head_sum(k * q[None], fold, lanes) * scale
             if quant:
                 # fused dequant: scores of int8/fp8 codes times this
                 # page's per-kv-head scale == scores of dequantized keys
-                logits = logits * ks_ref[0][None]
+                logits = logits * ks[None]
             if softcap is not None:
                 logits = softcap * jnp.tanh(logits / softcap)
-            logits = jnp.where(mask, logits, NEG_INF)  # (ps, Hkv, 1)
+            logits = jnp.where(mask, logits, NEG_INF)  # (ps, R, W)
 
-            m_prev, l_prev = m_ref[g], l_ref[g]        # (Hkv, 1)
+            m_prev, l_prev = m_ref[g], l_ref[g]        # (R, W)
             m_new = jnp.maximum(m_prev, logits.max(axis=0))
             pexp = jnp.exp(logits - m_new[None])
             corr = jnp.exp(m_prev - m_new)
             l_ref[g] = l_prev * corr + pexp.sum(axis=0)
             m_ref[g] = m_new
-            pv = jnp.sum(pexp * v, axis=0)             # (Hkv, D)
+            pv = jnp.sum(pexp * v, axis=0)             # (R, f·D)
             if quant:
-                pv = pv * vs_ref[0]
+                pv = pv * vs
             acc_ref[g] = acc_ref[g] * corr + pv
 
     @pl.when(p == n_pages - 1)
     def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)             # (group, Hkv, 1)
+        l = jnp.maximum(l_ref[...], 1e-30)             # (group, R, W)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         if lse_ref is not None:
             # log-sum-exp of this invocation's logits, for cross-shard
@@ -129,18 +178,24 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            scale: Optional[float] = None,
                            k_scale: Optional[jnp.ndarray] = None,
                            v_scale: Optional[jnp.ndarray] = None,
+                           layer,
                            interpret: bool = False,
                            return_lse: bool = False):
-    """q (B, Hq, 1, D); pools (num_pages, page_size, Hkv, D);
-    page_table (B, P) int32 physical page ids; cache_len (B,) valid lengths.
-    Hq % Hkv == 0.  Token position t of slot b lives at
-    ``(page_table[b, t // page_size], t % page_size)``.
+    """q (B, Hq, 1, D); pools: the whole stacked pool
+    ``(L, num_pages, page_size, Hkv/f, f·D)`` in its stored layout (``f``
+    from ``layers.kv_fold``; ``f = 1`` is the plain ``(.., Hkv, D)``
+    layout), of which layer ``layer`` (an int32 scalar, traced or not) is
+    read in place; page_table (B, P) int32 physical page ids of that layer
+    (the dead tail points at its scratch page 0); cache_len
+    (B,) valid lengths.  Hq % Hkv == 0.  Token position t of slot b lives
+    at ``(page_table[b, t // page_size], t % page_size)``.  The fold is
+    read off the shapes: ``f = (f·D) / D``.
 
-    ``k_scale``/``v_scale`` (num_pages, Hkv) f32 dequantize QUANTIZED pools
-    (int8/fp8 codes) at page-fetch time: each page's scales are fetched
-    through the same table index as the page itself and multiply its
-    scores and value sum inside the online softmax — the split-K grid is
-    unchanged and the pages stream at 1 byte per element (DESIGN.md §13).
+    ``k_scale``/``v_scale`` (L, num_pages, Hkv) f32 dequantize QUANTIZED
+    pools (int8/fp8 codes) at page-fetch time: each page's scales are fetched through the
+    same table index as the page itself and multiply its scores and value
+    sum inside the online softmax — the split-K grid is unchanged and the
+    pages stream at 1 byte per element (DESIGN.md §13).
 
     ``return_lse=True`` additionally returns the per-head log-sum-exp
     (B, Hkv, group) f32 of the computed logits, so partial results over a
@@ -150,14 +205,24 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     ``interpret=True`` runs the Pallas interpreter (CPU validation only).
     """
     B, Hq, _, D = q.shape
-    ps, Hkv = k_pool.shape[1], k_pool.shape[2]
+    L, N, ps, R, FD = k_pool.shape
+    fold = FD // D
+    Hkv = R * fold
     P = page_table.shape[1]
     group = Hq // Hkv
+    W = 1 if fold == 1 else FD             # width of the per-head stats
     s = scale if scale is not None else D ** -0.5
     quant = k_scale is not None
-    # GQA layout (B, group, Hkv, D): the group dim rides inside the q/out
-    # block, so each KV page is fetched once per slot (not once per q head)
-    qg = q[:, :, 0, :].reshape(B, Hkv, group, D).transpose(0, 2, 1, 3)
+    # GQA layout (B, group, Hkv/f, f·D): the group dim rides inside the
+    # q/out block, so each KV page is fetched once per slot (not once per q
+    # head), and q's heads fold into lanes exactly as the pool's do
+    qg = (q[:, :, 0, :].reshape(B, Hkv, group, D).transpose(0, 2, 1, 3)
+          .reshape(B, group, R, FD))
+    # the stacked pool as one run of L·N pages (its leading axes merged: a
+    # bitcast of the row-major pool), and the layer as an offset of the
+    # page ids, so each grid step fetches page ``layer·N + table[b, p]``
+    flat = lambda a: a.reshape((L * N,) + a.shape[2:])
+    table = page_table.astype(jnp.int32) + jnp.asarray(layer, jnp.int32) * N
 
     # index maps take the two scalar-prefetch refs as trailing args
     def per_slot(*block):
@@ -167,44 +232,48 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         return pl.BlockSpec(
             block, lambda b, p, tbl, _: (tbl[b, p],) + (0,) * (len(block) - 1))
 
-    in_specs = [per_slot(1, group, Hkv, D),
-                per_page(1, ps, Hkv, D), per_page(1, ps, Hkv, D)]
-    operands = [qg, k_pool, v_pool]
+    in_specs = [per_slot(1, group, R, FD), per_page(1, ps, R, FD),
+                per_page(1, ps, R, FD)]
+    operands = [qg, flat(k_pool), flat(v_pool)]
     if quant:
-        in_specs += [per_page(1, Hkv, 1), per_page(1, Hkv, 1)]
-        operands += [k_scale.astype(jnp.float32)[:, :, None],
-                     v_scale.astype(jnp.float32)[:, :, None]]
-    out_specs = per_slot(1, group, Hkv, D)
-    out_shape = jax.ShapeDtypeStruct((B, group, Hkv, D), q.dtype)
+        # per-page scales, heads split as the page's rows are
+        in_specs += [per_page(1, R, fold), per_page(1, R, fold)]
+        operands += [flat(sc).astype(jnp.float32).reshape(L * N, R, fold)
+                     for sc in (k_scale, v_scale)]
+    out_specs = per_slot(1, group, R, FD)
+    out_shape = jax.ShapeDtypeStruct((B, group, R, FD), q.dtype)
     if return_lse:
-        out_specs = [out_specs, per_slot(1, group, Hkv, 1)]
+        out_specs = [out_specs, per_slot(1, group, R, W)]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((B, group, Hkv, 1), jnp.float32)]
+                     jax.ShapeDtypeStruct((B, group, R, W), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                    # page_table, cache_len
         grid=(B, P),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((group, Hkv, 1), jnp.float32),
-            pltpu.VMEM((group, Hkv, 1), jnp.float32),
-            pltpu.VMEM((group, Hkv, D), jnp.float32),
+            pltpu.VMEM((group, R, W), jnp.float32),
+            pltpu.VMEM((group, R, W), jnp.float32),
+            pltpu.VMEM((group, R, FD), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=s, window=window, softcap=softcap, ps=ps,
-            n_pages=P, group=group, quant=quant, with_lse=return_lse),
+            n_pages=P, group=group, fold=fold, head_dim=D, quant=quant,
+            with_lse=return_lse),
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_flash_decode",
-    )(page_table.astype(jnp.int32), jnp.asarray(cache_len, jnp.int32),
-      *operands)
-    to_heads = lambda a: a.transpose(0, 2, 1, 3).reshape(B, Hq, 1, -1)
+    )(table, jnp.asarray(cache_len, jnp.int32), *operands)
+    # (B, group, Hkv, D) -> (B, Hq, 1, D), head h·group + g
+    to_heads = lambda a: (a.reshape(B, group, Hkv, -1).transpose(0, 2, 1, 3)
+                          .reshape(B, Hq, 1, -1))
     if return_lse:
         out, lse = out
+        lse = lse[..., ::D] if fold > 1 else lse       # one lane per head
         return to_heads(out), to_heads(lse).reshape(B, Hkv, group)
     return to_heads(out)

@@ -195,7 +195,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            softcap: Optional[float] = None,
                            scale: Optional[float] = None,
                            k_scale: Optional[jnp.ndarray] = None,
-                           v_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                           v_scale: Optional[jnp.ndarray] = None,
+                           layer) -> jnp.ndarray:
     """Single-position attention computed THROUGH the page table.
 
     The gather-free oracle: a ``lax.scan`` over the page-table columns with
@@ -204,20 +205,23 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     ``(B, S, ...)`` dense-view transient is ever materialized (the
     memory-wall copy ``gather_view`` + :func:`decode_attention` pays).
 
-    q: (B, Hq, 1, D); pools: (num_pages, page_size, Hkv, D);
-    page_table: (B, P) physical page ids (unallocated entries may point at
-    the scratch page — masked positions never contribute); cache_len: (B,)
-    valid lengths.  Token position t of slot b lives at
+    q: (B, Hq, 1, D); pools: the whole stacked ``(L, num_pages,
+    page_size, Hkv/f, f·D)`` pool in its stored, possibly lane-folded
+    layout (``layers.kv_fold``), read at layer ``layer``; page_table: (B, P)
+    physical page ids (unallocated entries may point at the scratch page —
+    masked positions never contribute); cache_len: (B,) valid lengths.
+    Token position t of slot b lives at
     ``(page_table[b, t // page_size], t % page_size)``.
 
-    ``k_scale``/``v_scale`` (num_pages, Hkv) f32, when given, dequantize a
+    ``k_scale``/``v_scale`` (L, num_pages, Hkv) f32, when given, dequantize a
     QUANTIZED pool (int8/fp8 codes, DESIGN.md §13) at page-fetch time:
     each fetched page block is multiplied by its per-page, per-kv-head
     scale before entering the online softmax — the oracle for the fused
     dequant in the Pallas kernel.
     """
     B, Hq, _, D = q.shape
-    ps, Hkv = k_pool.shape[1], k_pool.shape[2]
+    ps = k_pool.shape[-3]
+    Hkv = k_pool.shape[-2] * k_pool.shape[-1] // D
     P = page_table.shape[1]
     group = Hq // Hkv
     s = (scale if scale is not None else D ** -0.5)
@@ -227,12 +231,13 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     def page_step(carry, inputs):
         m, l, acc = carry
         pi, pid = inputs                     # page column index, (B,) phys ids
-        kb = k_pool[pid].astype(jnp.float32)             # (B, ps, Hkv, D)
-        vb = v_pool[pid].astype(jnp.float32)
+        unfold = lambda a: a.reshape(B, ps, Hkv, D).astype(jnp.float32)
+        kb = unfold(k_pool[layer, pid])                 # (B, ps, Hkv, D)
+        vb = unfold(v_pool[layer, pid])
         if k_scale is not None:
-            kb = kb * k_scale[pid][:, None, :, None]     # (B,1,Hkv,1)
+            kb = kb * k_scale[layer, pid][:, None, :, None]   # (B,1,Hkv,1)
         if v_scale is not None:
-            vb = vb * v_scale[pid][:, None, :, None]
+            vb = vb * v_scale[layer, pid][:, None, :, None]
         logits = jnp.einsum("bhgd,bshd->bhgs", qg, kb) * s
         logits = _soft_cap(logits, softcap)
         pos = pi * ps + jnp.arange(ps)                   # absolute positions

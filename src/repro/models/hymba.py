@@ -181,11 +181,12 @@ def paged_decode_step(params, cache, table, tokens: jnp.ndarray,
     """Hymba decode straight through the page pool (DESIGN.md §6).
 
     Only engaged when the attention window covers the whole cache (global
-    attention), i.e. the K/V leaves actually page: k/v arrive as
-    kernel-friendly ``(L, num_pages, page_size, Hkv, hd)`` pool leaves swept
-    by the layer scan, appended in place and attended gather-free; the SSM
-    state — the O(1) recurrent half of the hybrid head — stays dense and is
-    frozen (like ``len``) where ``write`` is False.
+    attention), i.e. the K/V leaves actually page: k/v arrive as stacked
+    ``(L, num_pages, page_size, Hkv/f, f·hd)`` pool leaves (stored layout,
+    ``layers.kv_fold``) CARRIED through the layer scan and appended and
+    attended in place at each layer's index — never sliced out and
+    restacked; the SSM state — the O(1) recurrent half of the hybrid head —
+    stays dense and is frozen (like ``len``) where ``write`` is False.
     """
     del seq_axes  # hymba pages k/v iff this entry point is reached at all
     B = tokens.shape[0]
@@ -197,26 +198,30 @@ def paged_decode_step(params, cache, table, tokens: jnp.ndarray,
     positions = pos[:, None]
     window = cfg.layer_pattern[0].window
 
-    def layer(x, inputs):
-        p, kc, vc, sstate = inputs
+    def layer(carry, inputs):
+        x, kc, vc = carry
+        p, sstate, li = inputs
         xn = L.rmsnorm(x, p["ln_in"], cfg.norm_eps)
         q, k, v = L.qkv_project(p["attn"], xn, cfg.num_heads,
                                 cfg.num_kv_heads, hd, cfg.kernels)
         q = L.rope(q, positions, cfg.rope_theta)
         k = L.rope(k, positions, cfg.rope_theta)
-        kc = L.paged_cache_write(kc, k, table, pos, write)
-        vc = L.paged_cache_write(vc, v, table, pos, write)
+        kc = L.paged_cache_write(kc, k, table, pos, write, layer=li)
+        vc = L.paged_cache_write(vc, v, table, pos, write, layer=li)
         o = ops.paged_decode_attention(q, kc, vc, table, pos + 1,
                                        window=window,
                                        kernels=cfg.kernels,
                                        model_axis=cfg.parallel.model_axis,
-                                       batch_axes=cfg.parallel.batch_axes)
+                                       batch_axes=cfg.parallel.batch_axes,
+                                       layer=li)
         x, new_state = _fuse_tail(p, x, xn, o, sstate, cfg)
         new_state = jnp.where(write[:, None, None], new_state, sstate)
-        return x, (kc, vc, new_state)
+        return (x, kc, vc), new_state
 
-    x, (k, v, ssm) = jax.lax.scan(
-        layer, x, (params["blocks"], cache["k"], cache["v"], cache["ssm"]))
+    n_layers = cache["ssm"].shape[0]
+    (x, k, v), ssm = jax.lax.scan(
+        layer, (x, cache["k"], cache["v"]),
+        (params["blocks"], cache["ssm"], jnp.arange(n_layers)))
     logits = _logits_head(params, x, cfg)
     return logits, {"k": k, "v": v, "ssm": ssm,
                     "len": cache["len"] + write.astype(jnp.int32)}

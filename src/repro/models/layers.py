@@ -204,41 +204,79 @@ def page_offsets(table: jnp.ndarray, pos: jnp.ndarray, write: jnp.ndarray,
     return jnp.where(write, page, SCRATCH_PAGE), pos % page_size
 
 
+LANES = 128
+
+
+def kv_fold(num_kv_heads: int, head_dim: int, tp: int = 1) -> int:
+    """How many kv heads share one 128-lane row of a page-pool leaf.
+
+    The TPU tiles an array's last dimension over 128 lanes, so a pool
+    stored ``(.., Hkv, D)`` with ``D < 128`` pads every row — the compiler
+    then picks another axis (the page axis) as the lane dimension, and each
+    program that hands a page block to the paged kernel first re-lays the
+    whole pool out.  Folding ``f = 128 // D`` adjacent heads into the minor
+    dimension, ``(.., Hkv/f, f·D)``, makes the stored row lane-dense, so the
+    row-major layout is the one the compiler keeps.  Head ``r·f + i`` lives
+    in lanes ``[i·D, (i+1)·D)`` of row ``r``: the fold is a plain row-major
+    reshape of the trailing ``(Hkv, D)`` pair (DESIGN.md §5).
+
+    ``f = 1`` (``D >= 128``, a ``D`` that does not divide 128, or an Hkv
+    that does not split into groups of ``f``) leaves the plain layout.  So
+    does a fold that would take away the head cut of the TP serving mesh
+    (DESIGN.md §11): where ``tp`` divides Hkv, the pool's head rows
+    ``Hkv/f`` must divide by ``tp`` too, or the pool would replicate."""
+    if head_dim < LANES and LANES % head_dim == 0:
+        f = LANES // head_dim
+        if num_kv_heads % f == 0 and (
+                num_kv_heads % tp or (num_kv_heads // f) % tp == 0):
+            return f
+    return 1
+
+
+def unfold_heads(x: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
+    """``(.., Hkv/f, f·D)`` -> ``(.., Hkv, D)``: undo ``kv_fold``."""
+    return x.reshape(x.shape[:-2] + (num_kv_heads, -1))
+
+
 def paged_cache_write(pool, new: jnp.ndarray,
                       table: jnp.ndarray, pos: jnp.ndarray,
-                      write: jnp.ndarray):
+                      write: jnp.ndarray, layer):
     """Append one token's K or V per slot directly into the page pool.
 
-    pool: (num_pages, page_size, Hkv, D) — one layer's kernel-friendly pool
-    slice (or its :class:`QuantizedLeaf` counterpart, which routes to the
-    quantize-on-write append); new: (B, Hkv, 1, D); table: (B, P) physical
-    page ids; pos: (B,) write positions (== ``len``); write: (B,) bool —
-    inactive slots land on the scratch page so the program shape never
-    depends on the active set.  O(B x token bytes) pool traffic: the
-    in-place counterpart of ``cache_write`` with no dense view in sight.
+    pool: the whole stacked ``(L, num_pages, page_size, Hkv/f, f·D)`` pool
+    in its stored, possibly lane-folded layout (``kv_fold``), written at
+    layer ``layer`` (a traced scalar) in place; a :class:`QuantizedLeaf`
+    routes to the quantize-on-write append.  new: (B, Hkv, 1, D); table:
+    (B, P) physical page ids; pos: (B,) write positions (== ``len``);
+    write: (B,) bool — inactive slots land on the scratch page so the
+    program shape never depends on the active set.  O(B x token bytes) pool
+    traffic: the in-place counterpart of ``cache_write`` with no dense view
+    in sight.
     """
+    codes = pool.codes if isinstance(pool, QuantizedLeaf) else pool
+    ps = codes.shape[-3]
+    page, off = page_offsets(table, pos, write, ps)
+    tok = new[:, :, 0, :].reshape((new.shape[0],) + codes.shape[-2:])
     if isinstance(pool, QuantizedLeaf):
-        ps = pool.codes.shape[1]
-        page, off = page_offsets(table, pos, write, ps)
-        tok = new[:, :, 0, :]                          # (B, Hkv, D)
         codes, scales = quant_page_append(pool.codes, pool.scales, tok,
-                                          page, off, pool.kv_dtype)
+                                          layer, page, off, pool.kv_dtype)
         return QuantizedLeaf(codes, scales, pool.kv_dtype, pool.out_dtype)
-    page, off = page_offsets(table, pos, write, pool.shape[1])
-    tok = new[:, :, 0, :].astype(pool.dtype)           # (B, Hkv, D)
-    return pool.at[page, off].set(tok)
+    return pool.at[layer, page, off].set(tok.astype(pool.dtype))
 
 
 def quant_page_append(codes: jnp.ndarray, scales: jnp.ndarray,
-                      tok: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray,
-                      kv_dtype: str):
+                      tok: jnp.ndarray, layer, page: jnp.ndarray,
+                      off: jnp.ndarray, kv_dtype: str):
     """The quantize-on-write page append core (DESIGN.md §13).
 
-    codes: (N, ps, *rest) pool codes in pages-leading layout; scales:
-    (N, *rest[:-1]) matching per-page scales (the trailing head_dim axis is
-    reduced away); tok: (B, *rest) the new token; page/off: (B,) resolved
-    write coordinates (``page_offsets``).  Decode-append must REQUANTIZE
-    the page — the incoming token can exceed the page's current range — so
+    codes: (L, N, ps, *rest) stacked pool codes in pages-leading layout,
+    the trailing pair of ``rest`` possibly lane-folded ``(Hkv/f, f·D)``;
+    scales: (L, N, *rest[:-2], Hkv) matching per-page, per-kv-head scales
+    (the fold is read off their last axis).  tok: (B, *rest) the new
+    tokens in the codes' layout; layer/page/off: resolved write
+    coordinates — page/off (B,) from ``page_offsets``, ``layer`` a scalar
+    or a (B,) array, one layer per token.  Decode-append must REQUANTIZE the
+    page — the incoming token can exceed the page's current range — so
     the page is dequantized, the token inserted at ``off``, and the whole
     page re-encoded under ``max(old_scale, needed)``:
 
@@ -254,15 +292,18 @@ def quant_page_append(codes: jnp.ndarray, scales: jnp.ndarray,
     scatters may hit duplicate indices only on the scratch page (inactive
     slots), whose content is garbage by contract.
     """
-    nd = codes.ndim
-    ps = codes.shape[1]
+    at = (layer, page)
+    hkv = scales.shape[-1]
     B = tok.shape[0]
+    stored = codes[at]                                 # (B, ps, *rest)
+    cp = unfold_heads(stored, hkv)                     # (B, ps, .., Hkv, D)
+    nd = cp.ndim
+    ps = cp.shape[1]
 
-    def _x(s):  # (B, *rest[:-1]) -> broadcast over (B, ps, *rest)
+    def _x(s):  # (B, .., Hkv) -> broadcast over (B, ps, .., Hkv, D)
         return jnp.expand_dims(s, (1, nd - 1))
 
-    cp = codes[page]                                   # (B, ps, *rest)
-    sp = scales[page]                                  # (B, *rest[:-1])
+    sp = scales[at]                                    # (B, .., Hkv)
     fresh = (off > 0).reshape((B,) + (1,) * (sp.ndim - 1))
     sp_eff = jnp.where(fresh, sp, 0.0)
     old = cp.astype(jnp.float32) * _x(sp_eff)
@@ -270,11 +311,12 @@ def quant_page_append(codes: jnp.ndarray, scales: jnp.ndarray,
     keep = (idx < off[:, None]).reshape((B, ps) + (1,) * (nd - 2))
     ins = (idx == off[:, None]).reshape((B, ps) + (1,) * (nd - 2))
     merged = jnp.where(keep, old, 0.0)
-    merged = jnp.where(ins, tok[:, None].astype(jnp.float32), merged)
-    amax = jnp.max(jnp.abs(merged), axis=(1, nd - 1))  # (B, *rest[:-1])
+    merged = jnp.where(ins, unfold_heads(tok, hkv)[:, None].astype(
+        jnp.float32), merged)
+    amax = jnp.max(jnp.abs(merged), axis=(1, nd - 1))  # (B, .., Hkv)
     new_sc = jnp.maximum(sp_eff, kv_pow2_scale(amax, kv_dtype))
-    q = kv_quantize(merged, _x(new_sc), kv_dtype)
-    return codes.at[page].set(q), scales.at[page].set(new_sc)
+    q = kv_quantize(merged, _x(new_sc), kv_dtype).reshape(stored.shape)
+    return codes.at[at].set(q), scales.at[at].set(new_sc)
 
 
 def fake_quant_pages(leaf: jnp.ndarray, s_ax: int, n_tokens,

@@ -426,17 +426,33 @@ def prefill_chunk(params, cache, tokens: jnp.ndarray, true_len, cfg: ModelConfig
     return new_cache
 
 
+def _stack_layers(leaf, n: int):
+    """(n_groups, gs//P, ...) -> (n_groups * gs//P, ...): one layer axis.
+    A bitcast for the row-major pool (the merged axes are its major ones);
+    QuantizedLeaf codes and scales reshape together."""
+    return jax.tree.map(lambda a: a.reshape((n,) + a.shape[2:]), leaf)
+
+
 def paged_decode_step(params, cache, table, tokens: jnp.ndarray,
                       cfg: ModelConfig, *, write=None, seq_axes=None):
     """One decode step straight through the page pool — no dense view.
 
     The gather-free serve path (DESIGN.md §6): full-attention pattern slots
-    hold their K/V as kernel-friendly pool leaves
-    ``(n_groups, gs//P, num_pages, page_size, Hkv, hd)``; the step appends
-    the new token to its page (``layers.paged_cache_write``) and attends via
+    hold their K/V as pool leaves ``(n_groups, gs//P, num_pages, page_size,
+    Hkv/f, f·hd)`` in the stored, possibly lane-folded layout
+    (``layers.kv_fold``); the step appends the new token to its page
+    (``layers.paged_cache_write``) and attends via
     ``ops.paged_decode_attention`` with pages as the split-K axis.  Ring
     (window < max_len) slots and ``len`` keep the dense layout and the
     exact ``decode_step`` math.
+
+    The pool is UPDATED IN PLACE: the layer scan carries the whole stacked
+    pool instead of slicing it as ``xs`` and restacking it as ``ys`` (which
+    on the TPU copies every layer's pool out and back in, every step).
+    Each layer scatters its token at ``(layer, page, offset)`` and the
+    kernel reads that layer's pages of the stacked pool as stored
+    (``ops.paged_decode_attention(..., layer=)``), so no program holds a
+    pool-sized temporary.
 
     cache: paged slot-cache pytree; table: (B, P) physical page ids;
     tokens: (B,); write: (B,) bool — slots with ``write=False`` are frozen
@@ -449,6 +465,7 @@ def paged_decode_step(params, cache, table, tokens: jnp.ndarray,
     """
     n_groups, group_size = group_layout(cfg)
     P = len(cfg.layer_pattern)
+    per = group_size // P                    # layers of one slot per group
     B = tokens.shape[0]
     if write is None:
         write = jnp.ones((B,), bool)
@@ -457,65 +474,62 @@ def paged_decode_step(params, cache, table, tokens: jnp.ndarray,
     pos = cache["len"]                        # (B,)
     positions = pos[:, None]                  # (B, 1)
     wmask = write[:, None, None, None]
+    kv = {n: [_stack_layers(a, n_groups * per) for a in cache[n]]
+          for n in ("k", "v")}
 
-    def group_fn(x, group_in):
+    def group_fn(carry, group_in):
+        x, kv = carry
+        kv = {n: list(leaves) for n, leaves in kv.items()}
         gp = group_in["blocks"]
-        new_k, new_v = [], []
         for j in range(group_size):
             slot = j % P
             spec = cfg.layer_pattern[slot]
             pj = jax.tree.map(lambda a: a[j], gp)
-            kc = group_in["k"][slot][j // P]
-            vc = group_in["v"][slot][j // P]
+            lyr = group_in["g"] * per + j // P
+            kc, vc = kv["k"][slot], kv["v"][slot]
             q, k, v = _block_qkv(pj, x, positions, cfg)
             if sa_k[slot] >= 0:
                 # pool leaf: in-place page append + gather-free attention.
                 # NOTE: no seq-sharded (decode_attn="shard_map") variant —
                 # the page pool is not sequence-sharded; configs needing it
                 # must serve via the dense or gather disciplines.
-                kc = L.paged_cache_write(kc, k, table, pos, write)
-                vc = L.paged_cache_write(vc, v, table, pos, write)
+                kc = L.paged_cache_write(kc, k, table, pos, write, layer=lyr)
+                vc = L.paged_cache_write(vc, v, table, pos, write, layer=lyr)
                 o = ops.paged_decode_attention(
                     q, kc, vc, table, pos + 1, window=spec.window,
                     softcap=cfg.softcap, kernels=cfg.kernels,
                     model_axis=cfg.parallel.model_axis,
-                    batch_axes=cfg.parallel.batch_axes)
+                    batch_axes=cfg.parallel.batch_axes, layer=lyr)
             else:
                 # ring buffer (window < max_len): dense path, frozen where
                 # the slot is inactive
-                S = kc.shape[2]
+                kl, vl = kc[lyr], vc[lyr]
+                S = kl.shape[2]
                 idx = pos % S
-                kc_new = L.cache_write(kc, k[:, :, 0:1], idx,
+                kl_new = L.cache_write(kl, k[:, :, 0:1], idx,
                                        cfg.parallel.aligned_decode)
-                vc_new = L.cache_write(vc, v[:, :, 0:1], idx,
+                vl_new = L.cache_write(vl, v[:, :, 0:1], idx,
                                        cfg.parallel.aligned_decode)
-                kc = jnp.where(wmask, kc_new, kc)
-                vc = jnp.where(wmask, vc_new, vc)
+                kc = kc.at[lyr].set(jnp.where(wmask, kl_new, kl))
+                vc = vc.at[lyr].set(jnp.where(wmask, vl_new, vl))
                 eff_len = jnp.minimum(pos + 1, S)
                 # no dist_axis: the engine refuses inplace paging under
                 # decode_attn="shard_map" (serve/engine.py), so the seq-
                 # sharded decode variant is unreachable from this step
-                o = ops.decode_attention(q, kc_new, vc_new, eff_len,
+                o = ops.decode_attention(q, kl_new, vl_new, eff_len,
                                          softcap=cfg.softcap)
             x = _block_tail(pj, x, o, cfg)
-            new_k.append(kc)
-            new_v.append(vc)
-        # tree-map stack: quantized pool leaves are QuantizedLeaf pytrees
-        # (codes + scales stack independently); dense ring leaves are plain
-        # arrays and take the same path
-        stack = lambda xs: jax.tree.map(lambda *ls: jnp.stack(ls), *xs)
-        upd = {
-            "k": [stack(new_k[s::P]) for s in range(P)],
-            "v": [stack(new_v[s::P]) for s in range(P)],
-        }
-        return x, upd
+            kv["k"][slot], kv["v"][slot] = kc, vc
+        return (x, kv), None
 
-    xs = {"blocks": params["blocks"], "k": cache["k"], "v": cache["v"]}
-    x, upd = jax.lax.scan(group_fn, x, xs)
+    xs = {"blocks": params["blocks"], "g": jnp.arange(n_groups)}
+    (x, kv), _ = jax.lax.scan(group_fn, (x, kv), xs)
 
     logits = _logits_head(params, x[:, 0], cfg)
     new_cache = dict(cache)
-    new_cache["k"], new_cache["v"] = upd["k"], upd["v"]
+    for n in ("k", "v"):
+        new_cache[n] = [jax.tree.map(lambda a, o: a.reshape(o.shape), new, old)
+                        for new, old in zip(kv[n], cache[n])]
     new_cache["len"] = cache["len"] + write.astype(jnp.int32)
     return logits, new_cache
 

@@ -65,6 +65,39 @@ from repro.serve import telemetry
 from repro.train import step as step_mod
 
 
+def make_paged_step(cfg: ModelConfig, ba, sa, paged_attn: str = "inplace"):
+    """The masked paged decode program ``decode_slots`` jits (its name,
+    ``paged_step``, is what a profile calls it): ``(params, pool, table,
+    tokens, active, corrupt) -> (next tokens, finite-logits flags, pool)``.
+
+    ``"inplace"`` appends and attends through the page table with the pool
+    updated in place (``api.paged_decode_step``); ``"gather"`` is the
+    reference discipline: gather the dense view, run the family
+    ``decode_step``, scatter the one new token per active slot back."""
+    if paged_attn == "inplace":
+        def paged_step(params, pcache, table, toks, act_m, bad):
+            logits, pc = api.paged_decode_step(
+                params, pcache, table, toks, cfg, write=act_m, seq_axes=sa)
+            logits = slots_mod.corrupt_logits(logits, bad)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            ok = slots_mod.finite_logits(logits)
+            return nxt, ok, pc
+    else:
+        def paged_step(params, pcache, table, toks, act_m, bad):
+            view = pages_mod.gather_tree(pcache, table, ba, sa,
+                                         cfg.resolved_head_dim)
+            pos = view["len"]
+            logits, new = api.decode_step(params, view, toks, cfg)
+            logits = slots_mod.corrupt_logits(logits, bad)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            ok = slots_mod.finite_logits(logits)
+            new = slots_mod.select_slots(act_m, new, view, ba)
+            pc = pages_mod.scatter_token_tree(
+                pcache, new, table, pos, act_m, ba, sa)
+            return nxt, ok, pc
+    return paged_step
+
+
 class ServeEngine(pages_mod.PagedEngineMixin):
     def __init__(self, cfg: ModelConfig, params, mesh=None, max_len: int = 128,
                  fused: bool = True, page_size: Optional[int] = None,
@@ -417,12 +450,15 @@ class ServeEngine(pages_mod.PagedEngineMixin):
                 "paged_attn='gather' or the dense slot cache")
         self._paging_active = True
         pool = self._pager.reset(n_slots)
-        # head-cut pool placement (DESIGN.md §11): each model shard owns a
-        # (num_pages, ps, Hkv/tp, hd) slice; the rules auto-replicate any
-        # leaf whose Hkv the TP degree does not divide (the Hkv < tp
-        # fallback), in which case the per-shard byte accounting stays 1-way
+        # head-cut pool placement (DESIGN.md §11): each model shard owns
+        # Hkv/tp kv heads, (num_pages, ps, Hkv/(f*tp), f*hd) of the folded
+        # pool (the fold keeps that cut wherever tp divides Hkv); the rules
+        # auto-replicate any leaf whose head rows the TP degree does not
+        # divide (the Hkv < tp fallback), in which case the per-shard byte
+        # accounting stays 1-way
         pshape = pages_mod.pool_shape(shape, ba, sa, pool.num_pages,
-                                      self.page_size, self._kv_dtype)
+                                      self.page_size, self._kv_dtype,
+                                      self._tp)
         pool_specs = shd.pool_pspecs(pshape, self._ragged_cfg, self.mesh, sa)
         self._pool_sh = shd.with_sharding(self.mesh, pool_specs)
         self._b1_sh = None
@@ -438,7 +474,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             return pages_mod.make_pool(shape, ba, sa, pool.num_pages,
                                        self.page_size,
                                        shardings=self._pool_sh,
-                                       kv_dtype=self._kv_dtype)
+                                       kv_dtype=self._kv_dtype, tp=self._tp)
 
     def _kv_cut(self, pool_specs, sa) -> int:
         return shd.pool_kv_cut(pool_specs, sa, self._tp,
@@ -610,29 +646,7 @@ class ServeEngine(pages_mod.PagedEngineMixin):
             if self._paged_step is None:
                 ba, sa = self._slot_axes(), self._slot_seq_axes()
                 rcfg = self._ragged_cfg
-
-                if self._paged_attn == "inplace":
-                    def paged_step(params, pcache, table, toks, act_m, bad):
-                        logits, pc = api.paged_decode_step(
-                            params, pcache, table, toks, rcfg, write=act_m,
-                            seq_axes=sa)
-                        logits = slots_mod.corrupt_logits(logits, bad)
-                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        ok = slots_mod.finite_logits(logits)
-                        return nxt, ok, pc
-                else:
-                    def paged_step(params, pcache, table, toks, act_m, bad):
-                        view = pages_mod.gather_tree(pcache, table, ba, sa)
-                        pos = view["len"]
-                        logits, new = api.decode_step(params, view, toks,
-                                                      rcfg)
-                        logits = slots_mod.corrupt_logits(logits, bad)
-                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                        ok = slots_mod.finite_logits(logits)
-                        new = slots_mod.select_slots(act_m, new, view, ba)
-                        pc = pages_mod.scatter_token_tree(
-                            pcache, new, table, pos, act_m, ba, sa)
-                        return nxt, ok, pc
+                paged_step = make_paged_step(rcfg, ba, sa, self._paged_attn)
 
                 # explicit placements: pool head-cut, page table replicated
                 # (host-owned), per-slot vectors on the batch axis — the

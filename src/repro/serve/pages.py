@@ -18,11 +18,18 @@ The pool layout is KERNEL-FRIENDLY: the ``(num_pages, page_size)`` axes sit
 exactly where the batch axis sat in the dense leaf (``page_axis``), so
 leading non-sequence axes — the stacked layer axis of
 ``(L, B, Hkv, S, hd)`` caches, the ``(n_groups, gs)`` group axes of the lm
-family — stay leading.  A ``lax.scan`` over depth therefore sweeps
-per-layer pool slices ``(num_pages, page_size, Hkv, hd)`` directly, which
-is the exact operand layout ``kernels/paged_attention.py`` (and its jnp
-oracle) consumes: attention walks ``pool[table]`` page-block-wise with no
-dense-view transient (DESIGN.md §6).
+family — stay leading, and the trailing ``(Hkv, hd)`` pair is stored
+LANE-DENSE as ``(Hkv/f, f·hd)`` (``layers.kv_fold``: ``f = 128 // hd``
+adjacent heads share a 128-lane row when that divides; ``f = 1``
+otherwise), so the TPU keeps the row-major layout it is written in.  The
+paged kernel reads ``layer·N + table[b, p]`` page blocks of the whole
+stacked pool as stored, with no dense-view transient (DESIGN.md §6).
+
+Every program that touches a pool leaf — the decode step, the insert, the
+prefix seed, the CoW copy — scatters or gathers pages at the page axis
+where it sits, on the leaf with its leading axes merged (a bitcast): only
+the B=1 request cache or the page blocks of one request are ever
+re-laid-out, never the pool.
 
 Physical page 0 is reserved as a *scratch* page: table entries beyond a
 slot's allocated pages point at it, so every jitted program can write a
@@ -83,9 +90,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers import (KV_DTYPES, QuantizedLeaf, SCRATCH_PAGE,
-                                 fake_quant_pages, kv_pow2_scale,
+                                 fake_quant_pages, kv_fold, kv_pow2_scale,
                                  kv_quantize, page_offsets,
-                                 quant_page_append)
+                                 quant_page_append, unfold_heads)
 from repro.serve import telemetry
 from repro.serve.errors import PageLifecycleError, ReservationError
 
@@ -105,6 +112,9 @@ __all__ = [
     "gather_tree",
     "scatter_token_tree",
     "insert_tree",
+    "copy_pages",
+    "make_insert",
+    "make_seed",
     "fake_quant_tree",
     "pool_bytes",
     "page_token_bytes",
@@ -818,10 +828,7 @@ class PagedEngineMixin:
         their mesh context where needed."""
         self._pager.note_insert(slot, n_tokens)
         if self._paged_insert_jit is None:
-            def insert(pcache, single, row, s, n):
-                return insert_tree(pcache, single, row, s, ba, sa,
-                                   n_tokens=n)
-
+            insert = make_insert(ba, sa)
             kw = {}
             if self._pool_sh is not None:
                 kw = dict(in_shardings=(self._pool_sh, self._b1_sh,
@@ -885,20 +892,7 @@ class PagedEngineMixin:
         (same pytree as the slot cache).  One compiled program covers
         every slot, match length and page assignment (row/len traced)."""
         if self._seed_jit is None:
-            def seed(pcache, row, m):
-                def leaf(path, sh, b_ax, s_ax, pl):
-                    if s_ax >= 0:
-                        return gather_view(pl, row[None, :], b_ax, s_ax)
-                    if _is_len_path(path):
-                        return jnp.full(sh.shape, m, sh.dtype)
-                    # unreachable when prefix sharing is active (the
-                    # shareability rule excludes other dense leaves), but
-                    # keep the seed total
-                    return jnp.zeros(sh.shape, sh.dtype)
-
-                return jax.tree_util.tree_map_with_path(
-                    leaf, b1_shape, ba, sa, pcache)
-
+            seed = make_seed(ba, sa, b1_shape)
             kw = {}
             if self._pool_sh is not None:
                 kw = dict(in_shardings=(self._pool_sh, None, None),
@@ -916,25 +910,7 @@ class PagedEngineMixin:
             return cache
         if self._cow_jit is None:
             def copy(pcache, src, dst):
-                def leaf(b_ax, s_ax, p):
-                    if s_ax < 0:
-                        return p
-                    if isinstance(p, QuantizedLeaf):
-                        # scales travel with their page: a CoW'd page keeps
-                        # encoding the same values in its private copy
-                        cl = _pages_leading(p.codes, b_ax, s_ax)
-                        sl = _scales_leading(p.scales, b_ax, s_ax)
-                        return QuantizedLeaf(
-                            _pages_restore(cl.at[dst].set(cl[src]),
-                                           b_ax, s_ax),
-                            _scales_restore(sl.at[dst].set(sl[src]),
-                                            b_ax, s_ax),
-                            p.kv_dtype, p.out_dtype)
-                    pl = _pages_leading(p, b_ax, s_ax)
-                    pl = pl.at[dst].set(pl[src])
-                    return _pages_restore(pl, b_ax, s_ax)
-
-                return jax.tree.map(leaf, ba, sa, pcache)
+                return copy_pages(pcache, src, dst, ba, sa)
 
             kw = {}
             if self._pool_sh is not None:
@@ -1045,34 +1021,41 @@ def page_axis(b_ax: int, s_ax: int) -> int:
     return b_ax - (1 if 0 <= s_ax < b_ax else 0)
 
 
-def _pages_leading(pool: jnp.ndarray, b_ax: int, s_ax: int) -> jnp.ndarray:
-    """View a pool leaf with the (num_pages, page_size) axes leading."""
-    pax = page_axis(b_ax, s_ax)
-    return jnp.moveaxis(pool, (pax, pax + 1), (0, 1))
-
-
-def _pages_restore(pool: jnp.ndarray, b_ax: int, s_ax: int) -> jnp.ndarray:
-    pax = page_axis(b_ax, s_ax)
-    return jnp.moveaxis(pool, (0, 1), (pax, pax + 1))
+def _layers(a: jnp.ndarray, pax: int) -> jnp.ndarray:
+    """A pool leaf (or its scales) with the ``pax`` axes before the page
+    axis merged into one leading layer axis: ``(Lf, num_pages, ...)``.
+    Those are the leaf's major axes, so on the row-major stored pool this
+    is a bitcast — the page axis is indexed where it sits, and nothing of
+    the pool moves (module docstring)."""
+    return a.reshape((math.prod(a.shape[:pax]),) + a.shape[pax:])
 
 
 def pool_shape(cache_shape: Any, ba: Any, sa: Any, num_pages: int,
-               page_size: int, kv_dtype: str = "bf16") -> Any:
+               page_size: int, kv_dtype: str = "bf16", tp: int = 1) -> Any:
     """ShapeDtypeStruct pytree of the paged slot cache (``make_pool``
     without the allocation) — what the sharding rules and eval_shape-based
-    plumbing consume.  With ``kv_dtype`` other than "bf16" every pool leaf
-    becomes a :class:`QuantizedLeaf`: codes in the pool layout at the
-    quantized dtype plus per-page × per-kv-head float32 scales (the pool
-    shape minus the ``page_size`` axis and the trailing head_dim axis)."""
+    plumbing consume.  The trailing ``(Hkv, hd)`` pair is stored folded,
+    ``(Hkv/f, f·hd)`` with ``f = layers.kv_fold(Hkv, hd, tp)``, ``tp`` the
+    degree of the head cut the pool will be placed with.  With
+    ``kv_dtype`` other than "bf16" every pool leaf becomes a
+    :class:`QuantizedLeaf`: codes in the pool layout at the quantized dtype
+    plus per-page × per-kv-head float32 scales ``(.., num_pages, Hkv)``
+    (the dense leaf less its batch, sequence and head_dim axes, plus the
+    page axis) — one scale per head whether or not its head shares a lane
+    row."""
     def leaf(a, b_ax, s_ax):
         if s_ax < 0:
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
         rest = tuple(d for i, d in enumerate(a.shape) if i not in (b_ax, s_ax))
         pax = page_axis(b_ax, s_ax)
-        shape = rest[:pax] + (num_pages, page_size) + rest[pax:]
+        lead, tail = rest[:pax], rest[pax:]
+        if len(tail) >= 2:
+            f = kv_fold(tail[-2], tail[-1], tp)
+            tail = tail[:-2] + (tail[-2] // f, tail[-1] * f)
+        shape = lead + (num_pages, page_size) + tail
         if kv_dtype == "bf16":
             return jax.ShapeDtypeStruct(shape, a.dtype)
-        sc_shape = shape[:pax + 1] + shape[pax + 2:-1]
+        sc_shape = lead + (num_pages,) + rest[pax:-1]
         return QuantizedLeaf(
             jax.ShapeDtypeStruct(shape, KV_DTYPES[kv_dtype]),
             jax.ShapeDtypeStruct(sc_shape, jnp.float32),
@@ -1083,7 +1066,7 @@ def pool_shape(cache_shape: Any, ba: Any, sa: Any, num_pages: int,
 
 def make_pool(cache_shape: Any, ba: Any, sa: Any, num_pages: int,
               page_size: int, shardings: Any = None,
-              kv_dtype: str = "bf16") -> Any:
+              kv_dtype: str = "bf16", tp: int = 1) -> Any:
     """Allocate the paged slot cache: pool layout for paging leaves, dense
     ``(max_slots, ...)`` zeros for everything else.  Same pytree structure
     as the dense cache, so engines keep one cache object either way
@@ -1092,8 +1075,10 @@ def make_pool(cache_shape: Any, ba: Any, sa: Any, num_pages: int,
 
     ``shardings`` (optional) is a matching pytree of ``jax.sharding``
     placements — the TP serving mesh allocates each pool leaf directly in
-    its head-cut layout, so no full replica ever materializes."""
-    shapes = pool_shape(cache_shape, ba, sa, num_pages, page_size, kv_dtype)
+    its head-cut layout (``tp`` ways, which the fold respects), so no full
+    replica ever materializes."""
+    shapes = pool_shape(cache_shape, ba, sa, num_pages, page_size, kv_dtype,
+                        tp)
     if shardings is None:
         return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), shapes)
     return jax.tree.map(lambda a, sh: jnp.zeros(a.shape, a.dtype, device=sh),
@@ -1170,50 +1155,44 @@ def kv_token_bytes_quant(cache_shape: Any, ba: Any, sa: Any,
 # ----------------------------------------------------------------------------
 # Traced page-table ops (fixed shapes, traced indices — compile once)
 # ----------------------------------------------------------------------------
-def _scales_leading(scales: jnp.ndarray, b_ax: int, s_ax: int) -> jnp.ndarray:
-    """View a scale array with its page axis leading (scales have no
-    page_size axis, so only one move)."""
-    return jnp.moveaxis(scales, page_axis(b_ax, s_ax), 0)
-
-
-def _scales_restore(scales: jnp.ndarray, b_ax: int, s_ax: int) -> jnp.ndarray:
-    return jnp.moveaxis(scales, 0, page_axis(b_ax, s_ax))
-
-
-def gather_view(pool, table: jnp.ndarray, b_ax: int,
-                s_ax: int) -> jnp.ndarray:
+def gather_view(pool, table: jnp.ndarray, b_ax: int, s_ax: int,
+                head_dim: int) -> jnp.ndarray:
     """Reassemble one paged leaf into its dense ``(..., B, ..., S, ...)``
     view through the page table ``(B, P)``.  This materializes the
     O(B x max_len) transient the in-place paged attention path exists to
-    avoid — fallback/oracle and prefix-seed only (DESIGN.md §6).
+    avoid — fallback/oracle and prefix-seed only (DESIGN.md §6).  The pages
+    are gathered where the page axis sits; only the gathered blocks are
+    unfolded and re-laid-out.  ``head_dim`` is the dense leaf's last axis,
+    which the stored, possibly lane-folded row (``layers.kv_fold``) is
+    unfolded to (a leaf with no head axis after its pages has no fold).
 
     A :class:`QuantizedLeaf` gathers codes and scales together and
     DEQUANTIZES: power-of-two scales make the product exact even in a
     bfloat16 ``out_dtype`` (layers.kv_dequantize), so the dense view is
     bit-stable — the prefix seed path depends on that."""
     B, P = table.shape
-    if isinstance(pool, QuantizedLeaf):
-        cl = _pages_leading(pool.codes, b_ax, s_ax)    # (N, ps, *rest)
-        sl = _scales_leading(pool.scales, b_ax, s_ax)  # (N, *rest[:-1])
-        g = cl[table]                                  # (B, P, ps, *rest)
-        gs = jnp.expand_dims(sl[table], (2, sl.ndim + 2))
-        d = (g.astype(jnp.float32) * gs).astype(jnp.dtype(pool.out_dtype))
-        d = d.reshape((B, P * cl.shape[1]) + cl.shape[2:])
-        return jnp.moveaxis(d, (0, 1), (b_ax, s_ax))
-    p = _pages_leading(pool, b_ax, s_ax)               # (N, ps, *rest)
-    ps = p.shape[1]
-    g = p[table]                                       # (B, P, ps, *rest)
-    g = g.reshape((B, P * ps) + p.shape[2:])           # (B, S, *rest)
-    return jnp.moveaxis(g, (0, 1), (b_ax, s_ax))
+    pax = page_axis(b_ax, s_ax)
+    quant = isinstance(pool, QuantizedLeaf)
+    codes = pool.codes if quant else pool
+    g = _layers(codes, pax)[:, table]                    # (Lf, B, P, ps, ..)
+    if codes.ndim - pax >= 4:           # a (rows, lanes) tail: unfold heads
+        g = g.reshape(g.shape[:-2] + (-1, head_dim))
+    if quant:
+        sc = _layers(pool.scales, pax)[:, table]           # (Lf, B, P, .., Hkv)
+        g = (g.astype(jnp.float32) * jnp.expand_dims(sc, (3, sc.ndim + 1))
+             ).astype(jnp.dtype(pool.out_dtype))
+    d = g.reshape(codes.shape[:pax] + (B, P * g.shape[3]) + g.shape[4:])
+    return jnp.moveaxis(d, (pax, pax + 1), (b_ax, s_ax))
 
 
-def gather_tree(pcache: Any, table: jnp.ndarray, ba: Any, sa: Any) -> Any:
+def gather_tree(pcache: Any, table: jnp.ndarray, ba: Any, sa: Any,
+                head_dim: int) -> Any:
     """Dense-view pytree: paged leaves gathered, dense leaves passed through.
     The result is exactly the cache pytree the family decode_step expects.
     (``ba`` leads the tree.map so quantized pool subtrees arrive whole.)"""
     return jax.tree.map(
         lambda b_ax, s_ax, p: p if s_ax < 0
-        else gather_view(p, table, b_ax, s_ax),
+        else gather_view(p, table, b_ax, s_ax, head_dim),
         ba, sa, pcache)
 
 
@@ -1237,20 +1216,26 @@ def scatter_token(pool, table: jnp.ndarray,
     Quantized pools route through the shared quantize-on-write append core
     (``layers.quant_page_append``) so the gather discipline's writeback and
     the in-place append encode pages identically."""
-    if isinstance(pool, QuantizedLeaf):
-        cl = _pages_leading(pool.codes, b_ax, s_ax)
-        sl = _scales_leading(pool.scales, b_ax, s_ax)
-        tok = _take_token(new_leaf, pos, b_ax, s_ax)   # (B, *rest)
-        page, off = page_offsets(table, pos, write, cl.shape[1])
-        cl, sl = quant_page_append(cl, sl, tok, page, off, pool.kv_dtype)
-        return QuantizedLeaf(_pages_restore(cl, b_ax, s_ax),
-                             _scales_restore(sl, b_ax, s_ax),
+    pax = page_axis(b_ax, s_ax)
+    quant = isinstance(pool, QuantizedLeaf)
+    codes = _layers(pool.codes if quant else pool, pax)   # (Lf, N, ps, ..)
+    B = pos.shape[0]
+    tok = jnp.moveaxis(_take_token(new_leaf, pos, b_ax, s_ax), 0, pax)
+    tok = tok.reshape((codes.shape[0], B) + codes.shape[3:])  # stored layout
+    page, off = page_offsets(table, pos, write, codes.shape[2])
+    if quant:
+        # one write per (layer, slot): the layer axis joins the slot axis
+        Lf = codes.shape[0]
+        cl, sl = quant_page_append(
+            codes, _layers(pool.scales, pax),
+            tok.reshape((Lf * B,) + tok.shape[2:]),
+            jnp.repeat(jnp.arange(Lf), B), jnp.tile(page, Lf),
+            jnp.tile(off, Lf), pool.kv_dtype)
+        return QuantizedLeaf(cl.reshape(pool.codes.shape),
+                             sl.reshape(pool.scales.shape),
                              pool.kv_dtype, pool.out_dtype)
-    p = _pages_leading(pool, b_ax, s_ax)
-    tok = _take_token(new_leaf, pos, b_ax, s_ax)       # (B, *rest)
-    page, off = page_offsets(table, pos, write, p.shape[1])
-    p = p.at[page, off].set(tok.astype(pool.dtype))
-    return _pages_restore(p, b_ax, s_ax)
+    return codes.at[:, page, off].set(tok.astype(pool.dtype)).reshape(
+        pool.shape)
 
 
 def scatter_token_tree(pcache: Any, new_view: Any, table: jnp.ndarray,
@@ -1265,12 +1250,16 @@ def scatter_token_tree(pcache: Any, new_view: Any, table: jnp.ndarray,
         ba, sa, new_view, pcache)
 
 
-def _dense_to_pages(leaf: jnp.ndarray, b_ax: int, s_ax: int,
-                    ps: int) -> jnp.ndarray:
-    """B=1 dense leaf -> (P, ps, *rest) page blocks."""
-    x = jnp.moveaxis(leaf, (b_ax, s_ax), (0, 1))       # (1, S, *rest)
-    S = x.shape[1]
-    return x[0].reshape((S // ps, ps) + x.shape[2:])
+def _dense_to_pages(leaf: jnp.ndarray, b_ax: int, s_ax: int, ps: int,
+                    stored: Tuple[int, ...]) -> jnp.ndarray:
+    """B=1 dense leaf -> (Lf, P, ps, *stored tail) page blocks in the
+    pool's stored (folded) layout — the one re-layout an admission pays,
+    of the request's own cache."""
+    pax = page_axis(b_ax, s_ax)
+    x = jnp.moveaxis(leaf, (b_ax, s_ax), (pax, pax + 1))   # (.., 1, S, ..)
+    S = x.shape[pax + 1]
+    return x.reshape((math.prod(x.shape[:pax]), S // ps, ps)
+                     + tuple(stored[pax + 2:]))
 
 
 def insert_tree(pcache: Any, single: Any, table_row: jnp.ndarray,
@@ -1280,6 +1269,7 @@ def insert_tree(pcache: Any, single: Any, table_row: jnp.ndarray,
     blocks to the slot's physical pages (excess logical pages hit scratch),
     dense leaves do the ordinary slot insert.  ``table_row``/``slot`` are
     traced — ONE compiled program covers every slot and page assignment.
+    The pool is written in place at its page axis (``_layers``).
 
     ``n_tokens`` (traced; required for quantized pools) is the prefilled
     length: positions at or past it are GARBAGE the prefill bucketing wrote
@@ -1291,33 +1281,85 @@ def insert_tree(pcache: Any, single: Any, table_row: jnp.ndarray,
         if s_ax < 0:
             return jax.lax.dynamic_update_slice_in_dim(
                 p, s.astype(p.dtype), slot, axis=b_ax)
-        if isinstance(p, QuantizedLeaf):
-            cl = _pages_leading(p.codes, b_ax, s_ax)
-            sl = _scales_leading(p.scales, b_ax, s_ax)
-            ps = cl.shape[1]
-            blocks = _dense_to_pages(s, b_ax, s_ax, ps)    # (P, ps, *rest)
-            P = blocks.shape[0]
-            pos = (jnp.arange(P) * ps)[:, None] + jnp.arange(ps)[None, :]
-            valid = pos < jnp.asarray(n_tokens, jnp.int32)
-            blocks = jnp.where(
-                valid.reshape((P, ps) + (1,) * (blocks.ndim - 2)),
-                blocks.astype(jnp.float32), 0.0)
-            amax = jnp.max(jnp.abs(blocks), axis=(1, blocks.ndim - 1))
-            sc = kv_pow2_scale(amax, p.kv_dtype)           # (P, *rest[:-1])
-            q = kv_quantize(
-                blocks, jnp.expand_dims(sc, (1, blocks.ndim - 1)),
-                p.kv_dtype)
-            cl = cl.at[table_row].set(q)
-            sl = sl.at[table_row].set(sc)
-            return QuantizedLeaf(_pages_restore(cl, b_ax, s_ax),
-                                 _scales_restore(sl, b_ax, s_ax),
-                                 p.kv_dtype, p.out_dtype)
-        pl = _pages_leading(p, b_ax, s_ax)
-        blocks = _dense_to_pages(s, b_ax, s_ax, pl.shape[1])
-        pl = pl.at[table_row].set(blocks.astype(p.dtype))
-        return _pages_restore(pl, b_ax, s_ax)
+        pax = page_axis(b_ax, s_ax)
+        quant = isinstance(p, QuantizedLeaf)
+        codes = p.codes if quant else p
+        ps = codes.shape[pax + 1]
+        blocks = _dense_to_pages(s, b_ax, s_ax, ps, codes.shape)
+        if not quant:
+            return _layers(p, pax).at[:, table_row].set(
+                blocks.astype(p.dtype)).reshape(p.shape)
+        hkv = p.scales.shape[-1]
+        P = blocks.shape[1]
+        blocks = unfold_heads(blocks, hkv)             # (Lf, P, ps, .., Hkv, D)
+        pos = (jnp.arange(P) * ps)[:, None] + jnp.arange(ps)[None, :]
+        valid = pos < jnp.asarray(n_tokens, jnp.int32)
+        blocks = jnp.where(
+            valid.reshape((1, P, ps) + (1,) * (blocks.ndim - 3)),
+            blocks.astype(jnp.float32), 0.0)
+        amax = jnp.max(jnp.abs(blocks), axis=(2, blocks.ndim - 1))
+        sc = kv_pow2_scale(amax, p.kv_dtype)           # (Lf, P, .., Hkv)
+        q = kv_quantize(blocks, jnp.expand_dims(sc, (2, blocks.ndim - 1)),
+                        p.kv_dtype)
+        cl = _layers(p.codes, pax)
+        cl = cl.at[:, table_row].set(q.reshape((cl.shape[0], P)
+                                               + cl.shape[2:]))
+        sl = _layers(p.scales, pax).at[:, table_row].set(sc)
+        return QuantizedLeaf(cl.reshape(p.codes.shape),
+                             sl.reshape(p.scales.shape),
+                             p.kv_dtype, p.out_dtype)
 
     return jax.tree.map(leaf, ba, sa, single, pcache)
+
+
+def copy_pages(pcache: Any, src, dst, ba: Any, sa: Any) -> Any:
+    """Copy physical page ``src`` onto ``dst`` in every pool leaf, in place
+    (the CoW copy).  Scales travel with their page: a CoW'd page keeps
+    encoding the same values in its private copy."""
+    def leaf(b_ax, s_ax, p):
+        if s_ax < 0:
+            return p
+        pax = page_axis(b_ax, s_ax)
+
+        def cp(a):
+            f = _layers(a, pax)
+            return f.at[:, dst].set(f[:, src]).reshape(a.shape)
+        if isinstance(p, QuantizedLeaf):
+            return QuantizedLeaf(cp(p.codes), cp(p.scales), p.kv_dtype,
+                                 p.out_dtype)
+        return cp(p)
+
+    return jax.tree.map(leaf, ba, sa, pcache)
+
+
+def make_insert(ba: Any, sa: Any):
+    """The admission program the engines jit (a profile calls it
+    ``insert``): ``(pool, B=1 cache, table row, slot, n_tokens) -> pool``."""
+    def insert(pcache, single, row, s, n):
+        return insert_tree(pcache, single, row, s, ba, sa, n_tokens=n)
+    return insert
+
+
+def make_seed(ba: Any, sa: Any, b1_shape: Any):
+    """The prefix-seed program the engines jit (a profile calls it
+    ``seed``): ``(pool, table row, cached length m) -> B=1 cache`` holding
+    the row's pages (the matched prefix) gathered from the pool, with
+    ``len = m``; ``b1_shape`` is the B=1 request cache's eval_shape (same
+    pytree as the slot cache)."""
+    def seed(pcache, row, m):
+        def leaf(path, sh, b_ax, s_ax, pl):
+            if s_ax >= 0:
+                return gather_view(pl, row[None, :], b_ax, s_ax,
+                                   sh.shape[-1])
+            if _is_len_path(path):
+                return jnp.full(sh.shape, m, sh.dtype)
+            # unreachable when prefix sharing is active (the shareability
+            # rule excludes other dense leaves), but keep the seed total
+            return jnp.zeros(sh.shape, sh.dtype)
+
+        return jax.tree_util.tree_map_with_path(leaf, b1_shape, ba, sa,
+                                                pcache)
+    return seed
 
 
 def fake_quant_tree(cache: Any, n_tokens, sa: Any, page_size: int,

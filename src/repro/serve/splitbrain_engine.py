@@ -223,10 +223,14 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         """The shared split-brain per-token body: embed, lax.scan the
         stacked layers (pre-norm -> DEVICE QKV -> rope -> the injected
         ``kv_attend`` -> DEVICE wo -> HOST residual -> DEVICE FFN), final
-        norm, DEVICE head, HOST argmax.  ``kv_attend(kc, vc, q, k, v)`` is
-        the ONLY point the dense and paged disciplines differ (cache
-        append + attention), so their token-identity contract cannot drift
-        anywhere else.  Returns (next_tok, logits, new_k, new_v)."""
+        norm, DEVICE head, HOST argmax.  ``kv_attend(k_all, v_all, li, q,
+        k, v)`` is the ONLY point the dense and paged disciplines differ
+        (cache append + attention at layer ``li``), so their token-identity
+        contract cannot drift anywhere else.  The stacked caches ride in
+        the scan's CARRY and are updated in place — slicing them as scan
+        inputs and restacking them as outputs would copy every layer's
+        cache out and back in each token (DESIGN.md §6).  Returns
+        (next_tok, logits, new_k, new_v)."""
         cfg = self.cfg
         B = token.shape[0]
         hd = self._hd
@@ -235,8 +239,9 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         x = weights["embed"][token][:, None, :].astype(self._dtype)
         positions = pos[:, None]
 
-        def layer_fn(x, per_layer):
-            p, kc, vc = per_layer
+        def layer_fn(carry, per_layer):
+            x, k_all, v_all = carry
+            p, li = per_layer
             # HOST: pre-norm (dynamic statistics)
             xn = L.rmsnorm(x, p["ln_attn"], cfg.norm_eps)
             # DEVICE: QKV projection
@@ -245,7 +250,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             q = L.rope(q, positions, cfg.rope_theta)
             k = L.rope(k, positions, cfg.rope_theta)
             # HOST: KV-cache append + attention (discipline-specific)
-            attn, kc, vc = kv_attend(kc, vc, q, k, v)
+            attn, k_all, v_all = kv_attend(k_all, v_all, li, q, k, v)
             attn = attn.transpose(0, 2, 1, 3).reshape(B, 1, cfg.num_heads * hd)
             # DEVICE: output projection;  HOST: residual add
             x = x + L.linear(attn, p["attn"]["wo"], kn)
@@ -253,10 +258,12 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             y = L.rmsnorm(x, p["ln_mlp"], cfg.norm_eps)
             x = x + L.swiglu(y, p["mlp"]["w1"], p["mlp"]["w3"],
                              p["mlp"]["w2"], kernels=kn)
-            return x, (kc, vc)
+            return (x, k_all, v_all), None
 
-        x, (new_k, new_v) = jax.lax.scan(
-            layer_fn, x, (weights["layers"], k_cache, v_cache))
+        n_layers = jax.tree.leaves(k_cache)[0].shape[0]
+        (x, new_k, new_v), _ = jax.lax.scan(
+            layer_fn, (x, k_cache, v_cache),
+            (weights["layers"], jnp.arange(n_layers)))
         x = L.rmsnorm(x, weights["ln_final"], cfg.norm_eps)
         logits = L.linear(x, weights["head"], kn)[:, 0]
         # HOST: sampling
@@ -271,14 +278,14 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         """
         pos = length
 
-        def kv_attend(kc, vc, q, k, v):
+        def kv_attend(k_all, v_all, li, q, k, v):
             kc = jax.vmap(lambda c, kk, i: jax.lax.dynamic_update_slice(
-                c, kk, (0, i, 0)))(kc, k[:, :, 0:1], pos)
+                c, kk, (0, i, 0)))(k_all[li], k[:, :, 0:1], pos)
             vc = jax.vmap(lambda c, vv, i: jax.lax.dynamic_update_slice(
-                c, vv, (0, i, 0)))(vc, v[:, :, 0:1], pos)
+                c, vv, (0, i, 0)))(v_all[li], v[:, :, 0:1], pos)
             attn = ops.decode_attention(q, kc, vc, pos + 1,
                                         softcap=self.cfg.softcap)
-            return attn, kc, vc
+            return attn, k_all.at[li].set(kc), v_all.at[li].set(vc)
 
         next_tok, logits, new_k, new_v = self._layer_sweep(
             weights, k_cache, v_cache, pos, token, kv_attend)
@@ -287,9 +294,10 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
     def _paged_token_step(self, weights, k_pool, v_pool, table, length,
                           token, write):
         """One split-brain token computed THROUGH the page pool — no dense
-        view.  k_pool/v_pool: (L, num_pages, page_size, Hkv, hd) in the
-        kernel-friendly layout, swept per layer by the same
-        ``_layer_sweep`` as ``_token_step``; the HOST phase appends each
+        view.  k_pool/v_pool: (L, num_pages, page_size, Hkv/f, f·hd) in
+        the stored, possibly lane-folded layout (``layers.kv_fold``),
+        carried through the same ``_layer_sweep`` as ``_token_step`` and
+        touched in place at each layer's index; the HOST phase appends each
         active slot's K/V to its page (inactive slots land on scratch) and
         attention walks ``pool[table]`` page-block-wise
         (``ops.paged_decode_attention``), so steady-state KV reads are
@@ -298,14 +306,14 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
         """
         pos = length
 
-        def kv_attend(kc, vc, q, k, v):
-            kc = L.paged_cache_write(kc, k, table, pos, write)
-            vc = L.paged_cache_write(vc, v, table, pos, write)
+        def kv_attend(kc, vc, li, q, k, v):
+            kc = L.paged_cache_write(kc, k, table, pos, write, layer=li)
+            vc = L.paged_cache_write(vc, v, table, pos, write, layer=li)
             attn = ops.paged_decode_attention(
                 q, kc, vc, table, pos + 1, softcap=self.cfg.softcap,
                 kernels=self.kernels,
                 model_axis=self.cfg.parallel.model_axis,
-                batch_axes=self.cfg.parallel.batch_axes)
+                batch_axes=self.cfg.parallel.batch_axes, layer=li)
             return attn, kc, vc
 
         next_tok, logits, new_k, new_v = self._layer_sweep(
@@ -576,12 +584,14 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             return self.init_cache(n_slots)
         pool = self._pager.reset(n_slots)
         self._pager.prefix_on = self.prefix_sharing_active()
-        # head-cut pool placement (DESIGN.md §11): each model shard owns a
-        # (L, num_pages, ps, Hkv/tp, hd) slice; an Hkv the TP degree does
-        # not divide auto-replicates (the Hkv < tp fallback) and the
-        # per-shard byte accounting stays 1-way.
+        # head-cut pool placement (DESIGN.md §11): each model shard owns
+        # Hkv/tp kv heads, (L, num_pages, ps, Hkv/(f*tp), f*hd) of the folded
+        # pool (the fold keeps that cut wherever tp divides Hkv); head rows
+        # the TP degree does not divide auto-replicate (the Hkv < tp
+        # fallback) and the per-shard byte accounting stays 1-way.
         pshape = pages_mod.pool_shape(shape, ba, sa, pool.num_pages,
-                                      self.page_size, self._kv_dtype)
+                                      self.page_size, self._kv_dtype,
+                                      self._tp)
         pool_specs = shd.pool_pspecs(pshape, self.cfg, self.mesh, sa)
         self._pool_sh = shd.with_sharding(self.mesh, pool_specs)
         self._b1_sh = self._cache_shardings(1)
@@ -596,7 +606,7 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
             return pages_mod.make_pool(shape, ba, sa, pool.num_pages,
                                        self.page_size,
                                        shardings=self._pool_sh,
-                                       kv_dtype=self._kv_dtype)
+                                       kv_dtype=self._kv_dtype, tp=self._tp)
 
     # reserve_slot / can_ever_admit / free_slot / cache_stats come from
     # pages_mod.PagedEngineMixin.
@@ -761,7 +771,8 @@ class SplitBrainEngine(pages_mod.PagedEngineMixin):
                         return nxt, ok, {"k": k2, "v": v2, "len": ln2}
                 else:
                     def paged_step(weights, pcache, table, tok, act_m, bad):
-                        view = pages_mod.gather_tree(pcache, table, ba, sa)
+                        view = pages_mod.gather_tree(pcache, table, ba, sa,
+                                                     self._hd)
                         pos = view["len"]
                         _, logits, k2, v2, ln2 = self._token_step(
                             weights, view["k"], view["v"], pos, tok)

@@ -118,12 +118,15 @@ _SCRIPT = """
             scales = dict((n, jnp.asarray(rng.uniform(0.01, 0.1, (N, Hkv)),
                                           jnp.float32))
                           for n in ("k_scale", "v_scale"))
+        # one-layer stacks: the pool (and its scales) as the step passes it
+        kp, vp = kp[None], vp[None]
+        scales = {{n: sc[None] for n, sc in scales.items()}}
         want = _pa.paged_decode_attention(q, kp, vp, table, lens, softcap=2.0,
-                                          interpret=True, **scales)
+                                          layer=0, interpret=True, **scales)
         build = (collectives.tp_paged_decode_attention if Hkv % TP == 0
                  else collectives.tp_paged_decode_attention_merge)
         fn = build(mesh, "model", softcap=2.0, interpret=True, quant=quant)
-        got = fn(q, kp, vp, table, lens,
+        got = fn(q, kp, vp, table, lens, jnp.int32(0),
                  *(scales[n] for n in ("k_scale", "v_scale") if quant))
         err = float(jnp.max(jnp.abs(want - got)))
         assert err < 1e-5, (name, err)
@@ -164,3 +167,93 @@ def test_mesh_serve_token_parity(tp):
     run_multidev(_SCRIPT.format(tp=tp), devices=tp,
                  markers=FAMILY_MARKERS + ["KERNEL_TP_OK", "MESH_SERVE_OK"],
                  timeout=1800)
+
+
+_FOLDED_SCRIPT = """
+    import numpy as np
+    import jax, jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.distributed import collectives
+    from repro.kernels import paged_attention as _pa
+    from repro.launch.mesh import make_test_mesh
+    from repro.models import api
+    from repro.models.layers import kv_fold
+    from repro.serve.engine import ServeEngine
+
+    TP, KV_DTYPE = {tp}, {kv_dtype!r}
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, 127, size=n).astype(np.int32) for n in (7, 12)]
+    # head_dim 64, Hkv 4: at tp 2 two heads per lane row, two rows -> one
+    # per shard; at tp 4 the fold gives way to the head cut, one head each
+    cfg = get_config("llama2-7b").reduced(vocab_size=128, num_heads=4,
+                                          num_kv_heads=4, head_dim=64)
+    assert kv_fold(4, 64, TP) == (2 if TP == 2 else 1)
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+
+    def run(mesh):
+        eng = ServeEngine(cfg, params, mesh=mesh, max_len=48, page_size=8,
+                          kv_dtype=KV_DTYPE)
+        cache = eng.init_slot_cache(2)
+        toks = np.zeros((2,), np.int32)
+        for i, p in enumerate(prompts):
+            assert eng.reserve_slot(i, len(p), 10)
+            c1, toks[i] = eng.prefill_slot(p)
+            cache = eng.insert_slot(cache, c1, i)
+        outs = []
+        for _ in range(6):
+            nxt, ok, cache = eng.decode_slots(cache, toks,
+                                              np.array([True, True]))
+            toks = np.asarray(nxt)
+            outs.append(toks.copy())
+        return np.stack(outs), eng.cache_stats(cache)
+
+    o1, _ = run(make_test_mesh(devices=jax.devices()[:1]))
+    o2, stats = run(make_test_mesh(shape=(1, TP)))
+    assert np.array_equal(o1, o2), (o1, o2)
+    assert stats["kv_shards"] == TP, stats
+    print("ENGINE_FOLDED_TP_OK")
+
+    # the per-shard kernels on a stacked, folded pool read at a layer
+    mesh = make_test_mesh(shape=(1, TP))
+    L, B, D, ps, N, Pg = 3, 3, 64, 8, 12, 4
+    for hkv in (4, 2):
+        f = kv_fold(hkv, D, TP)
+        build = (collectives.tp_paged_decode_attention if (hkv // f) % TP == 0
+                 else collectives.tp_paged_decode_attention_merge)
+        q = jnp.asarray(rng.standard_normal((B, 4, 1, D)), jnp.float32)
+        kp, vp = (jnp.asarray(rng.standard_normal((L, N, ps, hkv // f, f * D)),
+                              jnp.float32) for _ in range(2))
+        scales = ()
+        if KV_DTYPE != "bf16":
+            kp, vp = (jnp.round(p * 20).astype(jnp.int8) for p in (kp, vp))
+            scales = tuple(jnp.asarray(rng.uniform(0.01, 0.1, (L, N, hkv)),
+                                       jnp.float32) for _ in range(2))
+        table = jnp.asarray(rng.permutation(N)[:B * Pg].reshape(B, Pg),
+                            jnp.int32)
+        lens = jnp.asarray([1, 9, 30], jnp.int32)
+        layer = jnp.int32(1)
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        want = _pa.paged_decode_attention(q, kp, vp, table, lens, layer=layer,
+                                          interpret=True, **kw)
+        fn = build(mesh, "model", interpret=True, quant=bool(scales))
+        got = fn(q, kp, vp, table, lens, layer, *scales)
+        err = float(jnp.max(jnp.abs(want - got)))
+        assert err < 1e-5, (hkv, err)
+    print("KERNEL_FOLDED_TP_OK")
+"""
+
+
+@pytest.mark.parametrize("tp,kv_dtype", [
+    pytest.param(2, "bf16", id="bf16"), pytest.param(2, "int8", id="int8"),
+    pytest.param(4, "bf16", id="tp4-bf16")])
+def test_folded_pool_tp_token_parity(tp, kv_dtype):
+    """A lane-folded pool (head_dim 64, two heads per lane row) cut on its
+    head rows over a (1, 2) mesh serves the tokens of one device, and the
+    per-shard kernels read a layer of the stacked, folded pool: the head
+    cut without a collective, the page-split merge where the rows do not
+    divide.  At tp 4 the fold never takes the head cut away: Hkv 4 stays
+    unfolded and cuts four ways (``kv_shards == 4``), not replicated."""
+    run_multidev(_FOLDED_SCRIPT.format(tp=tp, kv_dtype=kv_dtype), devices=tp,
+                 markers=["ENGINE_FOLDED_TP_OK", "KERNEL_FOLDED_TP_OK"],
+                 timeout=900)

@@ -71,8 +71,8 @@ def test_oracle_matches_gather_plus_dense(case):
     q, kp, vp, table, lens, dk, dv = _rand_paged(rng, B, Hq, Hkv, D, ps, P)
     want = ref.decode_attention(q, dk, dv, lens, window=window,
                                 softcap=softcap)
-    got = ref.paged_decode_attention(q, kp, vp, table, lens, window=window,
-                                     softcap=softcap)
+    got = ref.paged_decode_attention(q, kp[None], vp[None], table, lens,
+                                     window=window, softcap=softcap, layer=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-6, rtol=2e-5)
 
@@ -83,14 +83,16 @@ def test_oracle_never_reads_unallocated_pages():
     rng = np.random.default_rng(0)
     q, kp, vp, table, lens, dk, dv = _rand_paged(rng, 2, 4, 2, 16, 4, 4)
     lens = jnp.asarray([3, 9], jnp.int32)
-    base = ref.paged_decode_attention(q, kp, vp, table, lens)
+    base = ref.paged_decode_attention(q, kp[None], vp[None], table, lens,
+                                      layer=0)
     # poison every page beyond each slot's live prefix AND the scratch page
     poison = np.asarray(kp).copy()
     poison[0] = 1e9                                    # scratch page
     for b, ln in enumerate([3, 9]):
         for p in range(-(-ln // 4), 4):
             poison[int(table[b, p])] = 1e9
-    got = ref.paged_decode_attention(q, jnp.asarray(poison), vp, table, lens)
+    got = ref.paged_decode_attention(q, jnp.asarray(poison)[None], vp[None],
+                                     table, lens, layer=0)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
@@ -122,9 +124,10 @@ def test_quant_oracle_matches_dequantized_dense(case):
                      .reshape(B, P * ps, Hkv, D).transpose(0, 2, 1, 3))
     want = ref.decode_attention(q, dk, dv, lens, window=window,
                                 softcap=softcap)
-    got = ref.paged_decode_attention(q, kq.codes, vq.codes, table, lens,
-                                     window=window, softcap=softcap,
-                                     k_scale=kq.scales, v_scale=vq.scales)
+    got = ref.paged_decode_attention(q, kq.codes[None], vq.codes[None],
+                                     table, lens, window=window,
+                                     softcap=softcap, k_scale=kq.scales[None],
+                                     v_scale=vq.scales[None], layer=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-6, rtol=2e-5)
 
@@ -137,9 +140,12 @@ def test_quant_ops_dispatch_unpacks_quantized_leaf(kv_dtype):
     rng = np.random.default_rng(7)
     q, kp, vp, table, lens, _, _ = _rand_paged(rng, 2, 4, 2, 16, 8, 3)
     kq, vq = _quantize(kp, kv_dtype), _quantize(vp, kv_dtype)
-    want = ref.paged_decode_attention(q, kq.codes, vq.codes, table, lens,
-                                      k_scale=kq.scales, v_scale=vq.scales)
-    got = ops.paged_decode_attention(q, kq, vq, table, lens, kernels="jnp")
+    stack = lambda z: jax.tree.map(lambda a: a[None], z)
+    want = ref.paged_decode_attention(q, kq.codes[None], vq.codes[None],
+                                      table, lens, k_scale=kq.scales[None],
+                                      v_scale=vq.scales[None], layer=0)
+    got = ops.paged_decode_attention(q, stack(kq), stack(vq), table, lens,
+                                     kernels="jnp", layer=0)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -155,13 +161,11 @@ def test_quant_pallas_kernel_matches_oracle(case):
     rng = np.random.default_rng(hash(case) % 2**32)
     q, kp, vp, table, lens, _, _ = _rand_paged(rng, B, Hq, Hkv, D, ps, P)
     kq, vq = _quantize(kp, "int8"), _quantize(vp, "int8")
-    want = ref.paged_decode_attention(q, kq.codes, vq.codes, table, lens,
-                                      window=window, softcap=softcap,
-                                      k_scale=kq.scales, v_scale=vq.scales)
-    got = paged_decode_attention(q, kq.codes, vq.codes, table, lens,
-                                 window=window, softcap=softcap,
-                                 k_scale=kq.scales, v_scale=vq.scales,
-                                 interpret=True)
+    args = (q, kq.codes[None], vq.codes[None], table, lens)
+    kw = dict(window=window, softcap=softcap, k_scale=kq.scales[None],
+              v_scale=vq.scales[None], layer=0)
+    want = ref.paged_decode_attention(*args, **kw)
+    got = paged_decode_attention(*args, **kw, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-6, rtol=2e-5)
 
@@ -176,12 +180,174 @@ def test_pallas_kernel_matches_oracle(case):
     B, Hq, Hkv, D, ps, P, window, softcap = case
     rng = np.random.default_rng(hash(case) % 2**32)
     q, kp, vp, table, lens, dk, dv = _rand_paged(rng, B, Hq, Hkv, D, ps, P)
-    want = ref.paged_decode_attention(q, kp, vp, table, lens, window=window,
-                                      softcap=softcap)
-    got = paged_decode_attention(q, kp, vp, table, lens, window=window,
-                                 softcap=softcap, interpret=True)
+    args = (q, kp[None], vp[None], table, lens)
+    want = ref.paged_decode_attention(*args, window=window, softcap=softcap,
+                                      layer=0)
+    got = paged_decode_attention(*args, window=window, softcap=softcap,
+                                 layer=0, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-6, rtol=2e-5)
+
+
+# ------------------------------------ the stored pool layout (DESIGN.md §5)
+def _stored(pool, fold):
+    """(.., Hkv, D) -> the lane-folded stored layout (.., Hkv/f, f·D)."""
+    return pool.reshape(pool.shape[:-2] + (pool.shape[-2] // fold, -1))
+
+
+STORED_CASES = [
+    # (Hq, Hkv, D, quant, lse): the fold is layers.kv_fold(Hkv, D)
+    (8, 4, 64, False, False),     # fold 2: two heads per 128-lane row
+    (8, 8, 16, False, True),      # fold 8, with the log-sum-exp output
+    (8, 4, 32, True, False),      # fold 4, int8 codes and per-head scales
+    (6, 3, 64, False, False),     # odd Hkv: unfoldable, plain layout
+    (4, 2, 96, False, False),     # D 96 does not divide 128: plain layout
+    (8, 2, 128, True, True),      # D 128: already lane-dense
+]
+
+
+@pytest.mark.parametrize("case", STORED_CASES,
+                         ids=lambda c: "hq{}-hkv{}-d{}-q{:d}-lse{:d}".format(*c))
+def test_pallas_kernel_reads_stored_stacked_pool(case):
+    """The kernel reads one layer of the whole stacked pool in its stored,
+    lane-folded layout (scalar-prefetched layer index; per-head lane-group
+    reductions) and matches the oracle on that layer's plain pool."""
+    from repro.kernels.paged_attention import paged_decode_attention
+    from repro.models.layers import kv_fold
+    Hq, Hkv, D, quant, lse = case
+    B, ps, P, L, layer = 3, 4, 3, 3, 1
+    rng = np.random.default_rng(Hkv * D)
+    per_layer = [_rand_paged(rng, B, Hq, Hkv, D, ps, P) for _ in range(L)]
+    q, _, _, table, lens, _, _ = per_layer[layer]
+    kp = jnp.stack([c[1] for c in per_layer])
+    vp = jnp.stack([c[2] for c in per_layer])
+    scales = {}
+    if quant:
+        qk, qv = (_quantize(p.reshape((-1,) + p.shape[2:]), "int8")
+                  for p in (kp, vp))
+        kp, vp = (z.codes.reshape(kp.shape) for z in (qk, qv))
+        scales = {"k_scale": qk.scales.reshape(L, -1, Hkv),
+                  "v_scale": qv.scales.reshape(L, -1, Hkv)}
+    fold = kv_fold(Hkv, D)
+    want = ref.paged_decode_attention(q, kp, vp, table, lens, layer=layer,
+                                      **scales)
+    got = paged_decode_attention(q, _stored(kp, fold), _stored(vp, fold),
+                                 table, lens, layer=jnp.int32(layer),
+                                 interpret=True, return_lse=lse, **scales)
+    if lse:
+        got, got_lse = got
+        _, want_lse = paged_decode_attention(
+            q, kp[layer][None], vp[layer][None], table, lens, layer=0,
+            interpret=True, return_lse=True,
+            **{n: sc[layer][None] for n, sc in scales.items()})
+        np.testing.assert_allclose(np.asarray(got_lse),
+                                   np.asarray(want_lse), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-5)
+
+
+def _step_cfg(num_heads, num_kv_heads, head_dim):
+    cfg = get_config("stablelm-1.6b").reduced(
+        num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
+        vocab_size=128, kernels="interpret")
+    return dataclasses.replace(cfg, parallel=dataclasses.replace(
+        cfg.parallel, remat="none", aligned_decode=False))
+
+
+@pytest.mark.parametrize("heads,kv_dtype", [
+    ((4, 4, 64), "bf16"),         # fold 2
+    ((4, 4, 64), "int8"),         # fold 2, quantized pool
+    ((6, 3, 64), "bf16"),         # odd Hkv: unfoldable
+    ((4, 2, 96), "bf16"),         # D 96: unfoldable
+    ((4, 2, 128), "bf16"),        # D 128: lane-dense as it is
+], ids=["fold2", "fold2-int8", "odd-hkv", "d96", "d128"])
+def test_paged_step_on_stored_pool_matches_dense_and_gather(heads, kv_dtype):
+    """``api.paged_decode_step`` with the Pallas kernel (interpreted) over
+    a pool built by ``insert_tree`` in the stored layout — carried through
+    the layer scan and updated in place — emits the tokens of the dense
+    ``decode_step`` on the same cache (bf16) and of the gather discipline
+    (gather the dense view, ``decode_step``, scatter the token back), with
+    slot 1 frozen for the first steps."""
+    from repro.models.layers import kv_fold
+    from repro.serve import pages, slots
+    cfg = _step_cfg(*heads)
+    dense_cfg = dataclasses.replace(cfg, kernels="jnp")
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    B, T, ps, steps = 3, 32, 8, 6
+    cache = lambda b, t: jax.eval_shape(lambda: api.init_cache(cfg, b, t))
+    ba = slots.batch_axes(cache(1, T), cache(2, T))
+    sa = pages.seq_axes(cache(2, T), cache(2, T + ps), ps)
+    host = pages.PagePool(B * (T // ps) + 1, ps, n_slots=B,
+                          slot_pages=T // ps)
+    pool = pages.make_pool(cache(B, T), ba, sa, host.num_pages, ps,
+                           kv_dtype=kv_dtype)
+    assert pool["k"][0].shape[-2:] == (
+        heads[1] // kv_fold(heads[1], heads[2]),
+        heads[2] * kv_fold(heads[1], heads[2]))
+    # prefill three prompts of ragged lengths into B=1 caches and insert
+    rng = np.random.default_rng(sum(heads))
+    dense = api.init_cache(cfg, B, T)
+    toks = np.zeros((B,), np.int32)
+    for b, n in enumerate((5, 11, 17)):
+        prompt = rng.integers(1, cfg.vocab_size, (n,)).astype(np.int32)
+        one = api.init_cache(cfg, 1, T)
+        _, one = api.prefill(params, one, jnp.asarray(prompt[None, :-1]),
+                             dense_cfg)
+        if kv_dtype != "bf16":
+            one = pages.fake_quant_tree(one, n - 1, sa, ps, kv_dtype)
+        assert host.try_reserve(b, T)
+        host.ensure(b, T)
+        pool = pages.insert_tree(pool, one, jnp.asarray(host.table[b]),
+                                 jnp.int32(b), ba, sa,
+                                 n_tokens=jnp.int32(n - 1))
+        dense = slots.make_slot_insert(ba)(dense, one, jnp.int32(b))
+        toks[b] = prompt[-1]
+    table = jnp.asarray(host.table)
+    step = jax.jit(lambda p, c, t, w: api.paged_decode_step(
+        p, c, table, t, cfg, write=w, seq_axes=sa))
+
+    @jax.jit
+    def gather_step(p, c, t, w):
+        view = pages.gather_tree(c, table, ba, sa, cfg.resolved_head_dim)
+        logits, new = api.decode_step(p, view, t, dense_cfg)
+        new = slots.select_slots(w, new, view, ba)
+        return logits, pages.scatter_token_tree(c, new, table, view["len"],
+                                                w, ba, sa)
+
+    @jax.jit
+    def dense_step(p, c, t, w):
+        logits, new = api.decode_step(p, c, t, dense_cfg)
+        return logits, slots.select_slots(w, new, c, ba)
+
+    inplace, gathered, dense_toks = pool, pool, jnp.asarray(toks)
+    t_in = t_ga = jnp.asarray(toks)
+    for k in range(steps):
+        write = jnp.asarray([True, k >= 2, True])
+        lg_in, inplace = step(params, inplace, t_in, write)
+        lg_ga, gathered = gather_step(params, gathered, t_ga, write)
+        nxt_in = jnp.argmax(lg_in, -1).astype(jnp.int32)
+        nxt_ga = jnp.argmax(lg_ga, -1).astype(jnp.int32)
+        np.testing.assert_array_equal(np.asarray(nxt_in)[np.asarray(write)],
+                                      np.asarray(nxt_ga)[np.asarray(write)])
+        if kv_dtype == "bf16":
+            lg_d, dense = dense_step(params, dense, dense_toks, write)
+            nxt_d = jnp.argmax(lg_d, -1).astype(jnp.int32)
+            np.testing.assert_array_equal(
+                np.asarray(nxt_in)[np.asarray(write)],
+                np.asarray(nxt_d)[np.asarray(write)])
+            dense_toks = jnp.where(write, nxt_d, dense_toks)
+        t_in = jnp.where(write, nxt_in, t_in)
+        t_ga = jnp.where(write, nxt_ga, t_ga)
+    # the first layer's K/V depend on the tokens alone: the in-place
+    # appends hold exactly what the gather discipline wrote back
+    view_in = pages.gather_tree(inplace, table, ba, sa, cfg.resolved_head_dim)
+    view_ga = pages.gather_tree(gathered, table, ba, sa,
+                                cfg.resolved_head_dim)
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(view_in[n][0][0]),
+                                      np.asarray(view_ga[n][0][0]))
+    np.testing.assert_array_equal(np.asarray(view_in["len"]),
+                                  np.asarray(view_ga["len"]))
 
 
 # --------------------------------------------------------- engine parity
@@ -199,8 +365,15 @@ def _mix_cfgs():
     # hymba with global attention: paged K/V + dense SSM state in ONE step
     hymba = get_config("hymba-1.5b").reduced(
         layer_pattern=(LayerSpec(window=None),))
+    # pool layouts by head shape (layers.kv_fold): two heads per lane row,
+    # an odd Hkv that cannot fold, and a head_dim already 128 lanes wide
+    heads = lambda name, hq, hkv, hd: dataclasses.replace(
+        lm, name=name, num_heads=hq, num_kv_heads=hkv, head_dim=hd)
+    fold2 = heads("lm-fold2", 4, 4, 64)
+    odd = heads("lm-odd-hkv", 6, 3, 64)
+    d128 = heads("lm-d128", 4, 2, 128)
     out = []
-    for cfg in (lm, gemma, hymba):
+    for cfg in (lm, gemma, hymba, fold2, odd, d128):
         out.append(dataclasses.replace(
             cfg, parallel=dataclasses.replace(cfg.parallel, remat="none")))
     return out
@@ -344,6 +517,7 @@ def test_zero_length_slot_returns_zeros():
     rng = np.random.default_rng(3)
     q, kp, vp, table, lens, dk, dv = _rand_paged(rng, 2, 4, 2, 16, 4, 4)
     lens = jnp.asarray([0, 9], jnp.int32)
-    out = np.asarray(ref.paged_decode_attention(q, kp, vp, table, lens))
+    out = np.asarray(ref.paged_decode_attention(q, kp[None], vp[None],
+                                                table, lens, layer=0))
     np.testing.assert_array_equal(out[0], 0.0)
     assert np.abs(out[1]).max() > 0

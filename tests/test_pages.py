@@ -200,7 +200,7 @@ def test_insert_gather_roundtrip_and_scratch_isolation():
         pool = pages.insert_tree(pool, single, jnp.asarray(host.table[b]),
                                  jnp.int32(b), ba, sa)
     table = jnp.asarray(host.table)
-    view = pages.gather_tree(pool, table, ba, sa)
+    view = pages.gather_tree(pool, table, ba, sa, S)
     np.testing.assert_array_equal(np.asarray(view), dense)
 
     # scatter one token per slot at ragged positions; only active slots
@@ -209,7 +209,7 @@ def test_insert_gather_roundtrip_and_scratch_isolation():
     write = jnp.asarray([True, False, True])
     new = jnp.asarray(dense + 100.0)
     pool2 = pages.scatter_token_tree(pool, new, table, pos, write, ba, sa)
-    view2 = np.asarray(pages.gather_tree(pool2, table, ba, sa))
+    view2 = np.asarray(pages.gather_tree(pool2, table, ba, sa, S))
     expect = dense.copy()
     expect[:, 0, 1] += 100.0
     expect[:, 2, 7] += 100.0                      # slot 1 frozen (inactive)
@@ -232,7 +232,7 @@ def test_insert_excess_logical_pages_hit_scratch_only():
                              jnp.asarray(host.table[0]), jnp.int32(0),
                              ba, sa)
     before = np.asarray(pages.gather_view(pool, jnp.asarray(host.table[0:1]),
-                                          ba, sa))
+                                          ba, sa, S))
     # slot 1 inserts a 3-token prompt: 1 real page, 1 scratch block
     assert host.try_reserve(1, 3)
     host.ensure(1, 3)
@@ -240,10 +240,10 @@ def test_insert_excess_logical_pages_hit_scratch_only():
                              jnp.asarray(host.table[1]), jnp.int32(1),
                              ba, sa)
     after = np.asarray(pages.gather_view(pool, jnp.asarray(host.table[0:1]),
-                                         ba, sa))
+                                         ba, sa, S))
     np.testing.assert_array_equal(after, before)
     got = np.asarray(pages.gather_view(pool, jnp.asarray(host.table[1:2]),
-                                       ba, sa))
+                                       ba, sa, S))
     np.testing.assert_array_equal(got[:, :, :ps], dense[:, 1:2, :ps])
 
 
@@ -317,7 +317,8 @@ def test_quant_insert_reconstruction_error_bounded(kv_dtype):
     table = jnp.arange(1, 7, dtype=jnp.int32)
     pool_k = pages.insert_tree(pool["k"], cache, table, jnp.int32(0),
                                ba["k"], sa["k"], n_tokens=jnp.int32(24))
-    view = pages.gather_view(pool_k, table[None, :], ba["k"], sa["k"])
+    view = pages.gather_view(pool_k, table[None, :], ba["k"], sa["k"],
+                             cache.shape[-1])
     err = jnp.abs(view.astype(jnp.float32) - cache.astype(jnp.float32))
     # per-(L, page, Hkv) bound, broadcast back over (ps, hd)
     sc = pool_k.scales[:, table]                  # (L, P, Hkv)
@@ -333,30 +334,31 @@ def test_quant_fresh_page_resets_stale_scale():
     """A freed/evicted page reused by a new sequence must NOT inherit the
     old tenant's coarse scale: the off==0 append zeroes the stale scale
     and re-encodes from the fresh content alone."""
-    codes = jnp.zeros((3, 4, 2, 8), jnp.int8)     # (N, ps, Hkv, hd)
-    scales = jnp.zeros((3, 2), jnp.float32)
+    # a one-layer stack: (L, N, ps, Hkv, hd) codes, (L, N, Hkv) scales
+    codes = jnp.zeros((1, 3, 4, 2, 8), jnp.int8)
+    scales = jnp.zeros((1, 3, 2), jnp.float32)
     big = 512.0 * jnp.ones((1, 2, 8), jnp.bfloat16)
     from repro.models.layers import quant_page_append
-    codes, scales = quant_page_append(codes, scales, big,
-                                      jnp.array([1]), jnp.array([0]), "int8")
-    coarse = float(scales[1, 0])
+
+    def append(codes, scales, tok, off):
+        return quant_page_append(codes, scales, tok, 0, jnp.array([1]),
+                                 jnp.array([off]), "int8")
+    codes, scales = append(codes, scales, big, 0)
+    coarse = float(scales[0, 1, 0])
     assert coarse >= 512.0 / 127
     # page 1 is recycled: a small token appended at offset 0 starts over
     small = 0.25 * jnp.ones((1, 2, 8), jnp.bfloat16)
-    codes, scales = quant_page_append(codes, scales, small,
-                                      jnp.array([1]), jnp.array([0]), "int8")
-    assert float(scales[1, 0]) < coarse / 100
-    deq = codes[1, 0].astype(jnp.float32) * scales[1][:, None]
+    codes, scales = append(codes, scales, small, 0)
+    assert float(scales[0, 1, 0]) < coarse / 100
+    deq = codes[0, 1, 0].astype(jnp.float32) * scales[0, 1][:, None]
     np.testing.assert_allclose(np.asarray(deq), 0.25, atol=1e-3)
     # within a page lifetime the scale is monotone: a later, larger token
     # recoarsens, an offset>0 smaller one never shrinks it
-    codes, scales = quant_page_append(codes, scales, big,
-                                      jnp.array([1]), jnp.array([1]), "int8")
-    grown = float(scales[1, 0])
+    codes, scales = append(codes, scales, big, 1)
+    grown = float(scales[0, 1, 0])
     assert grown >= coarse
-    codes, scales = quant_page_append(codes, scales, small,
-                                      jnp.array([1]), jnp.array([2]), "int8")
-    assert float(scales[1, 0]) == grown
+    codes, scales = append(codes, scales, small, 2)
+    assert float(scales[0, 1, 0]) == grown
 
 
 class _QuantStub(pages.PagedEngineMixin):
@@ -406,3 +408,83 @@ def test_check_kv_dtype_validation():
         pages.check_kv_dtype("int4", 8)
     with pytest.raises(ValueError, match="page_size"):
         pages.check_kv_dtype("int8", None)
+
+
+# ------------------------------------- the lane-dense stored layout (§5)
+@pytest.mark.parametrize("hkv,hd,stored", [
+    (4, 64, (2, 128)),    # two heads share a 128-lane row
+    (8, 16, (1, 128)),    # eight heads of 16
+    (3, 64, (3, 64)),     # odd Hkv: no fold
+    (2, 96, (2, 96)),     # 96 does not divide 128: no fold
+    (2, 128, (2, 128)),   # already lane-dense
+])
+def test_pool_stores_heads_lane_dense(hkv, hd, stored):
+    """The pool's trailing (Hkv, hd) pair is stored (Hkv/f, f*hd) with
+    f = kv_fold(Hkv, hd); the scales of a quantized pool stay one per kv
+    head, and the bytes per token are the dense figure either way."""
+    from repro.models.layers import kv_fold
+    shape, ba, sa = _quant_shape(B=2, Hkv=hkv, S=16, hd=hd)
+    assert kv_fold(hkv, hd) == hkv // stored[0]
+    pool = pages.make_pool(shape, ba, sa, num_pages=7, page_size=4)
+    assert pool["k"].shape == (3, 7, 4) + stored
+    qpool = pages.make_pool(shape, ba, sa, num_pages=7, page_size=4,
+                            kv_dtype="int8")
+    assert qpool["k"].codes.shape == (3, 7, 4) + stored
+    assert qpool["k"].scales.shape == (3, 7, hkv)
+    assert pages.page_token_bytes(pool, sa, 7, 4) == \
+        pages.kv_token_bytes(shape, ba, sa)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("hkv,hd", [(4, 64), (8, 16)], ids=["fold2", "fold8"])
+def test_folded_pool_insert_seed_cow_scatter_roundtrip(hkv, hd, kv_dtype):
+    """On a lane-folded pool: insert scatters a B=1 cache onto the slot's
+    pages, the prefix seed gathers it back (dequantized values equal the
+    page quantizer's round trip), a CoW copy carries a page and its scales,
+    and a decode writeback lands each token at its (page, offset) — all at
+    the page axis where it sits."""
+    from repro.models.layers import fake_quant_pages
+    L, S, ps = 3, 16, 4
+    shape, ba, sa = _quant_shape(B=2, Hkv=hkv, S=S, hd=hd, L=L)
+    b1 = jax.eval_shape(lambda: jax.tree.map(
+        lambda a: jnp.zeros((a.shape[0], 1) + a.shape[2:] if a.ndim > 1
+                            else (1,), a.dtype), shape))
+    pool = pages.make_pool(shape, ba, sa, num_pages=9, page_size=ps,
+                           kv_dtype=kv_dtype)
+    rng = np.random.default_rng(hkv)
+    dense = jnp.asarray(rng.standard_normal((L, 1, hkv, S, hd)), jnp.bfloat16)
+    want = dense if kv_dtype == "bf16" else fake_quant_pages(
+        dense, 3, S, ps, kv_dtype)
+    row = jnp.asarray([3, 5, 2, 8], jnp.int32)
+    single = {"k": dense, "len": jnp.asarray([S], jnp.int32)}
+    pool = pages.make_insert(ba, sa)(pool, single, row, jnp.int32(1),
+                                     jnp.int32(S))
+    seed = pages.make_seed(ba, sa, b1)(pool, row, jnp.int32(9))
+    np.testing.assert_array_equal(np.asarray(seed["k"], np.float32),
+                                  np.asarray(want, np.float32))
+    assert int(seed["len"][0]) == 9
+    # CoW: page 5 (logical 1) onto the free page 7, scales travelling along
+    pool = pages.copy_pages(pool, jnp.int32(5), jnp.int32(7), ba, sa)
+    moved = pages.gather_view(pool["k"], jnp.asarray([[3, 7, 2, 8]]),
+                              ba["k"], sa["k"], hd)
+    np.testing.assert_array_equal(np.asarray(moved, np.float32),
+                                  np.asarray(want, np.float32))
+    # a decode writeback of slot 1 at position 6 (page 5, offset 2); slot 0
+    # is frozen, its write goes to scratch
+    table = jnp.asarray([[1, 4, 6, 0], [3, 5, 2, 8]], jnp.int32)
+    new_k = jnp.asarray(rng.standard_normal((L, 2, hkv, S, hd)), jnp.bfloat16)
+    pos = jnp.asarray([0, 6], jnp.int32)
+    out = pages.scatter_token_tree(
+        pool, {"k": new_k, "len": pos}, table, pos,
+        jnp.asarray([False, True]), ba, sa)
+    view = pages.gather_view(out["k"], table[1:], ba["k"], sa["k"], hd)
+    tok = np.asarray(view, np.float32)[:, 0, :, 6]
+    ref_tok = np.asarray(new_k, np.float32)[:, 1, :, 6]
+    if kv_dtype == "bf16":
+        np.testing.assert_array_equal(tok, ref_tok)
+        others = np.delete(np.asarray(view, np.float32), 6, axis=3)
+        np.testing.assert_array_equal(
+            others, np.delete(np.asarray(want, np.float32), 6, axis=3))
+    else:   # requantized under the page's per-head scale: half a step
+        sc = np.asarray(out["k"].scales)[:, 5]            # (L, Hkv)
+        assert np.all(np.abs(tok - ref_tok) <= sc[..., None] * 0.5 + 1e-6)
