@@ -4,7 +4,8 @@ host clock the client's window is taken on.  A program without that
 recorder gives nothing, and the metrics that read it are left out."""
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+import bisect
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 STEP = "sched.step"
 # the prefill-side work an iteration can carry beside its decode step
@@ -29,6 +30,25 @@ def decode_iterations(run) -> Optional[Tuple[list, list]]:
     steps = [s for s in recs if s.name == STEP and s.attrs
              and s.attrs.get("decode") and run.win0 <= s.t0 < run.win1]
     return (steps, recs) if steps else None
+
+
+def step_counters(ends: Sequence[float]) -> List[Dict[str, Any]]:
+    """For each time in ``ends``, the attributes of the ``sched.step``
+    span whose interval holds it: what the program counted in that
+    iteration.  An empty dict where no span holds it, or the program has
+    no recorder."""
+    tel = _telemetry()
+    if tel is None:
+        return [{} for _ in ends]
+    steps = sorted((s for s in tel.recorder().spans() if s.name == STEP),
+                   key=lambda s: s.t0)
+    t0s = [s.t0 for s in steps]
+    out = []
+    for t in ends:
+        i = bisect.bisect_right(t0s, t) - 1
+        hit = i >= 0 and steps[i].t1 >= t
+        out.append(dict(steps[i].attrs or {}) if hit else {})
+    return out
 
 
 def host_self_ms(run) -> Optional[float]:

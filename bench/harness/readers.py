@@ -1,15 +1,16 @@
 """What the per-layer metric files in ``bench/metrics/`` read: one run's
 client records, the scheduler's counters, the traced steps' shapes and the
-trace's reduction.  Every reader returns None where it finds nothing to
-read, and the harness then leaves its metric out of the line."""
+program's counts in each, the trace's reduction, and the configuration's
+cost module, which counts the work of each traced step.  Every reader
+returns None where it finds nothing to read, and the harness then leaves
+its metric out of the line."""
 from __future__ import annotations
 
 import dataclasses
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
-import numpy as np
-
-from harness import costs
+from harness import costs as dense_costs
 from harness import trace as trace_mod
 
 # the programs the served path runs, by the names their jitted functions
@@ -28,9 +29,11 @@ class Run:
     win1: float
     open_loop: bool
     admits: Dict[int, tuple]
-    decode: List[tuple]            # traced decode steps: (t, n_active, read)
+    decode: List[tuple]            # traced decode steps:
+    #                                (t, n_active, read, the step's counters)
     chunks: List[tuple]            # traced prefill chunks: (t, start, width)
     trace: Optional[Dict[str, Any]]
+    costs: ModuleType = dense_costs   # the configuration's work counts
 
 
 def _window_admits(run: Run) -> List[tuple]:
@@ -66,11 +69,12 @@ def program_ms(run: Run, part: str) -> Optional[float]:
 
 
 def _roofline(run: Run, works) -> float:
-    return sum(costs.roofline_s(f, b, run.peaks)[0] for f, b in works)
+    return sum(dense_costs.roofline_s(f, b, run.peaks)[0] for f, b in works)
 
 
 def _decode_work(run: Run, fn):
-    return [fn(run.arch, n, read) for _, n, read in run.decode if n]
+    return [fn(run.arch, n, read, counters=c)
+            for _, n, read, c in run.decode if n]
 
 
 def _chunk_work(run: Run, fn):
@@ -82,9 +86,11 @@ def kernel_roofline_pct(run: Run, which: str) -> Optional[float]:
     if run.trace is None or run.peaks is None:
         return None
     if which == "paged_decode":
-        part, work = DECODE_PROGRAM, _decode_work(run, costs.paged_decode_kernel)
+        part, work = DECODE_PROGRAM, _decode_work(
+            run, run.costs.paged_decode_kernel)
     else:
-        part, work = CHUNK_PROGRAM, _chunk_work(run, costs.flash_prefill_kernel)
+        part, work = CHUNK_PROGRAM, _chunk_work(
+            run, run.costs.flash_prefill_kernel)
     k = trace_mod.kernel(run.trace, part)
     if not k or not k["seconds"] or not work:
         return None
@@ -96,8 +102,8 @@ def step_mfu_pct(run: Run) -> Optional[float]:
     the traced window: the whole step's share of the chip's peak."""
     if run.trace is None or run.peaks is None:
         return None
-    work = (_decode_work(run, costs.decode_step)
-            + _chunk_work(run, costs.prefill_chunk))
+    work = (_decode_work(run, run.costs.decode_step)
+            + _chunk_work(run, run.costs.prefill_chunk))
     if not work:
         return None
     return 100.0 * _roofline(run, work) / run.trace["window_s"]

@@ -5,8 +5,11 @@ configuration and traffic mix; every part lives in a file of its own under
 ``bench/`` and is found by that name, so a new cell, mix, configuration or
 per-layer metric is added as files and entries alone:
 
-- configuration  ``bench/configs/<config>.json`` (its ``file`` entry), whose
-  plain reference is ``bench/configs/<family>.py``;
+- configuration  ``bench/configs/<config>.json`` (its ``file`` entry); its
+  plain reference is ``bench/configs/<reference>.py``, the file's
+  ``reference`` key or else its ``family``, and its work counts come from
+  ``bench/costs/<costs>.py`` where the file has a ``costs`` key, else from
+  ``harness/costs.py`` (the dense ``lm`` block);
 - traffic mix    ``bench/traffic/<traffic>.json``;
 - metric reader  ``bench/metrics/<metric>.py`` with ``read(run)``, or, for
   a quantity split by the end-to-end metric it moves
@@ -78,12 +81,32 @@ def _load_module(path: Path, name: str):
     return mod
 
 
-def reference(family: str):
-    """The plain reference module of a model family."""
-    path = BENCH / "configs" / f"{family}.py"
+def reference(conf: Dict[str, Any]):
+    """The plain reference module of a configuration."""
+    name = conf.get("reference", conf["family"])
+    path = BENCH / "configs" / f"{name}.py"
     if not path.is_file():
-        raise SpecError(f"no plain reference {path} for family {family!r}")
-    return _load_module(path, f"bench_reference_{family}")
+        raise SpecError(f"no plain reference {path} for configuration "
+                        f"{conf['name']!r}")
+    return _load_module(path, f"bench_reference_{name}")
+
+
+def costs(conf: Dict[str, Any]):
+    """The module that counts a configuration's work: its file's
+    ``costs``, or the dense ``lm`` block's."""
+    from harness import costs as dense
+    name = conf.get("costs")
+    if name is None:
+        return dense
+    path = BENCH / "costs" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"no cost module {path} for configuration "
+                        f"{conf['name']!r}")
+    mod = _load_module(path, f"bench_costs_{name}")
+    missing = [f for f in dense.MODULE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SpecError(f"cost module {path} lacks {missing}")
+    return mod
 
 
 def metric_reader(name: str) -> Callable:

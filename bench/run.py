@@ -21,7 +21,8 @@ or on a device kind that ``bench/peaks.json`` does not list, it exits with
 code 2 and prints no result.
 
 ``--rehearse`` runs the same path on the CPU at a tiny size (the
-configuration's family and head ratio, the mix's rehearsal knobs) and
+configuration's family and head ratio, its ``rehearsal`` sizes, the mix's
+rehearsal knobs) and
 prints only counts: no time, no device metric.  ``--rate`` overrides an
 open-loop mix's arrival rate; it exists for the sweep that finds the knee.
 ``--control`` puts the fp8 control in the program's place: the tokens
@@ -144,7 +145,7 @@ def run(args, patch=None) -> dict:
             refuse(str(e))
     devs = devs[:cell["chips"]]
 
-    from harness import check, drive, model, readers
+    from harness import check, drive, model, program_spans, readers
     from harness import trace as trace_mod
     from harness.traffic import Traffic
     from repro.launch.mesh import make_test_mesh
@@ -152,14 +153,17 @@ def run(args, patch=None) -> dict:
     log = drive.CompileLog()
     a = model.arch(conf, rehearse)
     cfg = model.model_config(conf, rehearse)
+    ref = spec.reference(conf)
+    costs = spec.costs(conf)
     traffic = Traffic(mix, args.seed, a["vocab_size"], rehearse, args.rate)
     serving = traffic.serving
     mesh = make_test_mesh(devs)
     t = time.perf_counter()
     params = model.make_weights(cfg, args.seed, mesh)
     weights_s = time.perf_counter() - t
-    pages = model.num_pages(conf, serving, cfg, rehearse)
-    eng, sched = model.build_server(cfg, params, mesh, serving, pages)
+    pages = model.num_pages(conf, serving, cfg, costs, rehearse)
+    eng, sched = model.build_server(cfg, params, mesh, serving, pages,
+                                    conf.get("kv_dtype", "bf16"))
     if not all(x is y for x, y in zip(jax.tree.leaves(eng.params),
                                        jax.tree.leaves(params))):
         raise RuntimeError("the engine copied the weights it was given")
@@ -231,7 +235,6 @@ def run(args, patch=None) -> dict:
             red = trace_mod.reduce(trace_mod.extract(path))
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    ref = spec.reference(conf["family"])
     pick = check.sample(done, args.seed, traffic.mix["check"]["sample_tokens"],
                         traffic.mix["check"]["sample_requests"])
     G = int(traffic.output.max())
@@ -249,11 +252,13 @@ def run(args, patch=None) -> dict:
     metrics = {}
     tr = ctx["tracer"]
     t0, t1 = (tr.start, tr.stop) if tr is not None and tr.start else (0, 0)
+    traced = [r for r in decode_recs if t0 < r[0] <= t1]
+    traced = [r + (c,) for r, c in zip(
+        traced, program_spans.step_counters([r[0] for r in traced]))]
     rd = readers.Run(
         arch=a, peaks=peaks, stats=st, reqs=ctx["reqs"], win0=ctx["win0"],
         win1=ctx["win1"], open_loop=traffic.loop == "open",
-        admits=ctx["admits"],
-        decode=[r for r in decode_recs if t0 < r[0] <= t1],
+        admits=ctx["admits"], decode=traced, costs=costs,
         chunks=[r for r in chunk_recs if t0 < r[0] <= t1], trace=red)
     for mt in spec.metrics_for(bm, args.workload, bool(args.trace)):
         if rehearse and mt["source"] in ("host_clock", "device_trace"):

@@ -1,7 +1,8 @@
-"""FLOP and byte counts against shapes worked by hand."""
+"""FLOP and byte counts against shapes worked by hand, through the cost
+module a configuration without a ``costs`` key gets."""
 import pytest
 
-from harness import costs
+from harness import costs, spec
 
 # stablelm-1.6b: 24 layers, d 2048, 32 heads of 64 (MHA), FFN 5632, V 100352
 LM = {"num_hidden_layers": 24, "hidden_size": 2048, "num_attention_heads": 32,
@@ -70,3 +71,38 @@ def test_roofline_names_its_bound(flops, nbytes, bound):
 def test_decode_is_hbm_bound_at_these_sizes():
     f, b = costs.decode_step(GQA, 64, 64 * 500)
     assert costs.roofline_s(f, b, PEAKS)[1] == "hbm"
+
+
+def test_a_file_without_costs_gets_this_module():
+    assert spec.costs({"name": "stablelm-1.6b", "family": "lm"}) is costs
+    assert all(callable(getattr(costs, f)) for f in costs.MODULE)
+
+
+def test_counters_leave_dense_counts_unchanged():
+    c = {"iteration": 3, "n_active": 64}
+    assert costs.decode_step(GQA, 64, 64 * 500, counters=c) == \
+        costs.decode_step(GQA, 64, 64 * 500)
+    assert costs.paged_decode_kernel(LM, 2, 300, counters=c) == \
+        costs.paged_decode_kernel(LM, 2, 300)
+
+
+def test_pool_bytes_per_token():
+    assert costs.page_token_bytes(LM, 16) == 196608
+    assert costs.page_token_bytes(GQA, 16) == 73728
+    assert costs.slot_state_bytes(LM, 4096) == 0
+    assert costs.request_cache_bytes(LM, 4096) == 4096 * 196608
+
+
+def test_int8_pages_count_codes_and_scales():
+    """1-byte codes; a float32 scale per page, layer, K or V and kv head,
+    spread over the page's 16 tokens; the B=1 prefill cache and the chunk's
+    reads of it stay bf16."""
+    lm8 = dict(LM, kv_dtype="int8")
+    assert costs.kv_bytes(lm8, 1) == 98304
+    assert costs.page_token_bytes(lm8, 16) == 98304 + 2 * 24 * 32 * 4 / 16
+    assert costs.request_cache_bytes(lm8, 4096) == 4096 * 196608
+    f, b = costs.paged_decode_kernel(lm8, 2, 300)
+    assert b == 300 * 98304 + 24 * 2 * 2 * 32 * 64 * 2
+    assert costs.flash_prefill_kernel(lm8, 1024, 256) == \
+        costs.flash_prefill_kernel(LM, 1024, 256)
+    assert costs.prefill_chunk(lm8, 0, 256) == costs.prefill_chunk(LM, 0, 256)
